@@ -323,8 +323,8 @@ OPTIONS: list[Option] = [
            default=8 * 1024 * 1024,
            description="single calls below this encode on the SIMD host "
                        "codec; above (or batched via the pipeline/queue "
-                       "paths), on device — BASELINE_RESULTS.json config 2 "
-                       "measures the crossover"),
+                       "paths), on device — the crossover is not "
+                       "measured on the current code (ROADMAP S3)"),
     # -- device codec pipeline (ceph_tpu/ops/pipeline.py) ------------------
     Option("jax_rs_pipeline_depth", TYPE_UINT, LEVEL_ADVANCED,
            default=4,

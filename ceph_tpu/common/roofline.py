@@ -52,8 +52,8 @@ PEAK_SPECS: tuple[tuple[str, float, float], ...] = (
     ("v6e", 918e12, 1640e9),       # Trillium
     ("trillium", 918e12, 1640e9),
     ("v5p", 459e12, 2765e9),
-    ("v5e", 197e12, 819e9),        # the BENCH_r baseline hardware
-    ("v5 lite", 197e12, 819e9),
+    ("v5e", 197e12, 819e9),
+    ("v5 lite", 197e12, 819e9),    # what a v5e's device_kind says
     ("v4", 275e12, 1228e9),
     ("v3", 123e12, 900e9),
     ("v2", 46e12, 700e9),
@@ -70,9 +70,11 @@ CPU_NOMINAL_DRAM_BPS = 3e10
 def lookup_peaks(cct=None, device_kind: str | None = None,
                  platform: str | None = None) -> dict:
     """Resolve peak FLOP/s and HBM B/s for the current (or named)
-    device.  Config overrides win; then the device-kind registry; then a
-    nominal CPU spec (classification still works, ``source`` marks it).
-    Never initializes a backend: unknown stays unknown."""
+    device.  Config overrides win; then the device-kind registry.  A TPU
+    kind the registry does not know has NO peaks (zeros, ``source`` says
+    so) — a share of some other chip's roofline is not a measurement;
+    anything else gets a nominal CPU spec (classification still works,
+    ``source`` marks it).  Never initializes a backend."""
     if device_kind is None and platform is None:
         from . import device_telemetry
         inv = device_telemetry.device_inventory()
@@ -85,10 +87,7 @@ def lookup_peaks(cct=None, device_kind: str | None = None,
             flops, hbm, source = f, b, f"registry:{sub}"
             break
     if source is None and platform == "tpu":
-        # an unrecognized TPU generation: assume the baseline hardware
-        # rather than a meaningless nominal-CPU spec
-        flops, hbm, source = PEAK_SPECS[3][1], PEAK_SPECS[3][2], \
-            "default-tpu(v5e)"
+        source = f"unknown-tpu({device_kind})"
     if source is None:
         cores = os.cpu_count() or 1
         flops = CPU_NOMINAL_FLOPS_PER_CORE * cores

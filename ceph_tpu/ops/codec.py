@@ -47,24 +47,6 @@ class _DecodeTables:
         self.dev: jax.Array | None = None
 
 
-@functools.partial(jax.jit, static_argnames=("variant",),
-                   donate_argnums=(1,))
-def _gf_apply_donated(mat, data, variant):
-    """Steady-state pipeline apply with the data buffer DONATED: the
-    packed input block is dead after the dispatch (the pipeline packs a
-    fresh one per batch), so XLA may reuse its pages for scratch/output
-    instead of holding both live.  TPU-only — the CPU runtime cannot
-    alias them and would warn per call."""
-    return rs_kernels.gf_apply(mat, data, variant)
-
-
-def _donation_supported() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:            # backend init failure -> act like CPU
-        return False
-
-
 @functools.partial(jax.jit, static_argnames=("variant",))
 def _gf_scale_accumulate(mat, data, acc, variant):
     """One chained-repair hop's partial-sum update: ``mat @ data XOR acc``
@@ -155,7 +137,6 @@ class RSCodec:
         # must never re-upload a decode matrix per call)
         self.parity_uploads = 0
         self.decode_table_uploads = 0
-        self._donate = None          # lazily probed: platform supports it?
 
     # -- encode ------------------------------------------------------------
 
@@ -241,19 +222,9 @@ class RSCodec:
                 self._parity_dev = jnp.asarray(self.parity_mat)
                 self.parity_uploads += 1
 
-    def _donation_ok(self) -> bool:
-        if self._donate is None:
-            self._donate = _donation_supported()
-        return self._donate
-
-    def encode_device(self, data: jax.Array,
-                      donate: bool = False) -> jax.Array:
-        """Device-to-device encode (no host transfer), for pipeline use.
-        ``donate=True`` marks ``data`` dead-after-call on platforms that
-        support buffer donation (the pipeline's steady-state path)."""
+    def encode_device(self, data: jax.Array) -> jax.Array:
+        """Device-to-device encode (no host transfer), for pipeline use."""
         self._upload_parity()
-        if donate and self._donation_ok():
-            return _gf_apply_donated(self._parity_dev, data, self.variant)
         return rs_kernels.gf_apply(self._parity_dev, data, self.variant)
 
     # -- decode ------------------------------------------------------------
@@ -380,8 +351,7 @@ class RSCodec:
     # -- device-resident decode (no host round-trip; pipeline path) --------
 
     def decode_device(self, stack: jax.Array, erasures: list[int],
-                      available: list[int] | None = None,
-                      donate: bool = False) -> jax.Array:
+                      available: list[int] | None = None) -> jax.Array:
         """Device-to-device decode: ``stack`` [k, N] survivors already in
         the sorted-src order ``decode_matrix(erasures, available)``
         returns -> recovered rows [len(erasures), N], still on device.
@@ -392,13 +362,10 @@ class RSCodec:
         if int(stack.shape[0]) != len(src):
             raise ValueError(
                 f"stack has {stack.shape[0]} rows for {len(src)} sources")
-        if donate and self._donation_ok():
-            return _gf_apply_donated(D_dev, stack, self.variant)
         return rs_kernels.gf_apply(D_dev, stack, self.variant)
 
     def decode_batch_device(self, stack: jax.Array, src: list[int],
-                            erasures: list[int],
-                            donate: bool = False) -> jax.Array:
+                            erasures: list[int]) -> jax.Array:
         """Device-to-device batched decode: ``stack`` [B, k', N] survivors
         in ``src`` order -> [B, len(erasures), N] on device.  The row
         permutation, fold and unfold all run as device ops, so nothing
@@ -414,8 +381,5 @@ class RSCodec:
             stack = stack[:, :len(src_expected), :]
         b, k, n = (int(s) for s in stack.shape)
         folded = jnp.swapaxes(stack, 0, 1).reshape(k, b * n)
-        if donate and self._donation_ok():
-            rec = _gf_apply_donated(D_dev, folded, self.variant)
-        else:
-            rec = rs_kernels.gf_apply(D_dev, folded, self.variant)
+        rec = rs_kernels.gf_apply(D_dev, folded, self.variant)
         return jnp.swapaxes(rec.reshape(len(erasures), b, n), 0, 1)

@@ -28,6 +28,19 @@ from ..gf.tables import MUL_TABLE
 
 DEFAULT_TILE = 8192   # best sustained stream in the k=8,m=4 sweep on v5e
 
+# Block index maps return this, never a Python 0: under jax_enable_x64
+# (which CRUSH bulk mapping needs process-wide) a Python int traces as
+# i64, and Mosaic cannot legalize an index map that returns i64.
+_I0 = np.int32(0)
+
+
+def _out_like(data: jax.Array, shape: tuple[int, int]):
+    """The uint8 out_shape of a kernel whose output varies over the same
+    mesh axes as its data operand: inside ``shard_map`` the varying-axes
+    check refuses an out_shape that does not say (and outside it the set
+    is empty)."""
+    return jax.ShapeDtypeStruct(shape, jnp.uint8, vma=jax.typeof(data).vma)
+
 
 def expand_bits_plane_major(mat: jax.Array) -> jax.Array:
     """GF(2^8) matrix [r, k] -> GF(2) bit-matrix [8r, 8k], plane-major:
@@ -74,11 +87,38 @@ def _gf_stripes_kernel(bmat_ref, data_ref, out_ref, *, r: int, k: int,
     out_ref[:] = jnp.concatenate(outs, axis=0).astype(jnp.uint8)
 
 
+def _lane_tiles(n: int, tile_max: int) -> tuple[int, int]:
+    """Split a byte axis of n columns into (n_tiles, tile): the fewest
+    tiles no wider than tile_max, each the smallest 128-lane multiple
+    that covers n — padding waste stays under 128 columns per tile (a
+    fixed tile would do up to 2x wasted work at n just over a tile
+    boundary)."""
+    n_tiles = max(1, -(-n // max(128, tile_max)))
+    return n_tiles, max(128, (-(-n // n_tiles) + 127) // 128 * 128)
+
+
+def _stripe_groups(k: int, r: int, stripes: int) -> int:
+    """Stripe slabs per block of the vertical kernel.  Mosaic wants the
+    block's row counts (G*k in, G*r out) to be multiples of 8 sublanes
+    unless the block spans the whole stripe axis: the MXU-filling 4 does
+    that when k and r are even (8/4 encode, two-erasure decode), 8 does
+    it always (any single-erasure decode, m=3 encode, odd k).  A batch
+    no taller than one block is a single full-extent block, legal at any
+    k and r."""
+    return min(4 if k % 2 == 0 and r % 2 == 0 else 8, stripes)
+
+
+# the vertical kernel's VMEM working set is its bit-plane rows (int32
+# then int8 on the way in, int32 accumulators on the way out) times the
+# tile width; this is the product the k=10 m=4 block compiles at with
+# 8192-column tiles inside the 16 MiB scoped-VMEM limit of a v5e
+_STRIPES_VMEM_ELEMS = 4 * 8 * (10 + 4) * 8192
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("stripes", "groups", "tile_n",
-                                    "interpret"))
+                   static_argnames=("stripes", "tile_n", "interpret"))
 def gf_apply_stripes_pallas(mat: jax.Array, data: jax.Array, stripes: int,
-                            groups: int = 4, tile_n: int = 8192,
+                            tile_n: int = DEFAULT_TILE,
                             interpret: bool = False) -> jax.Array:
     """Batched GF apply over the VERTICAL stripe layout.
 
@@ -87,7 +127,8 @@ def gf_apply_stripes_pallas(mat: jax.Array, data: jax.Array, stripes: int,
     at rows [s*r, (s+1)*r).  This is the codec's device-native batch
     layout: stripes arrive one after another from the IO path, so stacking
     them as rows is a no-copy append, and it feeds the MXU full tiles
-    (see _gf_stripes_kernel).
+    (see _gf_stripes_kernel).  Group count and tile width follow from
+    (k, r, stripes) so every profile's encode and decode shapes lower.
     """
     from jax.experimental import pallas as pl
 
@@ -96,15 +137,15 @@ def gf_apply_stripes_pallas(mat: jax.Array, data: jax.Array, stripes: int,
     r, k = mat.shape
     rows, n = data.shape
     assert rows == stripes * k, f"{rows} rows != {stripes} stripes x {k}"
-    groups = max(1, min(groups, stripes))
+    groups = _stripe_groups(k, r, stripes)
     # pad the stripe count to a group multiple (zero stripes encode to
     # zero parity) and the byte axis to a lane multiple
     s_pad = (-stripes) % groups
     if s_pad:
         data = jnp.pad(data, ((0, s_pad * k), (0, 0)))
     s_total = stripes + s_pad
-    n_tiles = max(1, -(-n // tile_n))
-    tile = max(128, (-(-n // n_tiles) + 127) // 128 * 128)
+    n_tiles, tile = _lane_tiles(
+        n, min(tile_n, _STRIPES_VMEM_ELEMS // (groups * 8 * (k + r))))
     n_pad = n_tiles * tile
     if n_pad != n:
         data = jnp.pad(data, ((0, 0), (0, n_pad - n)))
@@ -119,11 +160,11 @@ def gf_apply_stripes_pallas(mat: jax.Array, data: jax.Array, stripes: int,
 
     out = pl.pallas_call(
         functools.partial(_gf_stripes_kernel, r=r, k=k, groups=groups),
-        out_shape=jax.ShapeDtypeStruct((s_total * r, n_pad), jnp.uint8),
+        out_shape=_out_like(data, (s_total * r, n_pad)),
         grid=(s_total // groups, n_tiles),
         in_specs=[
             pl.BlockSpec((groups * 8 * r, groups * 8 * k),
-                         lambda i, j: (0, 0)),
+                         lambda i, j: (_I0, _I0)),
             pl.BlockSpec((groups * k, tile), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((groups * r, tile), lambda i, j: (i, j)),
@@ -162,7 +203,6 @@ def gf_apply_pallas(mat: jax.Array, data: jax.Array,
     internally (zero GF columns contribute zero parity).
     """
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
     mat = jnp.asarray(mat, dtype=jnp.uint8)
     data = jnp.asarray(data, dtype=jnp.uint8)
@@ -170,25 +210,20 @@ def gf_apply_pallas(mat: jax.Array, data: jax.Array,
     _, n = data.shape
     bmat = expand_bits_plane_major(mat).astype(jnp.int8)
 
-    # pick the tile so padding waste stays < 128 columns per tile (a fixed
-    # 8k tile would do up to 8x wasted work at N just over a tile boundary):
-    # spread N over ceil(N/tile) tiles of the smallest 128-multiple size
-    n_tiles = max(1, -(-n // tile_n))
-    tile_n = max(128, (-(-n // n_tiles) + 127) // 128 * 128)
+    n_tiles, tile_n = _lane_tiles(n, tile_n)
     n_pad = n_tiles * tile_n
     if n_pad != n:
         data = jnp.pad(data, ((0, 0), (0, n_pad - n)))
-    grid = (n_tiles,)
 
     out = pl.pallas_call(
         functools.partial(_gf_kernel, r=r, k=k),
-        out_shape=jax.ShapeDtypeStruct((r, n_pad), jnp.uint8),
-        grid=grid,
+        out_shape=_out_like(data, (r, n_pad)),
+        grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((8 * r, 8 * k), lambda i: (0, 0)),
-            pl.BlockSpec((k, tile_n), lambda i: (0, i)),
+            pl.BlockSpec((8 * r, 8 * k), lambda i: (_I0, _I0)),
+            pl.BlockSpec((k, tile_n), lambda i: (_I0, i)),
         ],
-        out_specs=pl.BlockSpec((r, tile_n), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((r, tile_n), lambda i: (_I0, i)),
         interpret=interpret,
     )(bmat, data)
     return out[:, :n] if n_pad != n else out
@@ -203,6 +238,12 @@ def _xor_kernel(w_ref, data_ref, out_ref):
                                      data_ref[:].astype(jnp.int32))
 
 
+# the xor kernel's working set is 8 bit-planes of its K input and R
+# output rows per tile column; this is the product liberation's [14, 28]
+# compiles at with 16384-column tiles inside scoped VMEM on a v5e
+_XOR_VMEM_ELEMS = (14 + 28) * 16384
+
+
 @functools.partial(jax.jit, static_argnames=("tile_n", "interpret"))
 def xor_apply_pallas(W: jax.Array, packets: jax.Array,
                      tile_n: int = 16384,
@@ -210,8 +251,9 @@ def xor_apply_pallas(W: jax.Array, packets: jax.Array,
     """Fused packet-layout bitmatrix apply: W [R, K] 0/1, packets [K, P]
     uint8 -> [R, P].  The data path of the bitmatrix techniques and the
     wide-word (w=16/32) codes: bit-plane inflation stays in VMEM.  Row
-    counts ride full blocks, so any (R, K) — e.g. liberation's [14, 28]
-    or w=32 reed_sol's [64, 128] — lowers without padding games."""
+    counts ride full blocks and the tile narrows as R + K grows, so any
+    (R, K) — liberation's [14, 28] as much as w=32 reed_sol's
+    [64, 128] — lowers within scoped VMEM."""
     from jax.experimental import pallas as pl
 
     W = jnp.asarray(W, dtype=jnp.int8)
@@ -219,20 +261,19 @@ def xor_apply_pallas(W: jax.Array, packets: jax.Array,
     r, k = W.shape
     kk, p = packets.shape
     assert kk == k
-    n_tiles = max(1, -(-p // tile_n))
-    tile = max(128, (-(-p // n_tiles) + 127) // 128 * 128)
+    n_tiles, tile = _lane_tiles(p, min(tile_n, _XOR_VMEM_ELEMS // (r + k)))
     p_pad = n_tiles * tile
     if p_pad != p:
         packets = jnp.pad(packets, ((0, 0), (0, p_pad - p)))
     out = pl.pallas_call(
         _xor_kernel,
-        out_shape=jax.ShapeDtypeStruct((r, p_pad), jnp.uint8),
+        out_shape=_out_like(packets, (r, p_pad)),
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((r, k), lambda i: (0, 0)),
-            pl.BlockSpec((k, tile), lambda i: (0, i)),
+            pl.BlockSpec((r, k), lambda i: (_I0, _I0)),
+            pl.BlockSpec((k, tile), lambda i: (_I0, i)),
         ],
-        out_specs=pl.BlockSpec((r, tile), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((r, tile), lambda i: (_I0, i)),
         interpret=interpret,
     )(W, packets)
     return out[:, :p] if p_pad != p else out

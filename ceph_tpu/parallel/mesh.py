@@ -23,14 +23,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import rs_kernels
-
-try:                                    # jax >= 0.8 moved it out of
-    from jax import shard_map as _shard_map   # experimental
-except ImportError:                     # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def make_mesh(n_devices: int | None = None, dp: int | None = None) -> Mesh:
@@ -200,15 +196,10 @@ def sharded_placement_step(mesh: Mesh, bulk, ruleno: int, n_osds: int,
         hist = jax.lax.psum(hist, axis_name="dp")     # ICI all-reduce
         return out, hist
 
-    # Disable the replication/varying-axes checker: the CRUSH kernel's
-    # bounded-retry loops initialise carries from literals (unvarying)
-    # and update them from the dp-varying seeds — sound, but unprovable
-    # for the checker.  The kwarg is check_vma on jax >= 0.8 and
-    # check_rep on the experimental fallback import.
-    import inspect
-    kw = ("check_vma" if "check_vma" in
-          inspect.signature(_shard_map).parameters else "check_rep")
+    # Disable the varying-axes checker: the CRUSH kernel's bounded-retry
+    # loops initialise carries from literals (unvarying) and update them
+    # from the dp-varying seeds — sound, but unprovable for the checker.
     return jax.jit(_shard_map(local, mesh=mesh,
                               in_specs=(P("dp"),),
                               out_specs=(P("dp"), P(None)),
-                              **{kw: False}))
+                              check_vma=False))

@@ -1,7 +1,6 @@
 """Which-kernel-executed guards.
 
-Round-4 postmortem (BASELINE.md "dispatch-detection postscript"):
-`_runs_on_tpu` once mapped the ConcretizationTypeError a Tracer raises
+Round-4 postmortem: `_runs_on_tpu` once mapped the ConcretizationTypeError a Tracer raises
 from `.devices()` to "not TPU", so every JITTED caller — including the
 bench chain — silently took the XLA bitslice fallback instead of the
 pallas kernel, and the bench quietly measured the wrong kernel.  These
@@ -11,9 +10,10 @@ tests pin the dispatch contract so that failure mode cannot recur:
 - a jitted caller at bench-like shapes actually INVOKES the pallas
   kernel (recorded via monkeypatch, executed in interpret mode on CPU);
 - the sharded multichip step routes through the SAME production
-  selector (`gf_apply_stripes`) as the single-chip bench;
-- on a real TPU, the lowered HLO of the bench apply contains the pallas
-  custom call (skipped elsewhere).
+  selector (`gf_apply_stripes`) as the single-chip bench.
+
+That the selector's TPU branch really lowers to the Mosaic custom call is
+checked against a described v5e in tests/test_tpu_lowering.py.
 """
 import jax
 import jax.numpy as jnp
@@ -119,18 +119,3 @@ def test_sharded_step_routes_through_production_selector(monkeypatch):
     for b in range(data.shape[0]):
         np.testing.assert_array_equal(np.asarray(parity[b]),
                                       ref.encode(pm, data[b]))
-
-
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="real-TPU lowering check")
-def test_bench_apply_lowers_to_pallas_on_tpu():
-    """On real hardware the jitted bench apply must contain the Mosaic
-    custom call — the direct form of the dispatch guard."""
-    rng = np.random.default_rng(10)
-    mat = jnp.asarray(cauchy1(K, M))
-    data = jnp.asarray(rng.integers(0, 256, size=(S * K, 128 * 1024),
-                                    dtype=np.uint8))
-    txt = jax.jit(
-        lambda Mt, D: rs_kernels.gf_apply_stripes(Mt, D, S)).lower(
-            mat, data).as_text()
-    assert ("tpu_custom_call" in txt) or ("pallas" in txt)

@@ -11,20 +11,23 @@ from ceph_tpu.ops.pallas_kernels import (expand_bits_plane_major,
                                          gf_apply_stripes_pallas)
 
 
-@pytest.mark.parametrize("r,k,S,n,groups,tile", [
-    (4, 8, 8, 1024, 4, 512),     # even groups
-    (4, 8, 6, 1024, 4, 512),     # stripe count not a group multiple
-    (2, 4, 3, 700, 4, 256),      # ragged columns + groups > stripes
-    (4, 8, 1, 512, 4, 512),      # single stripe
+@pytest.mark.parametrize("r,k,S,n,tile", [
+    (4, 8, 8, 1024, 512),     # even groups of 4
+    (4, 8, 6, 1024, 512),     # stripe count not a group multiple
+    (2, 4, 3, 700, 256),      # ragged columns + fewer stripes than a group
+    (4, 8, 1, 512, 512),      # single stripe
+    (1, 8, 16, 512, 256),     # single-erasure decode: groups of 8
+    (3, 7, 12, 512, 256),     # m=3 over odd k: groups of 8 + stripe pad
+    (3, 10, 5, 384, 128),     # odd r, batch shorter than one group
 ])
-def test_stripes_kernel_matches_field_math(r, k, S, n, groups, tile):
+def test_stripes_kernel_matches_field_math(r, k, S, n, tile):
     """Vertical layout: stripe s = rows [s*k, (s+1)*k); parity at
     [s*r, (s+1)*r).  Bit-exact vs per-stripe host math."""
     rng = np.random.default_rng(r * 1000 + S)
     mat = rng.integers(0, 256, size=(r, k), dtype=np.uint8)
     data = rng.integers(0, 256, size=(S * k, n), dtype=np.uint8)
     got = np.asarray(gf_apply_stripes_pallas(
-        mat, data, S, groups=groups, tile_n=tile, interpret=True))
+        mat, data, S, tile_n=tile, interpret=True))
     assert got.shape == (S * r, n)
     for s in range(S):
         want = gfm.gf_matmul(mat, data[s * k:(s + 1) * k])

@@ -281,16 +281,32 @@ class TestMainAndHistory:
                                        str(tmp_path))
         assert not res["ok"]
 
-    def test_repo_history_gates_the_r05_artifact(self):
-        """The real repo history: BENCH_r05 (the silent CPU fallback)
-        must FAIL the gate against it."""
-        repo = Path(__file__).resolve().parent.parent
-        if not (repo / "BENCH_r05.json").exists():
-            pytest.skip("no BENCH history in this checkout")
-        with open(repo / "BENCH_r05.json") as f:
-            r05 = json.load(f)
+    def test_legacy_history_gates_a_cpu_fallback_artifact(self, tmp_path):
+        """A history in the shape the first five rounds left — device
+        lines with no ``device`` field, then an rc=1 round with nothing
+        parsed, then a host number written under the device metric's
+        name with ``device: cpu`` and an ``error`` — must FAIL that last
+        artifact as a platform fallback, not accept it as a slower
+        device number.  (The real files recorded a set-up that is gone
+        and were deleted in PR 21; this keeps their shapes.)"""
+        legacy = {"metric": "rs_k8m4_1MiB_encode_decode_device_resident",
+                  "unit": "MiB/s"}
+        for n, value in ((1, 32686.1), (2, 24437.0), (3, 32222.3)):
+            self._write(tmp_path, f"BENCH_r0{n}.json",
+                        {"n": n, "rc": 0,
+                         "parsed": dict(legacy, value=value,
+                                        vs_baseline=4.0)})
+        self._write(tmp_path, "BENCH_r04.json",
+                    {"n": 4, "rc": 1, "parsed": None})
+        r05 = {"n": 5, "rc": 0,
+               "parsed": dict(legacy, value=7532.2, vs_baseline=1.0,
+                              device="cpu", cpu_kind="simd",
+                              error="tpu backend unavailable after "
+                                    "bounded init retries")}
+        self._write(tmp_path, "BENCH_r05.json", r05)
+        assert perf_gate.expected_platform(str(tmp_path)) == "tpu"
         res = perf_gate.evaluate(
             r05, None, expect_platform=perf_gate.expected_platform(
-                str(repo)))
+                str(tmp_path)))
         assert not res["ok"]
         assert any("platform fallback" in x for x in res["failures"])

@@ -62,10 +62,16 @@ class TestPeaks:
         assert p["source"] == "registry:v5e"
         assert p["ridge_flops_per_byte"] == pytest.approx(197e12 / 819e9)
 
-    def test_unknown_tpu_defaults_to_baseline_hardware(self):
+    def test_unknown_tpu_has_no_peaks(self):
         p = roofline.lookup_peaks(device_kind="TPU v99", platform="tpu")
-        assert p["source"] == "default-tpu(v5e)"
-        assert p["hbm_bytes_s"] == 819e9
+        assert p["source"] == "unknown-tpu(TPU v99)"
+        assert p["flops"] == 0.0 and p["hbm_bytes_s"] == 0.0
+        assert p["ridge_flops_per_byte"] == 0.0
+
+    def test_v5e_reports_v5_lite_and_stays_in_the_table(self):
+        p = roofline.lookup_peaks(device_kind="TPU v5 lite", platform="tpu")
+        assert p["source"] == "registry:v5 lite"
+        assert p["flops"] == 197e12 and p["hbm_bytes_s"] == 819e9
 
     def test_cpu_falls_back_to_nominal(self):
         p = roofline.lookup_peaks(device_kind="cpu", platform="cpu")

@@ -749,8 +749,8 @@ def run_zero_copy_pair(n_clients: int = 256, ops_per_client: int = 4,
 
 
 def main(argv=None) -> int:
-    from ceph_tpu.utils.platform import honour_jax_platforms_env
-    honour_jax_platforms_env()
+    from ceph_tpu.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="rados_bench", description=__doc__.splitlines()[0])
     ap.add_argument("--mode", choices=["closed", "open", "mux"],
