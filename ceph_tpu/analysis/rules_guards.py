@@ -357,7 +357,7 @@ def check_span_owner(index: ProjectIndex) -> list[Finding]:
 
 _PHASE_SCOPE = ("ceph_tpu/exec", "ceph_tpu/recovery",
                 "ceph_tpu/ops/pipeline.py", "ceph_tpu/tier")
-_PHASE_CALLS = {"trace_span", "span", "complete"}
+_PHASE_CALLS = {"trace_span", "span", "observe"}
 
 
 @rule("span-phase", severity="error", scope=_PHASE_SCOPE,

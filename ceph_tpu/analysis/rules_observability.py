@@ -16,7 +16,7 @@ Heuristics, deliberately narrow to keep the signal clean:
 - unambiguous instrument method names (``tinc``/``hinc``/``account_*``/
   ``observe_rpc``/``note_queue_depth``/``trace_span``/``trace_instant``)
   flag on the name alone;
-- generic names (``inc``/``dec``/``set``/``complete``/``instant``/
+- generic names (``inc``/``dec``/``set``/``observe``/``instant``/
   ``flush``) flag only when the receiver chain names an instrument
   object (``...perf.inc``, ``self.acct...``, ``tracer...``), so plain
   ``dict.set``-style calls never trip it.
@@ -37,10 +37,10 @@ _SCOPE = ("ceph_tpu/msg",)
 # method names that are instruments wherever they appear
 _ALWAYS = {"tinc", "hinc", "account_tx", "account_rx", "account_msg",
            "observe_rpc", "note_queue_depth", "trace_span",
-           "trace_instant", "mark_event"}
+           "trace_instant", "mark_event", "stamp_calls"}
 
 # generic method names: instruments only on an instrument-ish receiver
-_GENERIC = {"inc", "dec", "set", "complete", "instant", "flush", "time"}
+_GENERIC = {"inc", "dec", "set", "observe", "instant", "flush", "time"}
 
 # receiver-chain fragments that identify an instrument object
 _RECEIVER_HINTS = ("perf", "acct", "tracer", "accounting", "counters")
@@ -61,7 +61,7 @@ def _receiver_chain(call: ast.Call) -> str:
         parts.append(node.id)
     elif isinstance(node, ast.Call) and \
             isinstance(node.func, ast.Name):
-        # default_tracer().complete(...) — the factory name is the hint
+        # default_tracer().observe(...) — the factory name is the hint
         parts.append(node.func.id)
     return ".".join(reversed(parts))
 

@@ -39,6 +39,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any
 
+from ..common.tracer import trace_span
 from .ecutil import crc32c
 from .memstore import GObject, Transaction, _Object
 
@@ -350,44 +351,47 @@ class BlueStoreLite:
         new_blobs: dict[int, Blob] = {}
         deref: list[int] = []       # blob ids losing one reference
         addref: list[int] = []      # blob ids gaining one (clone/split)
-        try:
-            for op in t.ops:
-                self._apply(staged, op, new_blobs, deref, addref)
-        except Exception:
-            # all-or-nothing: orphan the data already written for this
-            # transaction (nothing references it) and free its space
-            for bid, b in new_blobs.items():
-                self.blobs.pop(bid, None)
-                self.alloc.free(b.poff, b.alloc)
-            raise
-        # commit: refcounts, onode table, WAL
-        for bid in addref:
-            self.blobs[bid].refs += 1
-        freed: list[int] = []
-        self._deref(deref, freed)
-        for obj, onode in staged.items():
-            if onode is None:
-                self.onodes.pop(obj, None)
-            else:
-                self.onodes[obj] = onode
-        self.committed_seq += 1
-        payload = pickle.dumps(
-            (self.committed_seq, staged,
-             {bid: self.blobs[bid] for bid in
-              set(new_blobs) - set(freed)} |
-             {bid: self.blobs[bid] for bid in addref + deref
-              if bid in self.blobs},
-             freed, self.next_blob),
-            protocol=pickle.HIGHEST_PROTOCOL)
-        self._block.flush()          # data precedes its metadata
-        if self.sync:
-            os.fsync(self._block.fileno())
-        self._wal.write(_FRAME.pack(len(payload),
-                                    crc32c(0xFFFFFFFF, payload)))
-        self._wal.write(payload)
-        self._wal.flush()
-        if self.sync:
-            os.fsync(self._wal.fileno())
+        # what makes the transaction durable: the block writes (in
+        # _apply), the block flush + fsync, the WAL append + fsync
+        with trace_span("store.commit"):
+            try:
+                for op in t.ops:
+                    self._apply(staged, op, new_blobs, deref, addref)
+            except Exception:
+                # all-or-nothing: orphan the data already written for this
+                # transaction (nothing references it) and free its space
+                for bid, b in new_blobs.items():
+                    self.blobs.pop(bid, None)
+                    self.alloc.free(b.poff, b.alloc)
+                raise
+            # commit: refcounts, onode table, WAL
+            for bid in addref:
+                self.blobs[bid].refs += 1
+            freed: list[int] = []
+            self._deref(deref, freed)
+            for obj, onode in staged.items():
+                if onode is None:
+                    self.onodes.pop(obj, None)
+                else:
+                    self.onodes[obj] = onode
+            self.committed_seq += 1
+            payload = pickle.dumps(
+                (self.committed_seq, staged,
+                 {bid: self.blobs[bid] for bid in
+                  set(new_blobs) - set(freed)} |
+                 {bid: self.blobs[bid] for bid in addref + deref
+                  if bid in self.blobs},
+                 freed, self.next_blob),
+                protocol=pickle.HIGHEST_PROTOCOL)
+            self._block.flush()          # data precedes its metadata
+            if self.sync:
+                os.fsync(self._block.fileno())
+            self._wal.write(_FRAME.pack(len(payload),
+                                        crc32c(0xFFFFFFFF, payload)))
+            self._wal.write(payload)
+            self._wal.flush()
+            if self.sync:
+                os.fsync(self._wal.fileno())
         self._wal_records += 1
         if self._wal_records >= self.checkpoint_every:
             self.checkpoint()

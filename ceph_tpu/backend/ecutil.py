@@ -15,6 +15,7 @@ import functools
 import numpy as np
 
 from ..common import copy_ledger
+from ..common.tracer import trace_span
 
 # -- crc32c (Castagnoli), seed-chained like ceph_crc32c ----------------------
 # HashInfo chains bufferlist::crc32c(seed) per shard with initial seed -1
@@ -458,9 +459,14 @@ def hinfo_append(hinfo: HashInfo, old_size: int,
                 if nbytes else None
             if codec is not None:
                 shards = sorted(chunks)
-                rows = np.stack([_as_u8(chunks[s]) for s in shards])
                 from ..ops import rs_kernels
-                crc0 = np.asarray(rs_kernels.crc32c_rows(rows))
+                with trace_span("ec.hinfo_crc", rows=len(shards),
+                                bytes=nbytes * len(shards)):
+                    rows = np.stack([_as_u8(chunks[s]) for s in shards])
+                    crc_dev = rs_kernels.crc32c_rows(rows)
+                    # the fetch is where the host blocks on the device
+                    with trace_span("ec.hinfo_crc.wait"):
+                        crc0 = np.asarray(crc_dev)
                 hinfo.append_crcs(old_size,
                                   {s: int(c)
                                    for s, c in zip(shards, crc0)}, nbytes)

@@ -29,6 +29,7 @@ import pickle
 import struct
 from pathlib import Path
 
+from ..common.tracer import trace_span
 from .ecutil import crc32c
 from .memstore import GObject, MemStore, Transaction, _Object
 
@@ -130,9 +131,10 @@ class FileStore:
     def queue_transaction(self, t: Transaction) -> int:
         # apply first (all-or-nothing staging) so only transactions that
         # succeed reach the log; then journal before acking the caller
-        seq = self._mem.queue_transaction(t)
-        self._append_wal(pickle.dumps((seq, t.ops),
-                                      protocol=pickle.HIGHEST_PROTOCOL))
+        with trace_span("store.commit"):
+            seq = self._mem.queue_transaction(t)
+            self._append_wal(pickle.dumps(
+                (seq, t.ops), protocol=pickle.HIGHEST_PROTOCOL))
         self._wal_records += 1
         if self._wal_records >= self.checkpoint_every:
             self.checkpoint()

@@ -1148,13 +1148,12 @@ class MiniCluster:
             # fragments the batch — when demand overruns the bound,
             # bounded memory wins over maximal coalescing.
             import time as _time
-            t0 = _time.monotonic()
+            t0 = _time.perf_counter()
             daemon.drain()
-            backoff = _time.monotonic() - t0
             # the bounce + drain is this op's backoff-and-resend time:
             # stamped as `retry` phase in its trace
-            tr.complete("client.backoff_resend", _time.time() - backoff,
-                        backoff, ctx=trace_ctx, oid=oid)
+            tr.observe("client.backoff_resend", t0, ctx=trace_ctx,
+                       oid=oid)
             res = daemon.ms_dispatch(g.pgid, m, _done)
         if res is not None:
             return res

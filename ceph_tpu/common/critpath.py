@@ -103,6 +103,10 @@ PHASES = (QUEUE, ADMISSION, BATCH_DELAY, DISPATCH, DEVICE, WIRE, RETRY,
 SPAN_PHASES: dict[str, str] = {
     # queue: emitted by the OSD daemon when a queued op finally runs
     "osd.queue_wait": QUEUE,
+    # queue: a served call waiting for a dispatch worker (msg/server.py
+    # Dispatcher) and then for the one cluster lock (net.py _dispatch)
+    "msgr.dispatch_queue_wait": QUEUE,
+    "rpc.lock_wait": QUEUE,
     # admission: serving-engine throttle wait (emitted only when the
     # throttle actually blocked the submitter)
     "serving.admission": ADMISSION,
@@ -130,6 +134,12 @@ SPAN_PHASES: dict[str, str] = {
     # dispatcher) — cross-daemon frame time, hence wire
     "mux.batch_send": WIRE,
     "mux.batch_reply": WIRE,
+    # the served call's own frames (msg/connection.py, msg/server.py):
+    # first byte -> decoded, reply serialise + enqueue, enqueue -> last
+    # byte accepted by the socket
+    "msgr.frame_rx": WIRE,
+    "msgr.reply_send": WIRE,
+    "msgr.reply_drain": WIRE,
     # device: compute + transfers (the codec spans wrap the actual
     # device/SIMD work; ec.* self-time is pack/scatter around it)
     "codec.encode": DEVICE,
@@ -142,6 +152,15 @@ SPAN_PHASES: dict[str, str] = {
     "serving.batch_encode": DEVICE,
     "serving.batch_decode": DEVICE,
     "pipeline.complete": DEVICE,
+    # its three parts: the wait for the device, the device->host copy,
+    # and the host's relayout of what came back
+    "pipeline.device_wait": DEVICE,
+    "pipeline.fetch": DEVICE,
+    "pipeline.unpack": DISPATCH,
+    # the HashInfo checksum of a put's shards: stack + device crc + the
+    # blocking fetch (backend/ecutil.py hinfo_append)
+    "ec.hinfo_crc": DEVICE,
+    "ec.hinfo_crc.wait": DEVICE,
     "ec.encode": DEVICE,
     "ec.decode": DEVICE,
     "ec.decode_wave": DEVICE,
@@ -157,6 +176,9 @@ SPAN_PHASES: dict[str, str] = {
     "client.rpc": OTHER,
     "osd.op": OTHER,
     "serving.op": OTHER,
+    # a store's durable commit of one transaction (block writes, WAL,
+    # fsyncs): host I/O no dedicated phase names
+    "store.commit": OTHER,
     "backfill.pg": OTHER,
     # cache tier (tier/service.py): the proxy read forwards across the
     # tier boundary to the base pool (wire-shaped hop); promotion,

@@ -34,6 +34,9 @@ class TrackedOp:
     events: list[tuple[float, str]] = field(default_factory=list)
     slow: bool = False
     _done: bool = False
+    # the same instant as ``initiated_at`` on the tracer's clock: the
+    # dumps keep wall time, the ``op`` span is stamped from this
+    _t0: float = field(default_factory=time.perf_counter)
 
     def mark_event(self, event: str) -> None:
         self.events.append((time.time(), event))
@@ -45,10 +48,9 @@ class TrackedOp:
             self._done = True
             self.mark_event("done")
             self.tracker._finish(self)
-            default_tracer().complete("op", self.initiated_at,
-                                      self.duration, cat="optracker",
-                                      seq=self.seq, desc=self.description,
-                                      slow=self.slow)
+            default_tracer().observe("op", self._t0, cat="optracker",
+                                     seq=self.seq, desc=self.description,
+                                     slow=self.slow)
 
     @property
     def age(self) -> float:

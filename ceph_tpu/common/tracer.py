@@ -230,10 +230,8 @@ class Tracer:
         # owner appends without the lock (single writer + GIL), batches
         # fold under the ring lock
         self._pending: dict[int, list] = {}
-        # paired clocks: spans stamp with perf_counter; wall-clock sources
-        # (TrackedOp timelines) map through the epoch pair
+        # the one clock: every event is stamped from perf_counter
         self._t0 = time.perf_counter()
-        self._wall0 = time.time()
         self.pid = os.getpid()
         # span-name -> [bucket_counts..., overflow] plus (sum, count)
         self._hist: dict[str, list] = {}
@@ -375,41 +373,39 @@ class Tracer:
         self._emit(ev)
 
     def observe(self, name: str, t0: float, t1: float | None = None,
-                cat: str = "") -> None:
+                cat: str = "", ctx: TraceContext | None = None,
+                track: str | None = None, **args) -> None:
         """Record a finished region measured with ``time.perf_counter()``
-        — the allocation-light fast path for hot UNTRACED spans (the
-        per-op rpc dispatch).  No Span object, no context-manager
-        protocol, no event dict: a lite tuple rides the pending buffer
-        and the ring, and :meth:`dump` materializes whatever survived
-        eviction.  Use :meth:`span` whenever a TraceContext may be
-        active — this path carries no trace linkage."""
+        (``t1`` defaults to now) — the ONE after-the-fact path: waits
+        measured at dequeue, sends measured at return, TrackedOp ops.
+
+        Bare (no ``ctx``/``track``/args) it is the allocation-light fast
+        path for hot untraced regions: no Span object, no event dict, a
+        lite tuple rides the pending buffer and the ring, and
+        :meth:`dump` materializes whatever survived eviction.  With
+        ``ctx`` the event joins that distributed trace as a child span —
+        trace/span/parent ids, ``op_class`` and the head-sampling
+        decision stamped exactly as :meth:`_finish_span` stamps a live
+        span (an unsampled context drops the event unless it crossed
+        ``slow_threshold_s``).  Linkage is EXPLICIT opt-in, never
+        ambient, so an event recorded under somebody else's active
+        context does not become a node of that tree."""
         if not instruments.enabled():
             return
         if t1 is None:
             t1 = time.perf_counter()
-        # inlined _emit_lite: this is the single hottest instrument call
-        # (one per RPC dispatch), so it pays for zero extra frames
-        buf = getattr(self._local, "pending", None)
-        if buf is None:
-            buf = self._pending_buf()
-        buf.append((name, cat, (t0 - self._t0) * 1e6, (t1 - t0) * 1e6,
-                    threading.get_ident()))
-        if len(buf) >= FLUSH_BATCH:
-            self._flush_buf(buf)
-
-    def complete(self, name: str, start_wall: float, dur_s: float,
-                 cat: str = "", ctx: TraceContext | None = None,
-                 **args) -> None:
-        """A span observed externally on the WALL clock (TrackedOp ops,
-        queue/batch/backoff waits measured after the fact): mapped onto
-        the tracer timeline via the paired epochs.  With ``ctx`` the
-        event joins that distributed trace as a child span (trace/span/
-        parent ids + op_class stamped like a live span) so the
-        critical-path ledger can attribute it — linkage is EXPLICIT
-        opt-in, never ambient, so TrackedOp timelines that happen to
-        run under an active context don't double-count as tree nodes."""
-        if not instruments.enabled():
+        if ctx is None and track is None and not args:
+            # inlined _emit_lite: this is the single hottest instrument
+            # call (one per RPC dispatch), so it pays for zero extra frames
+            buf = getattr(self._local, "pending", None)
+            if buf is None:
+                buf = self._pending_buf()
+            buf.append((name, cat, (t0 - self._t0) * 1e6, (t1 - t0) * 1e6,
+                        threading.get_ident()))
+            if len(buf) >= FLUSH_BATCH:
+                self._flush_buf(buf)
             return
+        dur_s = t1 - t0
         promoted = False
         if ctx is not None and not getattr(ctx, "sampled", True):
             if dur_s < self.slow_threshold_s:
@@ -418,9 +414,8 @@ class Tracer:
                 return
             promoted = True                  # slow op: into the ring anyway
             self._drop_micro(ctx.trace_id)
-        ev = {"name": name, "cat": cat or "op", "ph": "X",
-              "ts": (start_wall - self._wall0) * 1e6,
-              "dur": dur_s * 1e6,
+        ev = {"name": name, "cat": cat or "span", "ph": "X",
+              "ts": (t0 - self._t0) * 1e6, "dur": dur_s * 1e6,
               "pid": self.pid, "tid": threading.get_ident()}
         if ctx is not None:
             args["trace_id"] = ctx.trace_id
@@ -434,6 +429,8 @@ class Tracer:
                 args["sample_weight"] = ctx.weight
         if args:
             ev["args"] = args
+        if track is not None:
+            ev["track"] = track
         self._emit(ev, name, dur_s)
 
     def _finish_span(self, span: Span) -> None:

@@ -39,12 +39,12 @@ class BatchFuture:
     """Completion handle for one submitted op (concurrent.futures shape)."""
 
     __slots__ = ("kind", "payload", "sinfo", "ec_impl", "op_class",
-                 "cost_bytes", "t_submit", "t_submit_wall", "t_dispatch",
+                 "cost_bytes", "t_submit", "t_submit_pc", "t_dispatch",
                  "t_done", "eager", "trace", "_event", "_result",
                  "_error", "_callbacks", "_lock")
 
     def __init__(self, kind: str, payload, sinfo, ec_impl, op_class: str,
-                 cost_bytes: int, t_submit: float, t_submit_wall: float,
+                 cost_bytes: int, t_submit: float, t_submit_pc: float,
                  eager: bool = False, trace=None):
         self.kind = kind
         self.payload = payload
@@ -53,7 +53,9 @@ class BatchFuture:
         self.op_class = op_class
         self.cost_bytes = cost_bytes
         self.t_submit = t_submit
-        self.t_submit_wall = t_submit_wall
+        # the same instant on the tracer's clock (perf_counter): the
+        # engine's after-the-fact wait spans start here
+        self.t_submit_pc = t_submit_pc
         self.t_dispatch = 0.0
         self.t_done = 0.0
         # eager: a submitter is BLOCKED on this op (sync encode()/

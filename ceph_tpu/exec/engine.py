@@ -243,12 +243,12 @@ class ServingEngine:
         would split admission and batch_wait across traces)."""
         tr = default_tracer()
         ctx = tr.current_ctx()
-        t0 = time.monotonic()
+        t0 = time.perf_counter()
         self._admit(cost_bytes)
-        wait = time.monotonic() - t0
-        if ctx is not None and wait >= self.ADMISSION_TRACE_FLOOR_S:
-            tr.complete("serving.admission", time.time() - wait, wait,
-                        ctx=ctx, engine=self.name)
+        t1 = time.perf_counter()
+        if ctx is not None and t1 - t0 >= self.ADMISSION_TRACE_FLOOR_S:
+            tr.observe("serving.admission", t0, t1, ctx=ctx,
+                       engine=self.name)
         return ctx
 
     def _admit(self, cost_bytes: int) -> None:
@@ -308,7 +308,8 @@ class ServingEngine:
         cost = int(arr.nbytes)
         ctx = self._admit_traced(cost)
         op = BatchFuture(ENCODE, arr, sinfo, ec, op_class, cost,
-                         time.monotonic(), time.time(), eager=eager,
+                         time.monotonic(), time.perf_counter(),
+                         eager=eager,
                          trace=ctx)
         return self._enqueue(op)
 
@@ -326,7 +327,8 @@ class ServingEngine:
         cost = int(sum(v.nbytes for v in payload.values()))
         ctx = self._admit_traced(cost)
         op = BatchFuture(DECODE, payload, sinfo, ec, op_class, cost,
-                         time.monotonic(), time.time(), eager=eager,
+                         time.monotonic(), time.perf_counter(),
+                         eager=eager,
                          trace=ctx)
         return self._enqueue(op)
 
@@ -418,6 +420,7 @@ class ServingEngine:
 
     def _dispatch(self, ops: list[BatchFuture]) -> None:
         t = time.monotonic()
+        t_pc = time.perf_counter()
         tr = default_tracer()
         for op in ops:
             op.t_dispatch = t
@@ -427,9 +430,8 @@ class ServingEngine:
                 # the submit-to-dispatch wait IS the batch-formation
                 # deadline the op paid: stamped into the op's trace so
                 # the critical-path ledger attributes `batch_delay`
-                tr.complete("serving.batch_wait", op.t_submit_wall,
-                            t - op.t_submit, ctx=op.trace,
-                            engine=self.name)
+                tr.observe("serving.batch_wait", op.t_submit_pc, t_pc,
+                           ctx=op.trace, engine=self.name)
         self.perf.inc("batches")
         self.perf.inc("ops_coalesced", len(ops))
         self.perf.hinc("batch_size", len(ops))
@@ -459,8 +461,8 @@ class ServingEngine:
             self.perf.inc("ops_failed")
         self.perf.tinc("e2e_time", e2e)
         self.perf.hinc("op_e2e_lat", e2e)
-        default_tracer().complete("serving.op", op.t_submit_wall, e2e,
-                                  kind=op.kind, op_class=op.op_class)
+        default_tracer().observe("serving.op", op.t_submit_pc,
+                                 kind=op.kind, op_class=op.op_class)
         # finisher completion boundary: fold this thread's pending span
         # batch into the tracer ring once per retired op
         default_tracer().flush()

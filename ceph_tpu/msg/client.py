@@ -296,12 +296,10 @@ class MuxClient:
                 self._fail_all(ConnectionError("reconnect exhausted"))
                 continue
             try:
-                t_send = time.monotonic()
-                wall = time.time()
+                t_send = time.perf_counter()
                 conn.send(msg)
                 if len(calls) > 1:
-                    self._stamp_batch(live, wall,
-                                      time.monotonic() - t_send,
+                    self._stamp_batch(live, t_send, time.perf_counter(),
                                       len(calls))
                 with self._cond:
                     self.batches_sent += 1
@@ -317,7 +315,7 @@ class MuxClient:
                     self._cond.notify()
 
     @staticmethod
-    def _stamp_batch(live, wall: float, dur: float, n: int) -> None:
+    def _stamp_batch(live, t0: float, t1: float, n: int) -> None:
         """Wire-phase spans for calls riding a batched RpcBatch frame:
         each riding call's trace gets one ``mux.batch_send`` child
         covering the coalesced serialize+enqueue, so critical-path
@@ -329,8 +327,8 @@ class MuxClient:
         tr = default_tracer()
         for c in live:
             if getattr(c.trace, "trace_id", None):
-                tr.complete("mux.batch_send", wall, dur, cat="mux",
-                            ctx=c.trace, batched_calls=n)
+                tr.observe("mux.batch_send", t0, t1, cat="mux",
+                           ctx=c.trace, batched_calls=n)
         # sender-loop completion boundary: fold this thread's pending
         # batch into the ring once per frame, not once per riding call
         tr.flush()

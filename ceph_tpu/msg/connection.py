@@ -28,9 +28,11 @@ from __future__ import annotations
 
 import socket
 import threading
+import time
 
 from ..backend.wire import WireError, frame_encode  # noqa: F401
-from ..common import wire_accounting
+from ..common import instruments, wire_accounting
+from ..common.tracer import default_tracer
 from ..exec.throttle import Throttle
 from .parser import StreamParser
 
@@ -44,6 +46,28 @@ SEND_TIMEOUT = 5.0
 _SENDMSG_MAX_BUFS = 64
 _SENDMSG_MAX_BYTES = 1 << 20
 _HAS_SENDMSG = hasattr(socket.socket, "sendmsg")
+
+
+def riding_calls(msg) -> tuple | list:
+    """The calls (or results) a frame carries: a batch frame's members,
+    the message itself for a single RpcCall / RpcResult, nothing for
+    every other frame type (handshake steps, notify traffic)."""
+    riders = getattr(msg, "calls", None) or getattr(msg, "results", None)
+    if riders:
+        return riders
+    return (msg,) if hasattr(msg, "trace") else ()
+
+
+def stamp_calls(name: str, t0: float, t1: float, riders,
+                track: str | None) -> None:
+    """One ``msgr.*`` span of [t0, t1] (``time.perf_counter`` values)
+    per riding call, a child of that call's TraceContext on the daemon's
+    track; an untraced call keeps the tracer's lite path."""
+    tr = default_tracer()
+    for c in riders:
+        ctx = getattr(c, "trace", None)
+        tr.observe(name, t0, t1, "msgr", ctx,
+                   track if ctx is not None else None)
 
 
 class AsyncConnection:
@@ -71,8 +95,14 @@ class AsyncConnection:
                       "rx_msgs": 0, "rx_bytes": 0}
         self.acct = None
         self.faults = None
+        # the daemon track this end stamps its ``msgr.frame_rx`` /
+        # ``msgr.reply_drain`` spans on; None (clients) stamps none
+        self.span_track: str | None = None
+        self._rx_t0: float | None = None  # first byte of the frame in flight
         self._wlock = threading.Lock()
-        self._wq: list = []              # [[memoryview, throttled_left]]
+        # [[memoryview, throttled_left, drain mark or None]]: the mark
+        # (enqueue stamp, riding calls) sits on a frame's LAST entry
+        self._wq: list = []
         self._close_after_flush = False
         self._closed = False
         self._close_exc: BaseException | None = None
@@ -106,7 +136,7 @@ class AsyncConnection:
         from .. import net
         return net._encode_parts(msg, self.secret)
 
-    def _send_parts(self, msg, parts: list, timeout: float) -> None:
+    def _send_parts(self, msg, parts: list, timeout: float):
         """Enqueue one frame as multiple write-queue entries (payload
         views unjoined).  Entries land atomically under _wlock, so
         concurrent senders cannot interleave mid-frame; each entry
@@ -120,14 +150,25 @@ class AsyncConnection:
         if self._closed:
             self.wthrottle.put(total)
             raise ConnectionError(f"{self.name}: connection closed")
+        mark = self._drain_mark(msg)
         with self._wlock:
             self._stats_tx(total)
             for p in parts:
                 self._enqueue_locked_entry(
                     p if isinstance(p, memoryview) else memoryview(p),
                     len(p))
+            self._wq[-1][2] = mark
         self._account_tx(msg, total)
         self.reactor.update_interest(self.sock, self)
+        return mark[0] if mark is not None else None
+
+    def _drain_mark(self, msg):
+        """(enqueue stamp, riding calls) for a frame whose drain this
+        end stamps as ``msgr.reply_drain``; None where it stamps none."""
+        if self.span_track is None or not instruments.enabled():
+            return None
+        riders = riding_calls(msg)
+        return (time.perf_counter(), riders) if riders else None
 
     def _stats_tx(self, nbytes: int) -> None:
         # plain-dict read-modify-write: callers hold _wlock (pairs with
@@ -151,11 +192,15 @@ class AsyncConnection:
                 ctx = default_tracer().current_ctx()
             self.acct.account_msg(msg, nbytes=nbytes, ctx=ctx)
 
-    def send(self, msg, timeout: float = SEND_TIMEOUT) -> None:
+    def send(self, msg, timeout: float = SEND_TIMEOUT) -> float | None:
         """Thread-safe framed send with write-queue backpressure.  May
         block up to ``timeout`` for throttle budget; raises
         ConnectionError on a closed link, an injected transport fault,
-        or exhausted backpressure budget (peer stopped reading)."""
+        or exhausted backpressure budget (peer stopped reading).
+        Returns the ``time.perf_counter`` instant the frame entered the
+        write queue where this end stamps its drain (``span_track``),
+        else None: the sender's span ends where ``msgr.reply_drain``
+        starts."""
         if self._closed:
             raise ConnectionError(f"{self.name}: connection closed")
         hooks = self.faults() if self.faults is not None else None
@@ -166,8 +211,7 @@ class AsyncConnection:
             # buffer frame so truncate/reset see one contiguous image.
             parts = self._encode_parts(msg)
             if parts is not None:
-                self._send_parts(msg, parts, timeout)
-                return
+                return self._send_parts(msg, parts, timeout)
         data = self._encode(msg)
         action = "ok"
         if hooks is not None:
@@ -184,12 +228,14 @@ class AsyncConnection:
             raise ConnectionError(f"{self.name}: connection closed")
         from ..failure.transport import SEND_TRUNCATE
         if action == "ok":
+            mark = self._drain_mark(msg)
             with self._wlock:
                 self._stats_tx(len(data))
-                self._enqueue_locked_entry(memoryview(data), len(data))
+                self._enqueue_locked_entry(memoryview(data), len(data),
+                                           mark)
             self._account_tx(msg, len(data))
             self.reactor.update_interest(self.sock, self)
-            return
+            return mark[0] if mark is not None else None
         # injected transport failure: partial frame (truncate) or
         # nothing, then an abrupt close — the peer must reconnect+resend
         self.wthrottle.put(len(data))
@@ -219,8 +265,9 @@ class AsyncConnection:
         self._account_tx(msg, len(data))
         self.reactor.update_interest(self.sock, self)
 
-    def _enqueue_locked_entry(self, mv: memoryview, throttled: int) -> None:
-        self._wq.append([mv, throttled])
+    def _enqueue_locked_entry(self, mv: memoryview, throttled: int,
+                              mark=None) -> None:
+        self._wq.append([mv, throttled, mark])
 
     def wants_write(self) -> bool:
         return bool(self._wq)
@@ -228,6 +275,10 @@ class AsyncConnection:
     # -- readiness callbacks (reactor thread) --------------------------------
 
     def on_readable(self) -> None:
+        spans = self.span_track is not None and instruments.enabled()
+        if spans and self.parser.pending() == 0:
+            # this recv brings the first byte of the next frame
+            self._rx_t0 = time.perf_counter()
         try:
             data = self.sock.recv(RECV_SIZE)
         except (BlockingIOError, InterruptedError):
@@ -251,6 +302,13 @@ class AsyncConnection:
             except WireError as e:
                 self.close(e)
                 return
+            if spans and self._rx_t0 is not None:
+                # received, verified, decoded; what is still buffered
+                # belongs to the next frame, which starts here
+                t_rx = time.perf_counter()
+                stamp_calls("msgr.frame_rx", self._rx_t0, t_rx,
+                            riding_calls(msg), self.span_track)
+                self._rx_t0 = t_rx
             nbytes = sizes[i] if i < len(sizes) else \
                 sum(len(s) for s in segs) + wire_accounting.MSG_OVERHEAD
             # tx bumps run under _wlock on sender threads; take it here
@@ -274,6 +332,7 @@ class AsyncConnection:
     def on_writable(self) -> None:
         released = 0
         err: BaseException | None = None
+        drained_marks = []               # [(drain mark, last-byte stamp)]
         with self._wlock:
             while self._wq:
                 if _HAS_SENDMSG:
@@ -295,11 +354,12 @@ class AsyncConnection:
                 except OSError as e:
                     err = ConnectionError(f"send failed: {e}")
                     break
+                t_sent = time.perf_counter()
                 full = n >= cap
                 # walk the sent count across entries (a gathered send
                 # can complete several and split the last)
                 while self._wq:
-                    mv, throttled = self._wq[0]
+                    mv, throttled, mark = self._wq[0]
                     take = min(n, len(mv))
                     if throttled:
                         rel = min(take, throttled)
@@ -307,6 +367,8 @@ class AsyncConnection:
                         released += rel
                     if take == len(mv):
                         self._wq.pop(0)
+                        if mark is not None:
+                            drained_marks.append((mark, t_sent))
                     else:
                         self._wq[0][0] = mv[take:]
                     n -= take
@@ -317,6 +379,10 @@ class AsyncConnection:
             drained = not self._wq
         if released:
             self.wthrottle.put(released)
+        # stamped outside _wlock (instrument-under-lock)
+        for (t_enq, riders), t_last in drained_marks:
+            stamp_calls("msgr.reply_drain", t_enq, t_last, riders,
+                        self.span_track)
         if err is not None:
             self.close(err)
             return
@@ -340,7 +406,7 @@ class AsyncConnection:
                 return
             self._closed = True
             self._close_exc = exc
-            held = sum(t for _, t in self._wq)
+            held = sum(e[1] for e in self._wq)
             self._wq.clear()
         if held:
             self.wthrottle.put(held)

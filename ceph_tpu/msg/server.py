@@ -33,9 +33,11 @@ import socket
 import threading
 import time
 
+from ..common import instruments
+from ..common.tracer import default_tracer
 from ..osd.mclock import (CLIENT_OP, ClientInfo, DEFAULT_OP_CLASS_INFO,
                           MClockOpClassQueue)
-from .connection import AsyncConnection
+from .connection import AsyncConnection, riding_calls, stamp_calls
 from .reactor import Reactor
 from .shed import EBUSY, ShedPolicy
 
@@ -146,9 +148,11 @@ class Dispatcher:
             except (ConnectionError, OSError):
                 pass
             return False
+        # the wait for a worker starts here (0.0: not being recorded)
+        t_enq = time.perf_counter() if instruments.enabled() else 0.0
         with self._cond:
-            self.q.enqueue(op_class, (conn, msg, n), now=time.monotonic(),
-                           cost=float(n))
+            self.q.enqueue(op_class, (conn, msg, n, t_enq),
+                           now=time.monotonic(), cost=float(n))
             self._depth += n
             self._cond.notify()
         return True
@@ -169,21 +173,21 @@ class Dispatcher:
         return one(msg)
 
     @staticmethod
-    def _stamp_batch_reply(calls, wall: float, dur: float) -> None:
-        """Wire-phase spans for replies riding a batched RpcResultBatch
-        frame: each riding call's trace gets one ``mux.batch_reply``
-        child covering the coalesced reply serialize+enqueue (the send
-        the per-method ``rpc.*`` server spans end before)."""
-        from ..common import instruments
-        if not instruments.enabled():
+    def _stamp_reply_send(msg, t0: float, t1: float) -> None:
+        """The reply's serialise + write-throttle wait + enqueue, per
+        call (the send the per-method ``rpc.*`` server spans end
+        before): ``msgr.reply_send`` for a call that came alone,
+        ``mux.batch_reply`` for each traced call that rode a batch frame
+        (one coalesced RpcResultBatch answers them all)."""
+        if not hasattr(msg, "calls"):
+            stamp_calls("msgr.reply_send", t0, t1, (msg,), "server")
             return
-        from ..common.tracer import default_tracer
         tr = default_tracer()
-        for c in calls:
+        for c in msg.calls:
             ctx = getattr(c, "trace", None)
             if getattr(ctx, "trace_id", None):
-                tr.complete("mux.batch_reply", wall, dur, cat="mux",
-                            ctx=ctx, batched_calls=len(calls))
+                tr.observe("mux.batch_reply", t0, t1, "mux", ctx,
+                           "server", batched_calls=len(msg.calls))
 
     def _worker(self) -> None:
         from .. import net
@@ -203,19 +207,25 @@ class Dispatcher:
                         return
                     else:
                         self._cond.wait(0.5)
-            conn, msg, _n = item
+            conn, msg, _n, t_enq = item
+            spans = instruments.enabled()
+            if spans and t_enq:
+                stamp_calls("msgr.dispatch_queue_wait", t_enq,
+                            time.perf_counter(), riding_calls(msg),
+                            "server")
             if hasattr(msg, "calls"):     # RpcBatch: one worker, one frame
                 reply = RpcResultBatch(
                     [self.core._dispatch(conn, c) for c in msg.calls])
             else:
                 reply = self.core._dispatch(conn, msg)
             try:
-                t0 = time.monotonic()
-                wall = time.time()
-                conn.send(reply)
-                if hasattr(msg, "calls"):
-                    self._stamp_batch_reply(msg.calls, wall,
-                                            time.monotonic() - t0)
+                t0 = time.perf_counter()
+                t_queued = conn.send(reply)
+                if spans:
+                    # ends where the reply entered the write queue:
+                    # msgr.reply_drain takes over from that instant
+                    self._stamp_reply_send(
+                        msg, t0, t_queued or time.perf_counter())
             except (ConnectionError, OSError):
                 # link died (or an injected fault) before the reply got
                 # out: results are cached under their reqids — the
@@ -223,7 +233,6 @@ class Dispatcher:
                 pass
             # dispatcher completion boundary: fold this worker's pending
             # span batch into the ring once per frame, not per span
-            from ..common.tracer import default_tracer
             default_tracer().flush()
 
 
@@ -305,6 +314,7 @@ class AsyncServerTransport:
             write_queue_bytes=self.write_queue_bytes,
             staging=self.staging)
         conn.acct = self.core.wire
+        conn.span_track = "server"
         conn.auth = _AuthState()
         conn.auth.timer = self.reactor.call_later(
             AUTH_TIMEOUT, lambda c=conn: self._auth_timeout(c))

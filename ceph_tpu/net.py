@@ -839,18 +839,26 @@ class ClusterServer:
             sname = _RPC_SPAN_NAMES.get(call.method)
             if sname is None:
                 sname = _RPC_SPAN_NAMES[call.method] = "rpc." + call.method
-            if trace is not None:
-                with self.lock, tr.activate(trace, track="server"), \
-                        tr.span(sname, cat="rpc"):
-                    value = fn(ch, **call.args)
-            else:
-                # untraced op: no context/track to adopt and nothing to
-                # link — record through the allocation-light observe()
-                # path instead of the full Span protocol
+            t_ask = t_got = time.perf_counter()
+            try:
                 with self.lock:
-                    t0_span = time.perf_counter()
-                    value = fn(ch, **call.args)
-                    tr.observe(sname, t0_span, cat="rpc")
+                    t_got = time.perf_counter()
+                    if trace is not None:
+                        with tr.activate(trace, track="server"), \
+                                tr.span(sname, cat="rpc"):
+                            value = fn(ch, **call.args)
+                    else:
+                        # untraced op: no context/track to adopt and
+                        # nothing to link — record through the
+                        # allocation-light observe() path instead of the
+                        # full Span protocol
+                        value = fn(ch, **call.args)
+                        tr.observe(sname, t_got, cat="rpc")
+            finally:
+                # the wait for the one cluster lock, recorded after the
+                # lock is released so it adds nothing to the hold
+                tr.observe("rpc.lock_wait", t_ask, t_got, "rpc", trace,
+                           "server" if trace is not None else None)
             return self._rpc_remember(
                 key, RpcResult(call.rid, True, value,
                                trace=getattr(call, "trace", None)))
@@ -1333,19 +1341,17 @@ class TcpRados:
         tr = default_tracer()
         last: BaseException | None = None
         timeouts = 0
-        last_mark = time.monotonic()
+        last_mark = time.perf_counter()
         for attempt in range(attempts):
             if attempt:
                 self.resends += 1
                 # time burned since the previous attempt started (the
                 # failed attempt + any reconnect backoff) is retry
                 # overhead: stamp it into the op's trace
-                now = time.monotonic()
+                now = time.perf_counter()
                 if ctx is not None:
-                    tr.complete("net.resend",
-                                time.time() - (now - last_mark),
-                                now - last_mark, ctx=ctx,
-                                method=method, attempt=attempt)
+                    tr.observe("net.resend", last_mark, now, ctx=ctx,
+                               method=method, attempt=attempt)
                 last_mark = now
             remaining = deadline - time.monotonic()
             if remaining <= 0:

@@ -162,6 +162,10 @@ def _build_perf(name: str):
             .add_u64("in_flight", "dispatched device batches not yet "
                                   "completed (the pipeline's depth gauge)")
             .add_u64_counter("submitted", "batches submitted to the pipeline")
+            .add_u64_counter("device_dispatches",
+                             "submitted batches whose dispatch launched "
+                             "work on the device (a host-only decode and "
+                             "a host fallback launch none)")
             .add_u64_counter("completed", "batches completed (fetch + unpack)")
             .add_u64_counter("errors", "batches that failed in pack, "
                                        "dispatch, device compute, or unpack")
@@ -369,6 +373,8 @@ class CodecPipeline:
                             owner=fut.owner), \
                     self.perf.time("dispatch_time"):
                 fut._dev = dispatch(packed)
+            if fut._dev is not None:            # None: a host-only item
+                self.perf.inc("device_dispatches")
             fut._dispatched_at = device_attribution.dispatch_mark()
             fut._unpack = unpack
             fut._host_fallback = host_fallback
@@ -415,7 +421,10 @@ class CodecPipeline:
                                owner=fut.owner), \
                     self.perf.time("complete_time"):
                 self._roll_device_fault("completion")
-                dev = jax.block_until_ready(fut._dev)
+                dev = fut._dev
+                if dev is not None:             # None: a host-only item
+                    with trace_span("pipeline.device_wait"):
+                        dev = jax.block_until_ready(dev)
                 device_ok = True
                 self._device_success()
                 nbytes = getattr(dev, "nbytes", 0) or 0
@@ -426,9 +435,15 @@ class CodecPipeline:
                 device_attribution.record_batch(fut.owner,
                                                 fut._dispatched_at, nbytes)
                 recorded = True
-                host = jax.device_get(dev)
-                result = fut._unpack(fut._packed, host) \
-                    if fut._unpack is not None else host
+                host = None
+                if dev is not None:
+                    with trace_span("pipeline.fetch", bytes=nbytes):
+                        host = jax.device_get(dev)
+                if fut._unpack is not None:
+                    with trace_span("pipeline.unpack"):
+                        result = fut._unpack(fut._packed, host)
+                else:
+                    result = host
         except BaseException as e:              # noqa: BLE001 — device-side
             error = e                           # failures surface on the
             if not recorded:                    # future, not the completer

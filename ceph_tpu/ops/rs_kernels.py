@@ -170,7 +170,12 @@ def gf_apply(mat, data, variant: str = "auto"):
             variant = "bitslice"
     if variant == "pallas":
         from .pallas_kernels import gf_apply_pallas
-        return gf_apply_pallas(mat, data)
+        # trace-time metadata only, and OUTSIDE the kernel's own jit: the
+        # TPU compiler names the Mosaic custom call after the innermost
+        # scope, so a scope inside gf_apply_pallas would rename the
+        # instruction profile readers match on (ISSUE 25)
+        with jax.named_scope("ceph.gf_apply"):
+            return gf_apply_pallas(mat, data)
     if variant == "bitslice":
         return gf_apply_bitslice(mat, data)
     if variant == "lookup":
@@ -262,16 +267,19 @@ def _crc_apply_fold(crcs: jax.Array, level: int) -> jax.Array:
 
 def _crc_rows_body(rows: jax.Array, pad: int) -> jax.Array:
     """Traced body: uint8 [r, n] -> uint32 [r] of crc32c(0, row)."""
-    c = _crc_t0_dev()[rows.astype(jnp.int32)]          # per-byte crcs
-    r, n = rows.shape
-    if pad > n:
-        c = jnp.concatenate(
-            [jnp.zeros((r, pad - n), dtype=jnp.uint32), c], axis=1)
-    level = 0
-    while c.shape[1] > 1:
-        c = _crc_apply_fold(c[:, 0::2], level) ^ c[:, 1::2]
-        level += 1
-    return c[:, 0]
+    # a stable name in every op's metadata (trace-time only): a profile
+    # reader can find the checksum without knowing its shapes
+    with jax.named_scope("ceph.crc32c_rows"):
+        c = _crc_t0_dev()[rows.astype(jnp.int32)]      # per-byte crcs
+        r, n = rows.shape
+        if pad > n:
+            c = jnp.concatenate(
+                [jnp.zeros((r, pad - n), dtype=jnp.uint32), c], axis=1)
+        level = 0
+        while c.shape[1] > 1:
+            c = _crc_apply_fold(c[:, 0::2], level) ^ c[:, 1::2]
+            level += 1
+        return c[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("pad",))

@@ -181,22 +181,21 @@ class OSDDaemon:
         now = self._now()
         self.queue_stats["enqueued"] += 1
 
-        t_enq_mono = time.monotonic()   # real clock: _now() may be virtual
+        t_enq = time.perf_counter()     # real clock: _now() may be virtual
 
         def run(m=m, g=g, on_reply=on_reply, op_class=op_class,
-                t_enq_mono=t_enq_mono):
+                t_enq=t_enq):
             # the queued op runs much later (drain), on whatever thread
             # drives the bus: re-activate the context the CLIENT stamped
             # on the MOSDOp so this daemon's spans stitch under it, with
             # this OSD as their track
             tr = default_tracer()
             ctx = getattr(m, "trace", None)
-            wait = max(0.0, time.monotonic() - t_enq_mono)
             if ctx is not None:
                 # the op's daemon-queue wait, stamped into its trace —
                 # the critical-path ledger's `queue` phase
-                tr.complete("osd.queue_wait", time.time() - wait, wait,
-                            ctx=ctx, osd=self.whoami)
+                tr.observe("osd.queue_wait", t_enq, ctx=ctx,
+                           osd=self.whoami)
             with tr.activate(ctx, track=f"osd.{self.whoami}"), \
                     tr.span("osd.op", oid=m.oid,
                             owner=canonical_owner(op_class)):
@@ -227,16 +226,15 @@ class OSDDaemon:
         # a client op draining the queue would misattribute the backlog
         ctx = default_tracer().current_ctx()
 
-        t_enq_mono = time.monotonic()   # real clock: _now() may be virtual
+        t_enq = time.perf_counter()     # real clock: _now() may be virtual
 
-        def run(fn=fn, owner=owner, ctx=ctx, t_enq_mono=t_enq_mono):
+        def run(fn=fn, owner=owner, ctx=ctx, t_enq=t_enq):
             tr = default_tracer()
             actx = ctx if ctx is not None else tr.new_trace(owner)
-            wait = max(0.0, time.monotonic() - t_enq_mono)
             # background work pays queue wait too (scrub behind client
             # bursts): stamped so its class's attribution carries it
-            tr.complete("osd.queue_wait", time.time() - wait, wait,
-                        ctx=actx, osd=self.whoami)
+            tr.observe("osd.queue_wait", t_enq, ctx=actx,
+                       osd=self.whoami)
             with tr.activate(actx, track=f"osd.{self.whoami}"), \
                     tr.span(f"osd.{owner}", owner=owner):
                 fn()

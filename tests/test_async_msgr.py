@@ -503,6 +503,53 @@ class TestMuxStack:
         finally:
             mux.close()
 
+    def test_batch_frame_stamps_each_riding_call(self, served):
+        """A batch frame leaves, for EACH traced call riding it, one
+        queue wait, one lock wait and one reply stamp (the coalesced
+        reply is ``mux.batch_reply``), all on the tracer's one clock:
+        inside the interval the test itself measured."""
+        from ceph_tpu.common.tracer import default_tracer
+        server, keyring = served
+        tr = default_tracer()
+        mux = MuxClient("127.0.0.1", server.port, keyring, n_conns=1)
+        try:
+            mux.connect()
+            s = mux.session()
+            s.call("mkpool", {"name": "p", "replicated": True, "size": 3})
+            ctxs = [tr.new_trace("client") for _ in range(8)]
+            t0 = time.perf_counter()
+            with server.lock:            # the calls pile up into batches
+                calls = [s.call_async("ping", {"payload": b"x"}, trace=c)
+                         for c in ctxs]
+                time.sleep(0.1)
+            for c in calls:
+                c.event.wait(30.0)
+                assert c.done
+            time.sleep(0.05)             # the reactor's last drain
+            t1 = time.perf_counter()
+            st = mux.stats()
+            assert st["batches_sent"] < st["calls_sent"]
+        finally:
+            mux.close()
+        evs = [e for e in tr.dump(stitched=False)["traceEvents"]
+               if e.get("ph") == "X"]
+        lo, hi = (t0 - tr._t0) * 1e6, (t1 - tr._t0) * 1e6
+        batched = 0
+        for ctx in ctxs:
+            mine = [e for e in evs
+                    if e.get("args", {}).get("trace_id") == ctx.trace_id]
+            names = [e["name"] for e in mine]
+            for name in ("msgr.frame_rx", "msgr.dispatch_queue_wait",
+                         "rpc.lock_wait", "rpc.ping", "msgr.reply_drain"):
+                assert names.count(name) == 1, (name, names)
+            # the reply's send: alone, or riding the batch's
+            assert names.count("msgr.reply_send") + \
+                names.count("mux.batch_reply") == 1, names
+            batched += names.count("mux.batch_reply")
+            for e in mine:
+                assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi, e
+        assert batched >= 2
+
     def test_tcprados_interops_with_async_server(self, served):
         """The classic one-session client and the mux client share one
         server: same pools, same data, same watch/notify plumbing."""

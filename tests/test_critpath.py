@@ -165,7 +165,8 @@ class TestLedger:
         led = CritPathLedger(name="amend")
         try:
             ctx = tr.new_trace("client")
-            tr.complete("osd.queue_wait", time.time(), 0.002, ctx=ctx)
+            t0 = time.perf_counter()
+            tr.observe("osd.queue_wait", t0, t0 + 0.002, ctx=ctx)
             assert led.refresh(tr) == 1          # truncated fold
             s = led.class_summary("client")
             assert s["ops"] == 1

@@ -128,7 +128,8 @@ class TestSlowOpPromotion:
             else:
                 fast.append((name, ctx))
                 dur = 0.001
-            t.complete(name, time.time() - dur, dur, ctx=ctx)
+            now = time.perf_counter()
+            t.observe(name, now - dur, now, ctx=ctx)
         ev = {e["name"]: e for e in t.dump()["traceEvents"]}
         for name, ctx in slow:
             assert name in ev, f"slow op {name} lost"
@@ -151,7 +152,8 @@ class TestSlowOpPromotion:
         t.sample_rate = 0.0
         ctx = t.new_trace("client")
         assert len(t.micro_records()) == 1
-        t.complete("fast", time.time() - 0.001, 0.001, ctx=ctx)
+        now = time.perf_counter()
+        t.observe("fast", now - 0.001, now, ctx=ctx)
         assert t.micro_records() == []
         assert t.dump()["traceEvents"] == []
 
@@ -226,7 +228,9 @@ class TestKillSwitch:
             with t.span("gone") as s:
                 s.set(note=1)                  # null span absorbs set()
             t.instant("gone.tick")
-            t.complete("gone.op", time.time(), 0.01)
+            t.observe("gone.op", time.perf_counter() - 0.01)
+            t.observe("gone.linked", time.perf_counter() - 0.01,
+                      ctx=t.new_trace("client"))
         assert instruments.enabled()
         assert t.dump()["traceEvents"] == []
         assert t.histograms() == {}
@@ -529,7 +533,8 @@ class TestSampledReportTools:
             if not ctx.sampled:
                 continue
             dur = 0.001 * (n + 1)
-            t.complete("client.op", time.time() - dur, dur, ctx=ctx)
+            now = time.perf_counter()
+            t.observe("client.op", now - dur, now, ctx=ctx)
             durs.append(dur)
             n += 1
         return t.dump(), durs

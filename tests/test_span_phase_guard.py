@@ -5,7 +5,7 @@ Thin wrapper over the ``span-phase`` rule in
 :mod:`ceph_tpu.analysis.rules_guards` (ISSUE 15); semantics unchanged —
 an undeclared span silently files its self-time under ``other`` in the
 latency decomposition, so every span opened (or
-``tracer.complete()``-stamped) in ``exec/``, ``recovery/`` and
+``tracer.observe()``-stamped) in ``exec/``, ``recovery/`` and
 ``ops/pipeline.py`` must be declared in ``critpath.SPAN_PHASES`` or
 carry an explicit constant ``phase=``.
 """

@@ -10,6 +10,7 @@ the tools/trace_report.py self-time math.
 import importlib.util
 import json
 import pathlib
+import time
 
 import numpy as np
 import pytest
@@ -79,6 +80,77 @@ class TestSpans:
         t.reset()
         assert t.dump()["traceEvents"] == []
         assert t.histograms() == {}
+
+
+class TestObserve:
+    """The one after-the-fact path: ``observe(name, t0, t1)`` on
+    ``time.perf_counter``, linked into a trace when given a context."""
+
+    def test_bare_observe_is_the_lite_tuple_path(self):
+        t = Tracer()
+        t0 = time.perf_counter()
+        t.observe("hot", t0, t0 + 0.002, cat="rpc")
+        t.flush()
+        assert type(t._events[-1]) is tuple
+        (ev,) = t.dump()["traceEvents"]
+        assert ev["name"] == "hot" and ev["cat"] == "rpc"
+        assert "args" not in ev
+        assert ev["dur"] == pytest.approx(2000.0)
+        assert t.histograms()["hot"]["count"] == 1
+
+    def test_linked_observe_stamps_what_a_live_span_stamps(self):
+        t = Tracer()
+        ctx = t.new_trace("recovery")
+        with t.activate(ctx, track="osd.1"):
+            with t.span("live") as live:
+                t0 = time.perf_counter()
+        t1 = time.perf_counter()
+        t.observe("after", t0, t1, "rpc", live_ctx := ctx.child_of(7),
+                  "server", shard=3)
+        ev = {e["name"]: e for e in t.dump(stitched=False)["traceEvents"]}
+        a, l = ev["after"]["args"], ev["live"]["args"]
+        assert a["trace_id"] == l["trace_id"] == ctx.trace_id
+        assert a["parent_span_id"] == live_ctx.span_id == 7
+        assert a["span_id"] not in (0, l["span_id"])
+        assert a["op_class"] == l["op_class"] == "recovery"
+        assert a["shard"] == 3 and "sample_weight" not in a
+        assert ev["after"]["ph"] == "X" and ev["after"]["cat"] == "rpc"
+        # one clock: stamped from perf_counter inside the live span, the
+        # event starts inside it
+        assert ev["live"]["ts"] <= ev["after"]["ts"] \
+            <= ev["live"]["ts"] + ev["live"]["dur"] + 1.0
+        assert ev["after"]["dur"] == pytest.approx((t1 - t0) * 1e6)
+        assert t.histograms()["after"]["count"] == 1
+        # the track re-homes it like a live span's
+        pids = {e["pid"] for e in t.dump()["traceEvents"]
+                if e["name"] in ("after", "live")}
+        assert len(pids) == 2 and all(p >= 1_000_000 for p in pids)
+
+    def test_linked_observe_honours_the_head_sampling_decision(self):
+        t = Tracer()
+        t.sample_rate = 0.0
+        t.slow_threshold_s = 0.05
+        fast, slow = t.new_trace("client"), t.new_trace("client")
+        assert not fast.sampled and len(t.micro_records()) == 2
+        now = time.perf_counter()
+        t.observe("fast", now - 0.001, now, ctx=fast)
+        t.observe("slow", now - 0.2, now, ctx=slow)
+        ev = {e["name"]: e for e in t.dump()["traceEvents"]}
+        assert "fast" not in ev and "fast" not in t.histograms()
+        assert ev["slow"]["args"]["promoted"] is True
+        assert t.micro_records() == []           # both roots finished
+        t.sample_rate = 0.5
+        while True:
+            ctx = t.new_trace("client")
+            if ctx.sampled:
+                break
+        t.observe("weighted", now - 0.001, now, ctx=ctx)
+        with t.activate(ctx):
+            with t.span("weighted.live"):
+                pass
+        ev = {e["name"]: e for e in t.dump()["traceEvents"]}
+        assert ev["weighted"]["args"]["sample_weight"] == \
+            ev["weighted.live"]["args"]["sample_weight"] == 2.0
 
 
 class TestTracedJit:
