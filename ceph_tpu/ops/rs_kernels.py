@@ -227,59 +227,93 @@ def _xor_apply_xla(W, packets):
     return bitplane_xor_matmul(W, packets)
 
 
-# -- fused crc32c (ISSUE 20 layer c: checksums ride the encode dispatch) -----
+# -- crc32c of rows: the HashInfo checksum as word arithmetic -----------------
 #
 # crc32c is GF(2)-linear in the data bits once the seed is factored out
-# (backend/ecutil.crc32c_zeros), so a row's crc32c(0, row) folds like a
-# reduction: start from per-byte crcs (one 256-entry table gather, the
-# same shape as the codec's lookup path), then log2(n) fold levels where
-# adjacent 2^l-byte blocks combine as  Z_{2^l}(left) ^ right  —  Z_L the
-# 32x32 GF(2) matrix advancing a register through L zero bytes.  Rows
-# pad with zeros on the LEFT: leading zeros are free for a zero-seeded
-# register, so padding changes nothing while keeping every level an
-# exact halving (static shapes, one compilation per (r, n)).  Z_L is
-# applied as 32 masked XORs of trace-time constants (register bit i set
-# -> XOR in the image of bit i): plain uint32 VPU work on the [r, m]
-# array itself.  An earlier form unpacked to [r, m, 32] bit-planes for
-# one integer matmul; at [12, 524288] XLA:TPU fused that level into
-# something that returned wrong crcs for rows 0-3 on a v5e (each stage
-# jitted alone was right), and it cost 32x the array in temporaries.
+# (backend/ecutil.crc32c_zeros).  With Z_L the 32x32 GF(2) matrix that
+# advances a register through L zero bytes, four little-endian bytes
+# taken as one uint32 w give  crc32c(0, w) = Z_4(w),  so a row of W words is
+#
+#     crc32c(0, row) = Z_4( XOR_j  Z_{4(W-1-j)}(w_j) )
+#
+# and every Z commutes with every other (powers of one operator).  The
+# sum therefore folds by CONTIGUOUS halves, largest distance first:
+# c = Z_{4h}(c[:h]) ^ c[h:].  The words lie as [r, a, 128]: the levels
+# over ``a`` cut whole (8, 128) tiles apart, the last seven run over the
+# 128 lanes of a [r, 128] remainder, and Z_4 is applied once, to the r
+# results.  W operator applications a row in all; no table, no
+# gather, no lane-strided slice.  Z_L is 32 masked XORs of trace-time
+# constants (register bit i set -> XOR in the image of bit i): plain
+# uint32 VPU work on the array itself.  Rows pad with zero bytes on the
+# LEFT to a power of two: leading zeros are free for a zero register,
+# and every level stays an exact halving (static shapes, one compilation
+# per (r, n)).
+#
+# Only distances that are whole words occur, and _crc_advance refuses
+# any other.  On a v5e (PR 26's chip runs) the same fold over byte-wide
+# registers, whose last levels apply Z_1 and Z_2 (most of their images
+# one bit: a pure shift), came back from one fused program with wrong
+# bits 16-22 for r = 5 and r = 8, while every level jitted alone and
+# every single Z_L was right -- the kind of thing PR 21 found here of a
+# bit-plane matmul.  Every image of a Z_4m is dense.  chip_smoke.py
+# compares this kernel with the host's crc on the chip.
 
-@functools.lru_cache(maxsize=1)
-def _crc_t0_dev() -> jax.Array:
+_CRC_LANES = 128
+
+
+def _crc_pad(n: int) -> int:
+    """Bytes a row of n is left-padded to: a power of two, a word at least."""
+    return max(4, 1 << (n - 1).bit_length())
+
+
+def _crc_words_shape(pad: int) -> tuple[int, int]:
+    """[a, lanes] of a padded row's pad // 4 words."""
+    lanes = min(_CRC_LANES, pad // 4)
+    return pad // 4 // lanes, lanes
+
+
+def _crc_advance(regs: jax.Array, nbytes: int) -> jax.Array:
+    """Z_nbytes on a uint32 array of crc32c registers, of any shape."""
     from ..backend import ecutil
-    # first call may land inside a jit trace; the cache must hold a
-    # CONCRETE array, never that trace's tracer
-    with jax.ensure_compile_time_eval():
-        return jnp.array(ecutil._CRC_TABLES[0], dtype=jnp.uint32)
-
-
-def _crc_apply_fold(crcs: jax.Array, level: int) -> jax.Array:
-    """Apply Z_{2^level} to a uint32 crc array of any shape."""
-    from ..backend import ecutil
-    out = jnp.zeros_like(crcs)
-    for i, image in enumerate(ecutil.crc32c_zeros_op(1 << level)):
+    if nbytes % 4:
+        raise ValueError(f"crc fold distance {nbytes} is no whole word")
+    out = jnp.zeros_like(regs)
+    for i, image in enumerate(ecutil.crc32c_zeros_op(nbytes)):
         # 0 - bit is all-ones where register bit i is set
-        mask = jnp.uint32(0) - ((crcs >> jnp.uint32(i)) & jnp.uint32(1))
+        mask = jnp.uint32(0) - ((regs >> jnp.uint32(i)) & jnp.uint32(1))
         out = out ^ (mask & jnp.uint32(image))
     return out
 
 
-def _crc_rows_body(rows: jax.Array, pad: int) -> jax.Array:
-    """Traced body: uint8 [r, n] -> uint32 [r] of crc32c(0, row)."""
+def _crc_fold(words: jax.Array) -> jax.Array:
+    """Traced: uint32 [r, a, lanes] -> uint32 [r], the crc32c(0, .) of
+    each row's bytes, given as little-endian words in row order (a and
+    lanes powers of two)."""
     # a stable name in every op's metadata (trace-time only): a profile
     # reader can find the checksum without knowing its shapes
     with jax.named_scope("ceph.crc32c_rows"):
-        c = _crc_t0_dev()[rows.astype(jnp.int32)]      # per-byte crcs
+        _, a, lanes = words.shape
+        while a > 1:
+            a //= 2
+            words = _crc_advance(words[:, :a], 4 * lanes * a) ^ words[:, a:]
+        c = words[:, 0]
+        while lanes > 1:
+            lanes //= 2
+            c = _crc_advance(c[:, :lanes], 4 * lanes) ^ c[:, lanes:]
+        return _crc_advance(c[:, 0], 4)
+
+
+def _crc_rows_body(rows: jax.Array, pad: int) -> jax.Array:
+    """Traced body: uint8 [r, n] -> uint32 [r] of crc32c(0, row); the
+    bytes become words on the device."""
+    with jax.named_scope("ceph.crc32c_rows"):
         r, n = rows.shape
         if pad > n:
-            c = jnp.concatenate(
-                [jnp.zeros((r, pad - n), dtype=jnp.uint32), c], axis=1)
-        level = 0
-        while c.shape[1] > 1:
-            c = _crc_apply_fold(c[:, 0::2], level) ^ c[:, 1::2]
-            level += 1
-        return c[:, 0]
+            rows = jnp.concatenate(
+                [jnp.zeros((r, pad - n), dtype=jnp.uint8), rows], axis=1)
+        words = jax.lax.bitcast_convert_type(
+            rows.reshape(r, *_crc_words_shape(pad), 4), jnp.uint32)
+    return _crc_fold(words)
 
 
 @functools.partial(jax.jit, static_argnames=("pad",))
@@ -287,14 +321,30 @@ def _crc32c_rows_jit(rows, pad):
     return _crc_rows_body(rows, pad)
 
 
+_crc32c_words_jit = jax.jit(_crc_fold)
+
+
 def crc32c_rows(rows) -> jax.Array:
     """Device crc32c(seed=0) of each row of a uint8 [r, n] array, in one
     jitted dispatch.  Seed-chained ceph semantics are the caller's host
-    combine: ``crc32c(seed, row) == crc32c_zeros(seed, n) ^ crc32c_rows(rows)[i]``."""
-    rows = jnp.asarray(rows, dtype=jnp.uint8)
-    n = rows.shape[1]
-    pad = 1 if n <= 1 else 1 << (n - 1).bit_length()
-    return _crc32c_rows_jit(rows, pad)
+    combine: ``crc32c(seed, row) == crc32c_zeros(seed, n) ^ crc32c_rows(rows)[i]``.
+
+    A host array goes up as the little-endian words it already is (a
+    view; a copy only where n is no power of two), which spares the
+    device the uint8 -> uint32 relayout; a device array takes
+    :func:`_crc_rows_body`, as the fused encode dispatch does."""
+    if isinstance(rows, jax.Array):
+        rows = rows.astype(jnp.uint8)
+        return _crc32c_rows_jit(rows, _crc_pad(rows.shape[1]))
+    rows = np.ascontiguousarray(rows, dtype=np.uint8)
+    r, n = rows.shape
+    pad = _crc_pad(n)
+    if pad > n:
+        padded = np.zeros((r, pad), dtype=np.uint8)
+        padded[:, pad - n:] = rows
+        rows = padded
+    return _crc32c_words_jit(
+        rows.view("<u4").reshape(r, *_crc_words_shape(pad)))
 
 
 @functools.partial(jax.jit, static_argnames=("variant", "pad"))
@@ -314,6 +364,5 @@ def gf_encode_with_crc(mat, data, variant: str = "auto"):
     encode just produced instead of a second HBM round-trip."""
     mat = jnp.asarray(mat, dtype=jnp.uint8)
     data = jnp.asarray(data, dtype=jnp.uint8)
-    n = data.shape[1]
-    pad = 1 if n <= 1 else 1 << (n - 1).bit_length()
-    return _gf_encode_with_crc_jit(mat, data, variant, pad)
+    return _gf_encode_with_crc_jit(mat, data, variant,
+                                   _crc_pad(data.shape[1]))

@@ -7,7 +7,8 @@ references (``gf/ref.py`` in pure numpy, the scalar ``crush_do_rule``
 path, the bytes a client wrote) outside any timed region:
 
   kernel      RS(8,4) cauchy, 64 stripes x 1 MiB resident in HBM, through
-              the vertical and the horizontal kernel selectors
+              the vertical and the horizontal kernel selectors; then the
+              HashInfo checksum (crc32c_rows) at the served shard shapes
   served      MiniCluster + serving engine + ClusterServer + TcpRados:
               put, read, degraded read, repair, kill -9 and reload
   placement   BulkPGMapper.map_pool over 32,768 PGs x 256 OSDs under x64,
@@ -44,11 +45,14 @@ REPO = Path(__file__).resolve().parent
 REAL = dict(stripes=64, chunk=131072, n_osds=12, objects=64,
             object_bytes=4 << 20, clients=16, overwrite=8,
             bulk_osds=256, bulk_pgs=32768, bulk_sample=1024,
-            ec_size=1048576)
+            ec_size=1048576,
+            # the served shard of a 4 MiB and of a 64 KiB object, and an
+            # odd one: PR 21's wrong crc was at the first, only on the chip
+            crc_shapes=((12, 524288), (12, 8192), (5, 777)))
 TINY = dict(stripes=8, chunk=1024, n_osds=12, objects=8,
             object_bytes=4 * 8 * 1024, clients=4, overwrite=2,
             bulk_osds=32, bulk_pgs=256, bulk_sample=32,
-            ec_size=65536)
+            ec_size=65536, crc_shapes=((12, 8192), (5, 777)))
 
 K, M = 8, 4
 ERASURES_TWO = [0, 9]
@@ -224,6 +228,40 @@ def phase_kernels(cfg: dict, rng, on_tpu: bool) -> None:
             say(f"  {layout:10s} {name:8s} {tuple(mat.shape)} x "
                 f"{tuple(data.shape)}: bit-equal; first_call_s={first:.3f} "
                 f"steady_call_s={steady:.5f}")
+
+    # the HashInfo checksum, bit for bit against the host's crc32c: rows
+    # given from the host (words before the upload), rows on the device
+    # (made words there), and the fused encode + checksum dispatch
+    from ceph_tpu.backend import ecutil
+
+    def host_crcs(rows):
+        return [ecutil.crc32c(0, np.ascontiguousarray(row)) for row in rows]
+
+    for r, width in cfg["crc_shapes"]:
+        fills = (("random", rng.integers(0, 256, size=(r, width),
+                                         dtype=np.uint8)),
+                 ("zeros", np.zeros((r, width), dtype=np.uint8)),
+                 ("0xff", np.full((r, width), 0xFF, dtype=np.uint8)))
+        for fill, rows in fills:
+            want = host_crcs(rows)
+            for given, arg in (("host", rows),
+                               ("device", jax.device_put(rows))):
+                got = np.asarray(rs_kernels.crc32c_rows(arg))
+                check([int(c) for c in got] == want,
+                      f"crc32c_rows [{r}, {width}] {fill} rows given from "
+                      f"the {given} differs from ecutil.crc32c")
+        say(f"  crc32c_rows [{r}, {width}]: bit-equal (random, zeros, 0xff; "
+            f"host and device rows)")
+    width = cfg["crc_shapes"][0][1]
+    data = rng.integers(0, 256, size=(K, width), dtype=np.uint8)
+    got_parity, got_crcs = rs_kernels.gf_encode_with_crc(pm, data)
+    want_parity = gfref.apply_matrix(pm, data)
+    check(np.array_equal(np.asarray(got_parity), want_parity),
+          f"gf_encode_with_crc [{K}, {width}]: parity differs from gf/ref")
+    check([int(c) for c in np.asarray(got_crcs)]
+          == host_crcs(np.concatenate([data, want_parity], axis=0)),
+          f"gf_encode_with_crc [{K}, {width}]: crcs differ from ecutil.crc32c")
+    say(f"  gf_encode_with_crc [{K}, {width}]: parity and crcs bit-equal")
 
 
 # -- phase 2: the served path, over the wire -----------------------------------

@@ -141,6 +141,38 @@ def test_shard_map_over_pallas_lowers_on_2x2(v5e, as_tpu_host):
         assert "tpu_custom_call" in _mesh_encode(v5e, (2, 2))
 
 
+@pytest.mark.parametrize("r,n", [(12, 524288), (12, 8192), (6, 777)])
+@pytest.mark.parametrize("given", ["host_words", "device_bytes"])
+def test_crc32c_rows_lowers(v5e, r, n, given):
+    """The HashInfo checksum at the served shard sizes (4 MiB and 64 KiB
+    objects on k=8 m=4) and an odd one, both ways ``crc32c_rows`` enters
+    the device: words viewed on the host, bytes made words on the chip."""
+    pad = rs_kernels._crc_pad(n)
+    with jax.enable_x64(False):
+        if given == "host_words":
+            lowered = rs_kernels._crc32c_words_jit.lower(_sds(
+                v5e[0], (r, *rs_kernels._crc_words_shape(pad)), jnp.uint32))
+        else:
+            lowered = rs_kernels._crc32c_rows_jit.lower(
+                _sds(v5e[0], (r, n)), pad)
+        text = lowered.compile().as_text()
+    assert "gather" not in text and "dynamic-slice" not in text
+
+
+def test_encode_with_crc_lowers_at_the_served_shape(v5e, as_tpu_host):
+    """The fused encode + checksum dispatch, k=8 m=4 on 512 KiB shards:
+    the Pallas encode and the word fold in one program."""
+    with jax.enable_x64(False):
+        lowered = rs_kernels._gf_encode_with_crc_jit.lower(
+            _sds(v5e[0], (4, 8)), _sds(v5e[0], (8, 524288)), "auto", 524288)
+        text = lowered.compile().as_text()
+    assert "tpu_custom_call" in lowered.as_text()
+    # (gf_apply_pallas expands its [4, 8] matrix with a gather of its own)
+    crc_ops = [ln for ln in text.splitlines() if "ceph.crc32c_rows" in ln]
+    assert crc_ops and not any("gather(" in ln or "dynamic-slice(" in ln
+                               for ln in crc_ops)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("x64", [False, True])
 def test_every_profile_shape_lowers(v5e, as_tpu_host, x64):

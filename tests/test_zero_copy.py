@@ -266,14 +266,51 @@ class TestStreamParserZeroCopy:
 
 # -- the fused encode + checksum kernel --------------------------------------
 
+# every width class of the word fold: under a word, a word, odd, a lane
+# row and more, no power of two, and the served shard (12 x 512 KiB);
+# fill None is seeded random bytes, else every byte that value
+_CRC_CASES = [(r, n, None)
+              for n in (1, 2, 3, 4, 5, 63, 64, 777, 4096, 131072, 524288)
+              for r in (1, 5, 12)] \
+    + [(r, n, fill) for r, n in ((5, 777), (12, 524288))
+       for fill in (0x00, 0xFF)]
+
+
 class TestFusedChecksum:
-    @pytest.mark.parametrize("n", [1, 2, 63, 64, 777, 4096])
-    def test_crc32c_rows_matches_host(self, n):
+    @pytest.mark.parametrize("r,n,fill", _CRC_CASES)
+    def test_crc32c_rows_matches_host(self, r, n, fill):
+        """Bit-equal to the host crc whether the rows come from the host
+        (viewed as words before the upload) or lie on the device (made
+        words there, as the fused encode dispatch does; at the two
+        largest widths for r = 12 only, a CPU compile being seconds)."""
+        import jax.numpy as jnp
         from ceph_tpu.ops import rs_kernels
-        rows = _rng(n).integers(0, 256, size=(5, n), dtype=np.uint8)
-        dev = np.asarray(rs_kernels.crc32c_rows(rows))
-        host = [ecutil.crc32c(0, bytes(r)) for r in rows]
-        assert [int(x) for x in dev] == host
+        rows = np.full((r, n), fill, dtype=np.uint8) if fill is not None \
+            else _rng(n).integers(0, 256, size=(r, n), dtype=np.uint8)
+        host = [ecutil.crc32c(0, bytes(row)) for row in rows]
+        given = [rows] + [jnp.asarray(rows)] * (n < 131072 or r == 12)
+        for arg in given:
+            dev = np.asarray(rs_kernels.crc32c_rows(arg))
+            assert dev.dtype == np.uint32 and dev.shape == (r,)
+            assert [int(x) for x in dev] == host
+
+    def test_crc32c_rows_has_no_gather(self):
+        """The served shape lowers to word arithmetic alone: a TPU has
+        no fast per-element gather, and the table lookup this kernel
+        replaced cost 57 ms a put there."""
+        import jax
+        import jax.numpy as jnp
+        from ceph_tpu.ops import rs_kernels
+        for text in (
+                rs_kernels._crc32c_rows_jit.lower(
+                    jax.ShapeDtypeStruct((12, 524288), jnp.uint8),
+                    524288).as_text(),
+                rs_kernels._crc32c_words_jit.lower(
+                    jax.ShapeDtypeStruct((12, 1024, 128),
+                                         jnp.uint32)).as_text()):
+            assert "xor" in text
+            for op in ("gather", "dynamic-slice", "dynamic_slice"):
+                assert op not in text, op
 
     @pytest.mark.parametrize("k,m", [(2, 1), (4, 2), (6, 3)])
     @pytest.mark.parametrize("n", [64, 1000, 4096])
