@@ -42,7 +42,7 @@ from .pg_backend import (Op, OSDShard, PG_META, PGBackend, RecoveryOp,
                          shard_store,
                          RecoveryState, RepairState, ShardRepairOp,
                          _slice_subchunks)
-from .transaction import get_write_plan
+from .transaction import PreparedWrite, get_write_plan
 from ..common.tracer import trace_span
 from ..osd.pg_log import OP_DELETE, OP_MODIFY
 
@@ -137,6 +137,43 @@ class ECBackend(PGBackend):
             return self.serving.encode(logical, sinfo=self.sinfo,
                                        ec_impl=self.ec_impl)
         return ecutil.encode(self.sinfo, self.ec_impl, logical)
+
+    def _encode_traced(self, logical, **where) -> dict[int, np.ndarray]:
+        with trace_span("ec.encode", bytes=int(logical.nbytes),
+                        backend=self.instance_name,
+                        served=self.serving is not None, **where), \
+                self.perf.time("encode_time"):
+            return self._serving_encode(logical)
+
+    def prepare_write_full(self, data):
+        """A full-object write's codec work, which depends on nothing but
+        the payload and the pool's immutable profile: pad it to the
+        stripe width, encode it and checksum the shards — the same
+        ``_serving_encode`` and ``ecutil.device_shard_crcs`` calls
+        ``_generate_transactions`` would make.  Takes no lock and touches
+        no PG state (the serving engine is the thread-safe front door),
+        so the transport runs it in the worker that dequeued the op
+        while another op holds the cluster lock; ``_generate_transactions``
+        adopts the result after checking that the plan is the one it was
+        computed for.  Returns a :class:`PreparedWrite`, or None where
+        there is nothing to move (an empty payload, no device codec).
+
+        Stamped as the first half of transaction generation: a
+        ``pg.generate_transactions`` span with the codec spans nested in
+        it, as they are when the work runs under the lock."""
+        data = bytes(data)
+        pad = (-len(data)) % self.sinfo.stripe_width
+        padded = data + b"\0" * pad if pad else data
+        if not padded or \
+                ecutil._device_codec(self.ec_impl, len(padded)) is None:
+            return None
+        with trace_span("pg.generate_transactions", half="prepare",
+                        backend=self.instance_name):
+            chunks = self._encode_traced(
+                np.frombuffer(padded, dtype=np.uint8))
+            crcs = ecutil.device_shard_crcs(chunks, self.ec_impl)
+        self.perf.inc("writes_prepared")
+        return PreparedWrite(padded, chunks, crcs, self.ec_impl)
 
     def _serving_decode(self, by_chunk) -> bytes:
         if self.serving is not None:
@@ -413,26 +450,23 @@ class ECBackend(PGBackend):
             for off, length in will_write:
                 pieces.append((off, self._assemble_extent(op, oid, objop, off, length)))
             # ONE batched encode over all extents' stripes — or adopt the
-            # chunks a cross-op batch encoder (ecutil.encode_many via
-            # put_many) precomputed, IF the plan really is the single
-            # full-extent write they were computed for
-            logical = np.concatenate(
-                [np.frombuffer(b, dtype=np.uint8) for _, b in pieces])
+            # chunks computed ahead of the transaction (a cross-op batch
+            # encode via put_many, a served put's prepare_write_full), IF
+            # the plan really is the single full-extent write they were
+            # computed for: the same bytes, compared in place
             pre = objop.precomputed_chunks
-            if (pre is not None and len(pieces) == 1 and
-                    pieces[0][0] == 0 and
-                    logical.tobytes() == getattr(objop, "precomputed_for",
-                                                 None)):
+            adopted = (pre is not None and len(pieces) == 1 and
+                       pieces[0][0] == 0 and
+                       pieces[0][1] == objop.precomputed_for)
+            if adopted:
                 encoded = {c: np.asarray(pre[c], dtype=np.uint8)
                            for c in range(n)}
             else:
-                with trace_span("ec.encode", oid=oid,
-                                bytes=int(logical.nbytes),
-                                backend=self.instance_name,
-                                served=self.serving is not None), \
-                        self.perf.time("encode_time"):
-                    encoded = self._serving_encode(logical)
-            self.perf.inc("stripe_bytes_encoded", int(logical.nbytes))
+                logical = np.concatenate(
+                    [np.frombuffer(b, dtype=np.uint8) for _, b in pieces])
+                encoded = self._encode_traced(logical, oid=oid)
+            self.perf.inc("stripe_bytes_encoded",
+                          sum(len(b) for _, b in pieces))
             if op.tracked:
                 op.tracked.mark_event("encoded")
             # scatter per-extent chunk ranges into shard transactions
@@ -481,9 +515,15 @@ class ECBackend(PGBackend):
             total = hinfo.projected_total_chunk_size
             if pure_append and appended:
                 # fused path: one device crc dispatch over the stacked
-                # appended rows when the plugin has a device codec
+                # appended rows when the plugin has a device codec — or
+                # the crcs that came with the adopted chunks (one piece
+                # at offset 0: the appended rows ARE those chunks)
+                crcs = objop.precomputed_crcs \
+                    if adopted and hinfo.has_chunk_hash() else None
                 ecutil.hinfo_append(hinfo, old_size, append_chunks,
-                                    ec_impl=self.ec_impl)
+                                    ec_impl=self.ec_impl, crcs=crcs)
+                if crcs is not None:
+                    self.perf.inc("prepared_adopted")
             elif not pure_append:
                 hinfo.set_total_chunk_size_clear_hash(total)
             self._persist_hinfo(oid, hinfo, shard_txns)
@@ -493,6 +533,13 @@ class ECBackend(PGBackend):
                          length: int) -> bytes:
         """Merge read-in stripes, cached stripes, and the op's new writes
         into the stripe-aligned extent [off, off+length)."""
+        if len(objop.buffer_updates) == 1:
+            w_off, data = objop.buffer_updates[0]
+            if w_off == off and len(data) == length:
+                # the op's one write IS the extent (a stripe-aligned full
+                # write): writes land last, so reads and the truncate's
+                # zeros would all be overwritten — no copy to make
+                return data
         buf = bytearray(length)
         reads = op.remote_reads.get(oid, {})
         for r_off, data in reads.items():

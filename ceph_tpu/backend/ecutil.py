@@ -440,37 +440,54 @@ def _device_codec(ec_impl, nbytes: int):
     return probe(int(nbytes))
 
 
+def device_shard_crcs(chunks: dict[int, np.ndarray],
+                      ec_impl) -> dict[int, int] | None:
+    """The seed-free crc32c of each of ``chunks``' equal-length rows in
+    ONE ``crc32c_rows`` dispatch, or None where the plugin has no device
+    codec for them (numpy routing, uneven or empty rows).  Reads nothing
+    but its arguments, so a full-object write computes it before the
+    cluster lock (:meth:`ECBackend.prepare_write_full`) and
+    :func:`hinfo_append` under it — same call, same bits."""
+    lens = {len(v) for v in chunks.values()}
+    if len(lens) != 1 or ec_impl is None:
+        return None
+    nbytes = lens.pop()
+    if not nbytes or _device_codec(ec_impl, nbytes * len(chunks)) is None:
+        return None
+    shards = sorted(chunks)
+    from ..ops import rs_kernels
+    with trace_span("ec.hinfo_crc", rows=len(shards),
+                    bytes=nbytes * len(shards)):
+        rows = np.stack([_as_u8(chunks[s]) for s in shards])
+        crc_dev = rs_kernels.crc32c_rows(rows)
+        # the fetch is where the host blocks on the device
+        with trace_span("ec.hinfo_crc.wait"):
+            crc0 = np.asarray(crc_dev)
+    return {s: int(c) for s, c in zip(shards, crc0)}
+
+
 def hinfo_append(hinfo: HashInfo, old_size: int,
-                 chunks: dict[int, np.ndarray], ec_impl=None) -> None:
+                 chunks: dict[int, np.ndarray], ec_impl=None,
+                 crcs: dict[int, int] | None = None) -> None:
     """HashInfo maintenance with the checksum fused into a device
     dispatch: when the plugin has a device codec and the hashes are
     live, the appended chunk rows stack into ONE ``crc32c_rows`` call
-    and the seed-free results chain through
+    (:func:`device_shard_crcs`) and the seed-free results chain through
     :meth:`HashInfo.append_crcs` — no host crc loop over the shards.
-    Everything else (numpy routing, hash-less objects, uneven appends)
-    falls through to the bitwise-identical :meth:`HashInfo.append`."""
+    ``crcs`` are that call's results for exactly these ``chunks``,
+    computed ahead of time; they are chained only where this function
+    would have computed them itself.  Everything else (numpy routing,
+    hash-less objects, uneven appends) falls through to the
+    bitwise-identical :meth:`HashInfo.append`."""
     if not chunks:
         return
-    if hinfo.has_chunk_hash() and ec_impl is not None:
-        lens = {len(v) for v in chunks.values()}
-        if len(lens) == 1:
-            nbytes = lens.pop()
-            codec = _device_codec(ec_impl, nbytes * len(chunks)) \
-                if nbytes else None
-            if codec is not None:
-                shards = sorted(chunks)
-                from ..ops import rs_kernels
-                with trace_span("ec.hinfo_crc", rows=len(shards),
-                                bytes=nbytes * len(shards)):
-                    rows = np.stack([_as_u8(chunks[s]) for s in shards])
-                    crc_dev = rs_kernels.crc32c_rows(rows)
-                    # the fetch is where the host blocks on the device
-                    with trace_span("ec.hinfo_crc.wait"):
-                        crc0 = np.asarray(crc_dev)
-                hinfo.append_crcs(old_size,
-                                  {s: int(c)
-                                   for s, c in zip(shards, crc0)}, nbytes)
-                return
+    if hinfo.has_chunk_hash():
+        if crcs is None or crcs.keys() != chunks.keys():
+            crcs = device_shard_crcs(chunks, ec_impl)
+        if crcs is not None:
+            hinfo.append_crcs(old_size, crcs,
+                              len(next(iter(chunks.values()))))
+            return
     hinfo.append(old_size, chunks)
 
 

@@ -35,6 +35,13 @@ class ExtentCache:
     def _merge(self, oid: str, offset: int, data: bytes) -> None:
         spans = self._pinned[oid]
         end = offset + len(data)
+        if not any(off + len(buf) >= offset and off <= end
+                   for off, buf in spans.items()):
+            # nothing pinned overlaps or adjoins (a new object's write):
+            # bytes are immutable, so pin them as they are — no splice,
+            # no copy of a 4 MiB extent inside the cluster lock's hold
+            spans[offset] = data
+            return
         merged_off, merged = offset, bytearray(data)
         for off in sorted(list(spans)):
             buf = spans[off]

@@ -845,6 +845,14 @@ class PGBackend:
         self.perf = (
             PerfCountersBuilder(f"{perf_prefix}.{self.instance_name}")
             .add_u64_counter("writes", "client writes committed")
+            .add_u64_counter("writes_prepared",
+                             "full-object writes whose encode and shard "
+                             "crcs ran ahead of the transaction "
+                             "(prepare_write_full)")
+            .add_u64_counter("prepared_adopted",
+                             "of those, writes that adopted both (the "
+                             "rest re-ran them live: the plan was not "
+                             "the one prepared for)")
             .add_u64_counter("write_rollbacks",
                              "in-flight writes rolled back (min_size)")
             .add_u64_counter("reads", "client reads completed")

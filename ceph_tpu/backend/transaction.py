@@ -8,9 +8,20 @@ src/osd/ECTransaction.h:40-183): computes which whole stripes must be read
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 from .ecutil import HashInfo, StripeInfo
 from .extent import ExtentSet
+
+
+class PreparedWrite(NamedTuple):
+    """What ECBackend.prepare_write_full hands from the transport's
+    worker to the locked section: the codec work that the
+    ObjectOperation's ``precomputed_*`` fields carry."""
+    padded: bytes           # the payload, zero-padded to the stripe width
+    chunks: dict            # {chunk index: np.uint8 stream}
+    crcs: dict | None       # {chunk index: crc32c(0, stream)}
+    codec: object           # the ec_impl they were computed with
 
 
 @dataclass
@@ -22,14 +33,23 @@ class ObjectOperation:
     # (truncate_before_writes, truncate_after_writes) — ECTransaction.h:71,154
     truncate: tuple[int, int] | None = None
     source: str | None = None  # rename/clone source oid
-    # pre-encoded chunk streams ({chunk index: bytes-like}) supplied by a
-    # cross-op batch encoder (ecutil.encode_many): the backend uses them
-    # instead of encoding, IF the assembled write bytes equal
-    # ``precomputed_for`` exactly (a plan that turned into an RMW falls
-    # back to a live encode) — the cross-PG coalescing hook SURVEY §3.2
-    # marks as the main TPU restructuring
+    # a full-extent write's codec work, done ahead of the transaction:
+    # chunk streams ({chunk index: bytes-like}) and, optionally, their
+    # seed-free shard crcs ({chunk index: crc32c(0, chunk)}), both pure
+    # functions of ``precomputed_for`` (the stripe-padded write bytes)
+    # and the pool's profile.  Filled by Cluster.put_many (chunks of a
+    # cross-op ecutil.encode_many batch — the cross-PG coalescing hook
+    # SURVEY §3.2 marks as the main TPU restructuring) and by
+    # ECBackend.prepare_write_full (chunks and crcs of ONE served put,
+    # computed in the dispatcher's worker before ClusterServer.lock and
+    # staged by the op engine's stage_write_full).  The backend adopts
+    # the chunks instead of encoding IF the assembled write bytes equal
+    # ``precomputed_for`` exactly, and the crcs only where that write is
+    # a pure append onto live hashes; any other plan encodes and
+    # checksums live
     precomputed_chunks: dict | None = None
     precomputed_for: bytes | None = None
+    precomputed_crcs: dict | None = None
     # object attribute updates (name -> value, None = remove), applied to
     # every shard like the reference's per-shard xattr replication
     # (PGTransaction::ObjectOperation::attr_updates, src/osd/PGTransaction.h)

@@ -116,6 +116,9 @@ SPAN_PHASES: dict[str, str] = {
     "pipeline.pack": DISPATCH,
     "pipeline.dispatch": DISPATCH,
     "pg.generate_transactions": DISPATCH,
+    # a served put's codec work ahead of the cluster lock (net.py
+    # _prepare_put): the encode and crc spans nest in it
+    "rpc.prepare": DISPATCH,
     "crush.bulk_map": DISPATCH,
     "codec.decode_matrix_build": DISPATCH,
     "jit.trace": DISPATCH,

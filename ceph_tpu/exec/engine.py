@@ -41,7 +41,8 @@ from ..common.perf_counters import PerfCountersBuilder
 from ..common.tracer import LATENCY_BUCKETS_S, default_tracer
 from ..ops.pipeline import CodecPipeline
 from ..osd.mclock import CLIENT_OP, MClockOpClassQueue
-from .batcher import BatchFuture, DECODE, ENCODE, dispatch_batch
+from .batcher import (BatchFuture, DECODE, ENCODE, bucket_pad_stripes,
+                      dispatch_batch)
 from .finisher import Finisher
 from .throttle import Throttle, ThrottleFull
 
@@ -134,6 +135,11 @@ class ServingEngine:
         self._first_t = 0.0             # oldest queued op's submit time
         self._stopping = False
         self._thread: threading.Thread | None = None
+        # how many threads may block in encode() at once (a server's
+        # dispatch workers: expect_submitters), and per codec geometry
+        # the stripe buckets whose device program has run (_warm_buckets)
+        self.submitters = 1
+        self._warm: dict[tuple, set[int]] = {}
         # live-tunable batching knobs (md_config observer pattern); the
         # explicit ctor args pin a test's engine against global pokes.
         # Observers hold the engine WEAKLY: the config store outlives
@@ -226,6 +232,47 @@ class ServingEngine:
         engine's codec pipeline — the chaos harness hook."""
         if self.pipeline is not None:
             self.pipeline.inject_faults(injector)
+
+    def expect_submitters(self, n: int) -> None:
+        """Up to ``n`` threads will block in :meth:`encode` at once (the
+        server's dispatch workers, each preparing a put ahead of the
+        cluster lock), so batches of up to ``n`` ops can form."""
+        self.submitters = max(self.submitters, int(n))
+
+    def _warm_buckets(self, ops: list[BatchFuture]) -> None:
+        """Run a zero batch of every stripe bucket that ``submitters``
+        concurrent encodes of a size can fuse into, the first time an op
+        of that size is dispatched — so a fused batch's device program
+        is compiled when the first op of its size is served (a server's
+        warm-up) and never when two ops first meet mid-traffic.  The
+        bucket set is logarithmic (batcher.bucket_pad_stripes): n = 3
+        adds two programs a size.  RS is positionwise-linear, so zeros
+        are as good a batch as any."""
+        for op in ops:
+            if op.kind != ENCODE:
+                continue
+            sinfo, ec = op.sinfo, op.ec_impl
+            seen = self._warm.setdefault(
+                (id(ec), sinfo.k, sinfo.chunk_size), set())
+            stripes = len(op.payload) // sinfo.stripe_width
+            for j in range(1, self.submitters + 1):
+                bucket = bucket_pad_stripes(j * stripes)
+                nbytes = bucket * sinfo.stripe_width
+                if bucket in seen or \
+                        ecutil._device_codec(ec, nbytes) is None:
+                    continue            # run before, or nothing to compile
+                seen.add(bucket)
+                zeros = [np.zeros(nbytes, dtype=np.uint8)]
+                try:
+                    fut = ecutil.encode_many_pipelined(
+                        sinfo, ec, zeros, self.pipeline, owner="serving") \
+                        if self.pipeline is not None else None
+                    if fut is None:
+                        ecutil.encode_many(sinfo, ec, zeros)
+                    else:
+                        self.pipeline.complete(fut)
+                except Exception:       # noqa: BLE001 — a device that fails
+                    pass                # here fails the op's own dispatch
 
     # -- submission ----------------------------------------------------------
 
@@ -419,6 +466,8 @@ class ServingEngine:
             return self._drain_locked(self.batch_max_ops)
 
     def _dispatch(self, ops: list[BatchFuture]) -> None:
+        if self.submitters > 1 and self.pad_to_bucket:
+            self._warm_buckets(ops)
         t = time.monotonic()
         t_pc = time.perf_counter()
         tr = default_tracer()

@@ -129,6 +129,10 @@ class Dispatcher:
             t.join(5.0)
 
     @property
+    def n_workers(self) -> int:
+        return self._n
+
+    @property
     def depth(self) -> int:
         with self._cond:
             return self._depth

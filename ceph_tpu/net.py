@@ -743,6 +743,12 @@ class ClusterServer:
                 self, self._listener,
                 cct=getattr(self.cluster, "cct", None),
                 name=f"net.{self.port}")
+            serving = getattr(self.cluster, "serving", None)
+            if serving is not None:
+                # each worker encodes a put ahead of the cluster lock
+                # (_prepare_put): that many encodes can meet in a batch
+                serving.expect_submitters(
+                    self._transport.dispatcher.n_workers)
             self._transport.start()
         return self._transport
 
@@ -839,6 +845,13 @@ class ClusterServer:
             sname = _RPC_SPAN_NAMES.get(call.method)
             if sname is None:
                 sname = _RPC_SPAN_NAMES[call.method] = "rpc." + call.method
+            track = "server" if trace is not None else None
+            args = call.args
+            if call.method == "put":
+                # a put's codec work runs HERE, in this worker, while
+                # another op holds the lock; the locked section adopts it
+                with tr.activate(trace, track=track):
+                    args = self._prepare_put(args)
             t_ask = t_got = time.perf_counter()
             try:
                 with self.lock:
@@ -846,19 +859,24 @@ class ClusterServer:
                     if trace is not None:
                         with tr.activate(trace, track="server"), \
                                 tr.span(sname, cat="rpc"):
-                            value = fn(ch, **call.args)
+                            value = fn(ch, **args)
                     else:
                         # untraced op: no context/track to adopt and
                         # nothing to link — record through the
                         # allocation-light observe() path instead of the
                         # full Span protocol
-                        value = fn(ch, **call.args)
+                        value = fn(ch, **args)
                         tr.observe(sname, t_got, cat="rpc")
             finally:
                 # the wait for the one cluster lock, recorded after the
-                # lock is released so it adds nothing to the hold
+                # lock is released so it adds nothing to the hold; a
+                # put's prepare (worker dequeue -> lock asked for) comes
+                # before it, so a call's transport spans stay adjacent
+                if args is not call.args:
+                    tr.observe("rpc.prepare", t0, t_ask, "rpc", trace,
+                               track)
                 tr.observe("rpc.lock_wait", t_ask, t_got, "rpc", trace,
-                           "server" if trace is not None else None)
+                           track)
             return self._rpc_remember(
                 key, RpcResult(call.rid, True, value,
                                trace=getattr(call, "trace", None)))
@@ -901,11 +919,34 @@ class ClusterServer:
     def _rpc_pools(self, ch):
         return dict(self.cluster.pool_ids)
 
-    def _rpc_put(self, ch, pool, oid, data):
+    def _prepare_put(self, args: dict) -> dict:
+        """A put's work that needs no cluster state, done by the worker
+        BEFORE it asks for the cluster lock: the payload's one copy out
+        of the transport's staging buffer and, on an EC pool with a
+        device codec, the encode and the HashInfo crcs
+        (``ECBackend.prepare_write_full``).  Reads only what is immutable
+        for a pool's life (its id, its PGs' codec and stripe geometry).
+        Anything that raises here (pool gone, breaker open, device
+        error, a malformed call) drops the preparation: the op takes the
+        whole path under the lock, which raises what it always raised.
+        Returns ``_rpc_put``'s arguments."""
+        try:
+            out = dict(args, data=bytes(args["data"]))
+            c = self.cluster
+            prepare = getattr(
+                c.pg_group(c.pool_ids[args["pool"]], args["oid"]).backend,
+                "prepare_write_full", None)
+            if prepare is not None:
+                out["prepared"] = prepare(out["data"])
+            return out
+        except Exception:                      # noqa: BLE001 — see above
+            return args
+
+    def _rpc_put(self, ch, pool, oid, data, prepared=None):
         from .osd.osd_ops import ObjectOperation
         pid = self.cluster.pool_ids[pool]
-        self.cluster.operate(pid, oid,
-                             ObjectOperation().write_full(bytes(data)))
+        self.cluster.operate(
+            pid, oid, ObjectOperation().write_full(data, prepared))
         return len(data)
 
     def _rpc_get(self, ch, pool, oid):

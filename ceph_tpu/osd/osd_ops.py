@@ -135,8 +135,11 @@ class ObjectOperation:
     def write(self, offset: int, data: bytes):
         return self._add(OP_WRITE, offset=offset, data=bytes(data))
 
-    def write_full(self, data: bytes):
-        return self._add(OP_WRITEFULL, data=bytes(data))
+    def write_full(self, data: bytes, prepared=None):
+        """``prepared``: the codec work done for ``data`` ahead of the
+        op (backend.transaction.PreparedWrite; in-process only)."""
+        extra = {"prepared": prepared} if prepared is not None else {}
+        return self._add(OP_WRITEFULL, data=bytes(data), **extra)
 
     def append(self, data: bytes):
         return self._add(OP_APPEND, data=bytes(data))

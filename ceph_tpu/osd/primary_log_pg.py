@@ -253,9 +253,21 @@ class _ExecCtx:
         self.exists = True
         self.mutated = self.user_modify = True
 
-    def stage_write_full(self, data: bytes) -> None:
+    def stage_write_full(self, data: bytes, prepared=None) -> None:
         op = self.objop()
         op.buffer_updates = [(0, bytes(data))]
+        # the codec work done ahead for exactly these bytes rides along,
+        # if it was done with THIS pool's codec (a pool removed and made
+        # anew under the same name since has another); a second
+        # write_full of the vector drops the first's
+        if prepared is None or prepared.codec is not getattr(
+                self.engine.backend, "ec_impl", None):
+            op.precomputed_chunks = op.precomputed_for = \
+                op.precomputed_crcs = None
+        else:
+            op.precomputed_chunks, op.precomputed_for, \
+                op.precomputed_crcs = (prepared.chunks, prepared.padded,
+                                       prepared.crcs)
         op.truncate = (len(data), len(data))
         self.size = len(data)
         self.exists = True
@@ -710,7 +722,7 @@ class PrimaryLogPG:
             ctx.stage_write(p["offset"], p["data"])
             return 0
         if kind == OP_WRITEFULL:
-            ctx.stage_write_full(p["data"])
+            ctx.stage_write_full(p["data"], p.get("prepared"))
             return 0
         if kind == OP_APPEND:
             ctx.stage_write(ctx.size, p["data"])

@@ -4,9 +4,11 @@ A call that reaches ``ClusterServer`` over loopback leaves, under the
 client's trace id and on ``time.perf_counter``'s clock, six adjacent
 spans — ``msgr.frame_rx``, ``msgr.dispatch_queue_wait``,
 ``rpc.lock_wait``, ``rpc.<method>``, ``msgr.reply_send``,
-``msgr.reply_drain`` — and a put's lock hold breaks down into
-``ec.hinfo_crc`` (with its ``.wait``), ``store.commit`` and the
-``pipeline.*`` parts.  CPU, tiny sizes.
+``msgr.reply_drain`` — a put a seventh, ``rpc.prepare``, before the
+lock wait (ISSUE 29): its ``ec.encode`` and ``ec.hinfo_crc`` (with its
+``.wait``) lie there; the lock hold breaks down into ``store.commit``
+and the rest, and the ``pipeline.*`` parts are the coalescer's.  CPU,
+tiny sizes.
 """
 import threading
 import time
@@ -98,6 +100,12 @@ def test_a_call_leaves_six_adjacent_spans_of_its_trace(served, method):
         evs = _traced(lambda: r.get("p", "obj"))
     order = list(TRANSPORT)
     order.insert(3, f"rpc.{method}")
+    if method == "put":
+        # the codec work ahead of the lock (ISSUE 29): dequeue -> the
+        # lock is asked for
+        order.insert(2, "rpc.prepare")
+    else:
+        assert not [e for e in evs if e["name"] == "rpc.prepare"]
     line = [_one(evs, name) for name in order]
     client = _one(evs, "client.rpc")
     end = client["ts"]
@@ -184,9 +192,22 @@ def test_a_put_breaks_down_into_crc_and_store_commits(served):
     assert parents.count("osd.ECSubWrite") == K + M
     assert parents.count("osd.RollForward") == K + M
     assert len(commits) == 2 * (K + M)
-    for e in commits + [crc]:
+    for e in commits:
         assert hold["ts"] <= e["ts"]
         assert e["ts"] + e["dur"] <= hold["ts"] + hold["dur"] + 1.0
+    # the crc (and the encode) ran BEFORE the hold (ISSUE 29), in the
+    # prepare, under the first of the put's two pg.generate_transactions
+    prepare = _one(evs, "rpc.prepare")
+    assert prepare["ts"] + prepare["dur"] <= hold["ts"] + 1.0
+    txns = [e for e in evs if e["name"] == "pg.generate_transactions"]
+    assert len(txns) == 2
+    assert by_id[crc["args"]["parent_span_id"]] is txns[0]
+    assert by_id[_one(evs, "ec.encode")["args"]["parent_span_id"]] \
+        is txns[0]
+    assert prepare["ts"] <= txns[0]["ts"]
+    assert txns[0]["ts"] + txns[0]["dur"] <= \
+        prepare["ts"] + prepare["dur"] + 1.0
+    assert hold["ts"] <= txns[1]["ts"]
 
 
 def test_pipeline_complete_breaks_down_into_wait_fetch_unpack(served):
