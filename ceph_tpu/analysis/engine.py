@@ -24,7 +24,7 @@ REPO_ROOT = Path(__file__).resolve().parents[2]
 
 # the production tree ceph-lint covers by default (tests/ excluded: the
 # engine's own fixtures live there and must not self-trip)
-DEFAULT_SCAN = ("ceph_tpu", "tools", "bench.py")
+DEFAULT_SCAN = ("ceph_tpu", "tools")
 
 SEVERITIES = ("error", "warning")
 

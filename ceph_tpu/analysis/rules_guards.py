@@ -395,7 +395,7 @@ def check_span_phase(index: ProjectIndex) -> list[Finding]:
 
 # ------------------------------------------- 8. profiler-confinement
 
-_PROFILER_SCOPE = ("ceph_tpu", "tools", "bench.py")
+_PROFILER_SCOPE = ("ceph_tpu", "tools")
 # path -> why the profiler touch is legitimate there
 PROFILER_ALLOWLIST = {
     "ceph_tpu/common/profiler_capture.py":

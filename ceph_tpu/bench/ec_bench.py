@@ -216,7 +216,7 @@ def main(argv=None) -> int:
     before = _device_batches()
     try:
         rc = ErasureCodeBench(args).run()
-    except (ValueError, FileNotFoundError, RuntimeError) as e:
+    except (ValueError, OSError, RuntimeError) as e:
         print(str(e), file=sys.stderr)
         return 1
     # where the timed calls actually ran, observed not predicted: the

@@ -115,7 +115,7 @@ class CopyLedger:
         return self.snapshot()["copies_per_byte"]
 
     def reset(self) -> None:
-        """Zero everything (bench arms snapshot a clean window)."""
+        """Zero everything (a reader snapshots a clean window)."""
         with self._lock:
             self._fold_locked()
             self._copied = {s: 0 for s in COPY_SOURCES}

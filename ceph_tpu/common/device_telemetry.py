@@ -1,8 +1,7 @@
 """Device telemetry: JAX/XLA backend introspection as a perf collection.
 
-The observability gap this closes: every BENCH artifact and every perf
-number in this repo is meaningless without knowing WHAT hardware produced
-it (the BENCH trajectory was CPU-marked by prose only), and a serving
+The observability gap this closes: a perf number is meaningless
+without knowing WHAT hardware produced it, and a serving
 process needs live device-memory pressure the way the reference watches
 BlueStore utilization.  This module exposes:
 

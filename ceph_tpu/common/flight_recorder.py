@@ -16,8 +16,7 @@ captures ONE timestamped JSON bundle holding
   evaluation and stats digest),
 
 so the question "what was the system doing when X went wrong" is
-answered from the artifact alone — no reproduction required (the
-BENCH_r05 lesson applied to incidents instead of benchmarks).
+answered from the artifact alone — no reproduction required.
 
 Bundles land in a bounded in-memory ring and, when ``out_dir`` is set,
 as ``flight-<seq>-<reason>.json`` files.  Every source is exception-

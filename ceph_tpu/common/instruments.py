@@ -3,15 +3,14 @@
 ISSUE 18's measurement lever: every hot-path instrument (tracer spans/
 instants/completes, wire accounting, rpc latency observation) checks
 :func:`enabled` before doing any work, so ``instruments_enabled=false``
-turns the whole instrumentation plane into cheap no-op guards.  The
-``observability.overhead`` bench block runs the mux serving workload
-twice — instruments on vs off — and the delta IS the tax the gate holds
-to single digits.
+turns the whole instrumentation plane into cheap no-op guards.  One
+workload run twice — instruments on, then off — gives what they cost
+(PERF.md §6, PR 25).
 
 The flag is deliberately a bare module global read without a lock: the
 hot paths pay one attribute load + truth test per instrument call, and
 a torn read is impossible under the GIL (the value is a bool).  Flips
-are rare (bench arms, ``config set instruments_enabled``) and take
+are rare (``config set instruments_enabled``) and take
 effect on the next instrument call.
 
 What the switch does NOT stub: perf-counter math that the control plane
@@ -37,7 +36,7 @@ def set_enabled(on: bool) -> None:
 
 @contextmanager
 def disabled():
-    """Scoped kill-switch (the bench's off arm): instruments off inside
+    """Scoped kill-switch: instruments off inside
     the block, restored to the PRIOR state on exit."""
     prior = _enabled
     set_enabled(False)

@@ -166,10 +166,10 @@ OPTIONS: list[Option] = [
            description="master kill-switch for the hot-path instruments "
                        "(tracer spans/instants/completes, wire "
                        "accounting, rpc latency observation): off turns "
-                       "them into cheap no-op guards so the "
-                       "observability.overhead bench can measure the "
-                       "full-instrumentation tax; health checks and "
-                       "perf-counter math keep working either way",
+                       "them into cheap no-op guards, so a run with "
+                       "them off measures what they cost; health "
+                       "checks and perf-counter math keep working "
+                       "either way",
            see_also=["tracer_sample_rate"]),
     Option("tracer_sample_rate", TYPE_FLOAT, LEVEL_ADVANCED, default=1.0,
            min=0.0, max=1.0,
@@ -293,16 +293,6 @@ OPTIONS: list[Option] = [
            description="how long the mux client's sender waits for more "
                        "calls to coalesce once one is queued (0 sends "
                        "immediately)",
-           see_also=["ms_async_batch_max"]),
-    Option("ms_zero_copy", TYPE_BOOL, LEVEL_ADVANCED, default=True,
-           description="serialize batch-frame payloads through the "
-                       "raw sideband segment (length-prefixed bulk data "
-                       "after the pickled control header) so a payload "
-                       "byte is copied ~once between socket and device "
-                       "staging; off forces the legacy all-pickle frame "
-                       "(the bench's 'legacy' arm). Both formats decode "
-                       "regardless of the setting — this only gates the "
-                       "ENCODE side, so mixed-version peers interoperate",
            see_also=["ms_async_batch_max"]),
     Option("pipeline_breaker_threshold", TYPE_UINT, LEVEL_ADVANCED,
            default=3,

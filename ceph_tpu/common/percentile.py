@@ -1,14 +1,11 @@
 """Nearest-rank percentile: the ONE rank definition every surface uses.
 
-Three consumers grew their own copy of this five-liner — the serving
-workload generator (bench p99), the trace report (span p99) and the
-time-series report — with a "change BOTH if the rank definition ever
-moves" comment standing in for actual sharing.  ISSUE 10 unifies them:
-bench p99, trace p99 and SLO-objective p99 are compared against each
-other (the perf gate diffs bench p99; the SLO engine judges ops against
-a p99 target derived from the same distribution), so a drifted rank
-definition would make the gate and the health surface disagree about
-the same latency data.
+The trace report (span p99), the time-series report and the SLO engine
+each once had their own copy of this five-liner.  Trace p99 and
+SLO-objective p99 are compared against each other (the SLO engine
+judges ops against a p99 target derived from the same distribution),
+so a drifted rank definition would make the reports and the health
+surface disagree about the same latency data.
 
 Stdlib-only on purpose: ``tools/trace_report.py`` / ``tools/ts_report.py``
 load this file by PATH (``importlib.util.spec_from_file_location``), so

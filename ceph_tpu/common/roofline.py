@@ -19,9 +19,8 @@ join:
 - surfaces: the ``device_efficiency`` PerfCounters collection
   (:func:`refresh`), the ``ceph_tpu_device_efficiency{executable,stat}``
   prometheus family, the ``device roofline`` admin command
-  (:func:`report`), :func:`flat_series` for the time-series ring,
-  :func:`bench_block` for bench.py's ``efficiency`` JSON block (gated by
-  ``tools/perf_gate.py``), and ``tools/roofline_report.py`` post-hoc.
+  (:func:`report`), :func:`flat_series` for the time-series ring, and
+  ``tools/roofline_report.py`` post-hoc.
 
 Honesty note on the occupancy clock: per-call seconds are the WALL time
 of the dispatch on the calling thread.  The first dispatch of every key
@@ -300,27 +299,6 @@ def report(limit: int = 20, cct=None) -> dict:
         "device_busy_s": snap["device_busy_s"],
         "executables": [dict(rec, executable=eid)
                         for eid, rec in rows[:max(0, int(limit))]],
-    }
-
-
-def bench_block(platform: str | None, cct=None, limit: int = 12) -> dict:
-    """bench.py's ``efficiency`` JSON block: the roofline ledger the
-    bench run populated, device-marked like every other block so
-    ``tools/perf_gate.py`` can refuse cross-platform comparison."""
-    snap = snapshot(cct)
-    if not snap["executables"]:
-        return {"device": "none", "error": "no executables recorded"}
-    rows = sorted(snap["executables"].items(),
-                  key=lambda kv: kv[1]["seconds"], reverse=True)
-    return {
-        "device": "tpu" if platform == "tpu" else "cpu",
-        "peaks": snap["peaks"],
-        "pct_of_peak": snap["totals"]["pct_of_peak"],
-        "achieved_bytes_s": snap["totals"]["achieved_bytes_s"],
-        "achieved_flops_s": snap["totals"]["achieved_flops_s"],
-        "bound": snap["totals"]["bound"],
-        "executables": [dict(rec, executable=eid)
-                        for eid, rec in rows[:limit]],
     }
 
 

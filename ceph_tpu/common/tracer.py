@@ -5,7 +5,7 @@ timelines (src/common/TrackedOp.h), PerfCounters (src/common/perf_counters.h)
 and the blkin/opentracing span hooks (src/common/zipkin_trace.h) — but the
 span layer is the one this TPU-first framework needs most: a single MiB/s
 number cannot tell trace time from compile time from device-resident time
-from host<->device transfer (the BENCH_r05 failure mode: 570s of opaque
+from host<->device transfer (an earlier chip run spent 570s in opaque
 backend probing).  This module provides:
 
 - :class:`Span` / :class:`Tracer`: nested spans with a thread-safe bounded

@@ -27,9 +27,8 @@ ledger (``common/critpath.py``):
 Surfaces: the ``SLO_BURN``/``SLO_EXHAUSTED`` health checks (every
 MiniCluster registers them; transitions ride the clusterlog + flight
 recorder like any other check), ``slo status``/``slo dump`` admin
-commands, ``ceph_tpu_slo_budget{class,stat}`` prometheus gauges, the
-``slo`` series in the time-series ring, and the ``slo`` block in
-bench.py artifacts gated by ``tools/perf_gate.py``.
+commands, ``ceph_tpu_slo_budget{class,stat}`` prometheus gauges, and the
+``slo`` series in the time-series ring.
 """
 from __future__ import annotations
 
@@ -206,31 +205,6 @@ class SLOTracker:
             if summary:
                 out[f"{cls}_p99_ms"] = summary["p99_ms"]
         return out
-
-    def bench_block(self, device: str) -> dict:
-        """The bench.py `slo` block: per-class p99 + phase fractions +
-        budget state — everything tools/slo_report.py needs to
-        reproduce the attribution table from the artifact alone, and
-        tools/perf_gate.py gates (`slo.client_p99_ms`,
-        `slo.budget_remaining`)."""
-        st = self.status()
-        block: dict = {"device": device,
-                       "windows": st["windows"]}
-        for cls, summary in st["attribution"].items():
-            if not summary:
-                continue
-            entry = {"p99_ms": summary["p99_ms"],
-                     "mean_ms": summary["mean_ms"],
-                     "ops": summary["ops"],
-                     "phases": summary["phases"]}
-            obj = st["objectives"].get(cls)
-            if obj:
-                entry["objective_p99_ms"] = obj["objective_p99_ms"]
-                entry["budget_remaining"] = obj["budget_remaining"]
-                entry["burn_fast"] = obj["fast"]["burn"]
-                entry["burn_slow"] = obj["slow"]["burn"]
-            block[cls] = entry
-        return block
 
     def close(self) -> None:
         _TRACKERS.discard(self)

@@ -113,9 +113,7 @@ class MuxClient:
     def __init__(self, host: str, port: int, keyring, *, cct=None,
                  n_conns: int = 2, name: str = "mux"):
         from ..common import default_context
-        from .. import net
         self._conf = (cct if cct is not None else default_context()).conf
-        net.wire_zero_copy_config(self._conf)
         self._host, self._port = host, port
         with open(keyring, "rb") as f:
             self._key = pickle.load(f)["key"]

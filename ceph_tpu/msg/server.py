@@ -259,9 +259,6 @@ class AsyncServerTransport:
 
         def opt(key, default):
             return conf.get(key) if conf is not None else default
-        if conf is not None:
-            from .. import net
-            net.wire_zero_copy_config(conf)
         # server connections land request sidebands in the pooled
         # staging buffers (the one sanctioned copy: wire -> staging)
         from .staging import default_pool

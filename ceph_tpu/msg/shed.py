@@ -12,8 +12,8 @@ limit.
 
 A shed is an explicit, cheap refusal — the caller gets ``EBUSY``
 immediately (no queue time burned) and may back off and retry; counters
-record sheds per class so the bench can report shed-rate under
-overload.
+record sheds per class, so the shed-rate under overload can be
+read.
 """
 from __future__ import annotations
 

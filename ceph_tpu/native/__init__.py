@@ -3,10 +3,7 @@
 The reference's plugin host is C++ loading plugin .so files via dlopen
 (reference: src/erasure-code/ErasureCodePlugin.cc:126-184); here the native
 registry (native/src/registry.cc) implements that exact contract and Python
-binds it with ctypes (no pybind11 in this environment).  The batch queue
-(native/src/batch_queue.cc) is the host side of the TPU sidecar boundary:
-C++ producer threads coalesce stripes, a registered Python callback runs
-the batched JAX dispatch.
+binds it with ctypes (no pybind11 in this environment).
 """
 from __future__ import annotations
 
@@ -230,117 +227,5 @@ class NativeCodec:
         return list(out[:got])
 
 
-_BATCH_FN = C.CFUNCTYPE(C.c_int, C.c_void_p, C.POINTER(C.c_ubyte),
-                        C.POINTER(C.c_ubyte), C.c_size_t, C.c_size_t)
-_DONE_FN = C.CFUNCTYPE(None, C.c_void_p, C.c_int)
-
-
-class BatchQueue:
-    """Binding for the stripe-batching dispatch queue (batch_queue.cc).
-
-    ``fn(data, n_stripes, chunk) -> parity`` is the batched encode —
-    typically the JAX device dispatch over ``[n_stripes, k, chunk]``.
-    """
-
-    def __init__(self, k: int, m: int, chunk_size: int, fn,
-                 max_batch: int = 256):
-        build()
-        self.lib = C.CDLL(os.path.join(BUILD_DIR, "libec_batch.so"))
-        self.lib.ec_batch_queue_create.restype = C.c_void_p
-        self.lib.ec_batch_queue_create.argtypes = [
-            C.c_int, C.c_int, C.c_size_t, C.c_size_t, _BATCH_FN, C.c_void_p]
-        self.lib.ec_batch_queue_submit.argtypes = [
-            C.c_void_p, C.POINTER(C.c_ubyte), C.POINTER(C.c_ubyte),
-            _DONE_FN, C.c_void_p]
-        self.lib.ec_batch_queue_flush.argtypes = [C.c_void_p]
-        self.lib.ec_batch_queue_destroy.argtypes = [C.c_void_p]
-        self.lib.ec_batch_queue_batches.restype = C.c_size_t
-        self.lib.ec_batch_queue_batches.argtypes = [C.c_void_p]
-        self.lib.ec_batch_queue_stripes.restype = C.c_size_t
-        self.lib.ec_batch_queue_stripes.argtypes = [C.c_void_p]
-
-        self.k, self.m, self.chunk = k, m, chunk_size
-        self._fn = fn
-        self._err: list[BaseException] = []
-
-        def trampoline(_ctx, data_p, parity_p, n_stripes, chunk):
-            try:
-                data = np.ctypeslib.as_array(
-                    data_p, shape=(n_stripes, k, chunk))
-                parity = fn(data, n_stripes, chunk)
-                parity = np.ascontiguousarray(parity, dtype=np.uint8) \
-                    .reshape(n_stripes, m, chunk)
-                C.memmove(parity_p, parity.ctypes.data, parity.nbytes)
-                return 0
-            except BaseException as e:      # noqa: BLE001 - crosses C ABI
-                self._err.append(e)
-                return -1
-        self._trampoline = _BATCH_FN(trampoline)   # keep a reference!
-        self._done_keep: dict[int, object] = {}
-        self._retired: list[int] = []
-        self._q = self.lib.ec_batch_queue_create(
-            k, m, chunk_size, max_batch, self._trampoline, None)
-
-    def _reap(self) -> None:
-        """Free retired per-stripe callbacks.  Only called when the worker
-        is provably outside them (after flush's idle barrier / after
-        destroy joins) — freeing a CFUNCTYPE thunk from inside its own
-        invocation is a use-after-free."""
-        while self._retired:
-            self._done_keep.pop(self._retired.pop(), None)
-
-    def submit(self, data: np.ndarray, on_done=None) -> np.ndarray:
-        """Queue one stripe [k, chunk]; returns the parity buffer that will
-        be filled once the batch containing this stripe dispatches."""
-        data = np.ascontiguousarray(data, dtype=np.uint8)
-        parity = np.zeros((self.m, self.chunk), dtype=np.uint8)
-        key = id(parity)
-
-        def done(_ctx, rc):
-            # do NOT free the entry here: this very callback's thunk lives
-            # in it; mark it for _reap at the next safe point
-            self._retired.append(key)
-            if on_done is not None:
-                on_done(rc)
-        cb = _DONE_FN(done)
-        # keep data/parity/callback alive until the batch completes
-        self._done_keep[key] = (data, parity, cb)
-        rc = self.lib.ec_batch_queue_submit(
-            self._q, data.ctypes.data_as(C.POINTER(C.c_ubyte)),
-            parity.ctypes.data_as(C.POINTER(C.c_ubyte)), cb, None)
-        if rc != 0:
-            # the stripe never entered the queue: its done callback will
-            # never fire, so retire the keep-alive entry now
-            self._done_keep.pop(key, None)
-            raise IOError("queue stopped")
-        return parity
-
-    def flush(self) -> None:
-        self.lib.ec_batch_queue_flush(self._q)
-        self._reap()                 # idle barrier passed: thunks are quiet
-        if self._err:
-            errs, self._err = self._err, []
-            if len(errs) == 1:
-                raise errs[0]
-            raise BaseExceptionGroup("batch dispatch failures", errs)
-
-    @property
-    def batches(self) -> int:
-        return self.lib.ec_batch_queue_batches(self._q)
-
-    @property
-    def stripes(self) -> int:
-        return self.lib.ec_batch_queue_stripes(self._q)
-
-    def close(self) -> None:
-        if getattr(self, "_q", None):
-            self.lib.ec_batch_queue_destroy(self._q)   # joins the worker
-            self._q = None
-            self._reap()
-
-    def __del__(self):
-        self.close()
-
-
 __all__ = ["build", "registry_lib", "NativeRegistry", "NativeCodec",
-           "BatchQueue", "BUILD_DIR", "NATIVE_DIR"]
+           "BUILD_DIR", "NATIVE_DIR"]

@@ -279,8 +279,7 @@ def _encode(msg, secret: bytes | None) -> bytes:
 # splice straight into the connection's write queue) and the decode
 # side lands them with ONE copy — into a pooled staging buffer (server)
 # or owned bytes (client/blocking channel).  Frames dispatch on segment
-# count, so both formats decode regardless of ms_zero_copy: the option
-# gates only the encode side and mixed peers interoperate.
+# count, so a peer's all-pickle frame decodes beside sideband frames.
 
 _SB_MIN = copy_ledger.PAYLOAD_MIN
 # encode-side splice threshold: lifting a value costs a header rewrite,
@@ -289,29 +288,6 @@ _SB_MIN = copy_ledger.PAYLOAD_MIN
 # (and still weigh in the ledger as legacy copies via _sb_eligible)
 _SB_SPLICE_MIN = 1024
 _SB_LEN = struct.Struct("<I")
-
-_zero_copy = True
-
-
-def zero_copy_enabled() -> bool:
-    return _zero_copy
-
-
-def set_zero_copy(on: bool) -> None:
-    global _zero_copy
-    _zero_copy = bool(on)
-
-
-def wire_zero_copy_config(conf) -> None:
-    """Adopt ``ms_zero_copy`` from a ConfigProxy and follow live
-    updates (the transports call this; the switch is process-wide like
-    the instruments kill-switch, and gates only the encode side)."""
-    if "ms_zero_copy" not in conf.schema:
-        return
-    set_zero_copy(bool(conf.get("ms_zero_copy")))
-    conf.add_observer("ms_zero_copy",
-                      lambda _name, v: set_zero_copy(bool(v)))
-
 
 def _sb_eligible(v) -> bool:
     return isinstance(v, (bytes, bytearray, memoryview)) \
@@ -400,7 +376,7 @@ def _encode_parts(msg, secret: bytes | None) -> list | None:
     """Sideband encode: the frame as an ordered list of write buffers
     (payload views UNJOINED), or None when the message cannot or need
     not sideband — the caller falls back to :func:`_encode`."""
-    if secret is None or not _zero_copy:
+    if secret is None:
         return None
     codec = _SIDEBAND_CODECS.get(type(msg).__name__)
     if codec is None:
@@ -670,7 +646,7 @@ class ClusterServer:
     # cache (4 MiB gets x 4096 entries) for hits that barely happen
     IDEMPOTENT_RPCS = frozenset(
         {"get", "stat", "ls", "pools", "status", "health", "getxattr",
-         "ping", "tier_read"})
+         "ping"})
 
     def inject_faults(self, injector) -> None:
         """Arm (or, with None, disarm) transport-plane fault injection:
@@ -997,45 +973,10 @@ class ClusterServer:
     def _rpc_health(self, ch):
         return self.cluster.health()
 
-    def _rpc_ping(self, ch, payload=None, key=None):
-        """Echo: the serving-path microbenchmark op (rados_bench mux
-        mode) — round-trips the transport without touching the cluster.
-        ``key`` carries the workload generator's object key (zipf /
-        flash-crowd streams) so key-addressed load shapes ride the real
-        wire format; the echo ignores it."""
+    def _rpc_ping(self, ch, payload=None):
+        """Echo: round-trips the transport without touching the
+        cluster."""
         return payload
-
-    def _rpc_tier_read(self, ch, pool, key):
-        """Tiered read: when ``pool`` is a cache tier, serve ``key``
-        through it (hit / proxy / recency-gated promote — the
-        flash-crowd serving op); otherwise read straight from the pool
-        with the tier's own base op vector, so the tiering bench's cold
-        arm measures the exact path a miss proxies to.  Idempotent: a
-        promotion is a copy-up, so re-executing on a resend is safe."""
-        c = self.cluster
-        pid = c.pool_ids[pool]
-        tier = c.tiers.get(pid)
-        if tier is not None:
-            return tier[0].read(key)
-        from .osd.osd_ops import ObjectOperation
-        r = c.operate(pid, key, ObjectOperation().read(0, 0).getxattrs())
-        return bytes(r.ops[0].outdata)
-
-    def _rpc_tier_write(self, ch, pool, key, payload):
-        """Tiered write: absorbed by the cache tier bound over ``pool``
-        (writeback marks dirty, proxy forwards, readonly refuses) or
-        written straight to the pool when no tier is bound — the cold
-        arm's EC full-stripe write, encode and all.  Replay-deduped
-        like ``put`` (NOT in IDEMPOTENT_RPCS)."""
-        c = self.cluster
-        pid = c.pool_ids[pool]
-        tier = c.tiers.get(pid)
-        if tier is not None:
-            tier[0].write(key, bytes(payload))
-            return len(payload)
-        from .osd.osd_ops import ObjectOperation
-        c.operate(pid, key, ObjectOperation().write_full(bytes(payload)))
-        return len(payload)
 
     def _rpc_watch(self, ch, pool, oid, cookie):
         from .osd.osd_ops import ObjectOperation

@@ -59,7 +59,7 @@ def _gf_stripes_kernel(bmat_ref, data_ref, out_ref, *, r: int, k: int,
     slabs of k chunk rows each; all slabs go through ONE int8 MXU matmul
     against a block-diagonal bit-matrix.
 
-    Why this shape wins (measured on v5e, tools/kernel_sweep.py):
+    Why this shape was chosen:
     - int8 with int32 accumulation doubles MXU peak vs bf16 (the sums are
       0/1 bits, <= 8k terms, exact either way);
     - the block-diagonal stacking lifts the degenerate [8r, 8k] = [32, 64]
@@ -181,7 +181,7 @@ def _gf_kernel(bmat_ref, data_ref, out_ref, *, r: int, k: int):
     d = data_ref[:].astype(jnp.int32)             # [k, T]
     planes = [((d >> b) & 1) for b in range(8)]
     # int8 x int8 -> int32: exact (0/1 values, <= 8k terms) and 2x the
-    # bf16 MXU peak on v5e — measured ~1.3x end-to-end (kernel_sweep.py)
+    # bf16 MXU peak on v5e
     bits = jnp.concatenate(planes, axis=0).astype(jnp.int8)   # [8k, T]
     acc = jax.lax.dot_general(
         bmat_ref[:], bits, (((1,), (0,)), ((), ())),

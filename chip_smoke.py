@@ -461,8 +461,8 @@ def phase_placement(cfg: dict, rng, on_tpu: bool) -> None:
     from ceph_tpu.osdmap.osdmap import OSDMap
     from ceph_tpu.osdmap.types import PG, POOL_TYPE_ERASURE, Pool
 
-    # the map of tools/baseline_matrix.py config 5 (BASELINE.json config
-    # 5): hosts of 8 OSDs under one straw2 root, chooseleaf indep 6
+    # the map of BASELINE.json config 5: hosts of 8 OSDs under one
+    # straw2 root, chooseleaf indep 6
     n_osds, pg_num = cfg["bulk_osds"], cfg["bulk_pgs"]
     cmap = CrushMap()
     cmap.set_type_name(1, "host")

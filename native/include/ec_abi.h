@@ -6,8 +6,7 @@
  * entry points __erasure_code_init/__erasure_code_version at
  * ErasureCodePlugin.cc:24-34, version check :144, "libec_<name>.so" prefix
  * :28) reshaped as a C vtable so codecs cross the C/Python boundary without
- * C++ name mangling: Python binds via ctypes, the JAX sidecar registers a
- * batch callback (see ec_batch.h).
+ * C++ name mangling: Python binds via ctypes.
  */
 #ifndef CEPH_TPU_EC_ABI_H
 #define CEPH_TPU_EC_ABI_H

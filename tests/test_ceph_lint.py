@@ -12,6 +12,7 @@ Three layers:
 - engine internals — index resolution tiers, the baseline round trip,
   and the rule registry the wrapper tests lean on.
 """
+import json
 from pathlib import Path
 
 import pytest
@@ -53,12 +54,17 @@ def test_baseline_entries_all_carry_justifications():
         "every baseline suppression needs a real justification"
 
 
-def test_lint_summary_block_shape():
-    s = ceph_lint.lint_summary(str(ROOT / ".ceph_lint_baseline.json"))
-    assert s["new"] == 0
-    assert s["total"] == s["baselined"]
-    assert s["rules_run"] >= 19
-    assert all(isinstance(v, int) for v in s["by_rule"].values())
+def test_json_summary_has_no_new_and_no_stale_entries(capsys):
+    """``--json`` over the live tree: every finding is baselined and
+    every baseline entry still fires (the file stays honest)."""
+    rc = ceph_lint.main(["--json", "--baseline",
+                         str(ROOT / ".ceph_lint_baseline.json")])
+    doc = json.loads(capsys.readouterr().out)
+    assert rc == 0
+    s = doc["summary"]
+    assert s["new"] == 0 and s["stale_baseline"] == 0
+    assert s["total"] == s["baselined"] == len(doc["findings"])
+    assert all(f["baselined"] for f in doc["findings"])
 
 
 # -- fixture proof: lock-order ----------------------------------------------

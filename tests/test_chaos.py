@@ -846,3 +846,117 @@ class TestChaosSoak:
             assert rep["events"]["total"] > 0
         assert reports[0]["event_digest"] == reports[1]["event_digest"], \
             "same seed produced different injected-event logs"
+
+
+# -- the campaign's key streams (tools/chaos_run.py WorkloadKeys) -------------
+
+class TestWorkloadKeys:
+    @staticmethod
+    def _keys(**kw):
+        from tools.chaos_run import WorkloadKeys
+        return WorkloadKeys(**kw)
+
+    def _stream(self, n_ops, **kw):
+        keys = self._keys(**kw)
+        return [keys.key(i / n_ops) for i in range(n_ops)], keys
+
+    def test_same_seed_same_stream_and_scale_free_coordinates(self):
+        kw = dict(n_keys=64, dist="zipf", zipf_s=1.1,
+                  flash=(0.5, 0.25, 0.5), hot_frac=0.1, seed=7)
+        a, _ = self._stream(400, **kw)
+        b, _ = self._stream(400, **kw)
+        assert a == b
+        c, _ = self._stream(400, **dict(kw, seed=8))
+        assert a != c
+        # progress is op-sequence position, not a count: with no flash
+        # window the draw does not look at it, so any scale of run
+        # reads the same first keys from one seed
+        flat = dict(n_keys=64, dist="zipf", seed=7)
+        short, _ = self._stream(50, **flat)
+        long, _ = self._stream(5000, **flat)
+        assert long[:50] == short
+
+    def test_uniform_reaches_the_whole_keyspace_evenly(self):
+        got, keys = self._stream(4000, n_keys=16, dist="uniform", seed=1,
+                                 prefix="u")
+        counts = {k: got.count(k) for k in set(got)}
+        assert set(counts) == {f"u{r:08d}" for r in range(16)}
+        assert min(counts.values()) > 0.6 * 4000 / 16
+        assert max(counts.values()) < 1.4 * 4000 / 16
+        assert keys.describe()["distinct_keys"] == 16
+
+    def test_zipf_draws_rank_by_frequency(self):
+        got, _ = self._stream(6000, n_keys=50, dist="zipf", zipf_s=1.2,
+                              seed=2)
+        by_rank = [got.count(f"obj{r:08d}") for r in range(50)]
+        # P(r) ~ 1/r^s: the head dominates and the order of the first
+        # ranks is the order of their frequencies
+        assert by_rank[0] > by_rank[1] > by_rank[3] > by_rank[9] \
+            > by_rank[40]
+        h = sum(1.0 / r ** 1.2 for r in range(1, 51))
+        assert by_rank[0] / 6000 == pytest.approx(1.0 / h, rel=0.15)
+
+    def test_flash_window_collapses_the_stated_share_onto_the_hot_set(self):
+        n_ops, frac = 8000, 0.75
+        got, keys = self._stream(
+            n_ops, n_keys=1000, dist="uniform", flash=(frac, 0.25, 0.5),
+            hot_frac=0.01, seed=3)
+        hot = {f"obj{r:08d}" for r in range(10)}         # 1% of 1000
+        inside = got[n_ops // 4:3 * n_ops // 4]
+        outside = got[:n_ops // 4] + got[3 * n_ops // 4:]
+        share_in = sum(k in hot for k in inside) / len(inside)
+        share_out = sum(k in hot for k in outside) / len(outside)
+        # inside: frac + (1 - frac) x 1% of base draws; outside: 1%
+        assert share_in == pytest.approx(frac + (1 - frac) * 0.01,
+                                         abs=0.03)
+        assert share_out < 0.03
+        d = keys.describe()
+        assert d["flash_draws"] == pytest.approx(frac * len(inside),
+                                                 rel=0.05)
+        assert d["hot_set"] == 10 and d["flash"] == [frac, 0.25, 0.5]
+
+    def test_describe_names_what_was_drawn(self):
+        got, keys = self._stream(300, n_keys=32, dist="zipf", zipf_s=0.9,
+                                 seed=4)
+        assert keys.describe() == {
+            "dist": "zipf", "zipf_s": 0.9, "n_keys": 32, "hot_set": 1,
+            "flash": None, "keys_drawn": 300, "flash_draws": 0,
+            "distinct_keys": len(set(got))}
+        assert self._keys(n_keys=8).describe()["zipf_s"] is None
+
+    @pytest.mark.parametrize("kw", [
+        {"dist": "pareto"}, {"flash": (1.5, 0.0, 0.5)},
+        {"flash": (0.5, -0.1, 0.5)}, {"flash": (0.5, 0.0, 2.0)}])
+    def test_refuses_what_it_cannot_draw(self, kw):
+        with pytest.raises(ValueError):
+            self._keys(**kw)
+
+    def test_concurrent_draws_lose_no_call(self):
+        import sys
+        keys = self._keys(n_keys=128, dist="zipf", flash=(0.5, 0.0, 1.0),
+                          hot_frac=0.05, seed=5)
+        n_threads, per = 16, 2000
+        drawn: list[list[str]] = [[] for _ in range(n_threads)]
+        start = threading.Barrier(n_threads)
+
+        def work(slot):
+            start.wait(timeout=10)
+            for i in range(per):
+                drawn[slot].append(keys.key(i / per))
+        prior = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=work, args=(s,))
+                       for s in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(prior)
+        d = keys.describe()
+        assert d["keys_drawn"] == n_threads * per
+        assert sum(len(x) for x in drawn) == n_threads * per
+        assert d["distinct_keys"] == len({k for x in drawn for k in x})
+        assert 0 < d["flash_draws"] < d["keys_drawn"]

@@ -1,17 +1,14 @@
-"""MiniCluster (vstart analog) + rados-bench harness.
+"""MiniCluster (vstart analog).
 
 Mirrors the standalone EC suite flows (reference:
 qa/standalone/erasure-code/test-erasure-code.sh:21-66 — EC pool create over
-a cluster of OSDs, put/get, per-plugin rules; and src/common/obj_bencher.h
-write/seq benches), plus ceph_str_hash_rjenkins golden vectors from the
-reference C (src/common/ceph_hash.cc:21-78)."""
-import io
-
+a cluster of OSDs, put/get, per-plugin rules), plus
+ceph_str_hash_rjenkins golden vectors from the reference C
+(src/common/ceph_hash.cc:21-78)."""
 import numpy as np
 import pytest
 
 from ceph_tpu.cluster import MiniCluster
-from ceph_tpu.bench.rados_bench import seq_read_bench, write_bench
 from ceph_tpu.osdmap.str_hash import ceph_str_hash_rjenkins
 
 # golden vectors generated by compiling the reference's ceph_hash.cc
@@ -104,24 +101,7 @@ class TestMiniCluster:
             assert len(hosts) == 5
 
 
-class TestRadosBench:
-    def test_write_and_seq_bench(self):
-        c = MiniCluster(n_osds=8, chunk_size=128)
-        pid = c.create_ec_pool("bench", {"plugin": "jax_rs", "k": "2",
-                                         "m": "1", "device": "numpy"},
-                               pg_num=4)
-        buf = io.StringIO()
-        stats = write_bench(c, pid, seconds=0.3, object_size=4096,
-                            concurrency=4, out=buf)
-        assert stats["ops"] >= 4
-        assert stats["bandwidth_mb_s"] > 0
-        text = buf.getvalue()
-        assert "Bandwidth (MB/sec):" in text
-        assert "Average IOPS:" in text
-        rstats = seq_read_bench(c, pid, min(stats["ops"], 8), 4096,
-                                out=io.StringIO())
-        assert rstats["ops"] >= 4
-
+class TestPerfCounterNames:
     def test_per_pg_perf_counters_distinct(self):
         """PGs sharing a primary OSD must not clobber each other's
         observability registrations (review regression)."""

@@ -237,17 +237,16 @@ class TestUnifiedPercentile:
         assert nearest_rank([], 99) == 0.0
         assert percentile([3.0, 1.0, 2.0], 50) == 2.0
 
-    def test_workload_and_trace_report_share_the_definition(self):
-        """The two once-deliberately-duplicated copies now ARE the
-        shared helper: identical answers on an awkward distribution."""
-        from ceph_tpu.exec.workload import percentile as wl_pctl
+    def test_trace_report_shares_the_definition(self):
+        """trace_report's once-duplicated copy now IS the shared
+        helper: identical answers on an awkward distribution."""
         spec = importlib.util.spec_from_file_location(
             "trace_report_pctl", ROOT / "tools" / "trace_report.py")
         trace_report = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(trace_report)
         vals = [0.1, 5.0, 5.0, 7.5, 100.0, 0.2, 3.3]
         for q in (0, 1, 50, 95, 99, 100):
-            assert wl_pctl(sorted(vals), q) == \
+            assert nearest_rank(sorted(vals), q) == \
                 trace_report.percentile_us(vals, q), q
 
     def test_ast_guard_no_local_percentile_redefinitions(self):

@@ -1,5 +1,5 @@
 """Native C++ runtime: registry dlopen contract, RS codec parity with the
-Python/JAX field math, broken-plugin failure paths, batch queue.
+Python/JAX field math, broken-plugin failure paths.
 
 Mirrors the reference's registry tests (reference:
 src/test/erasure-code/TestErasureCodePlugin.cc exercising the deliberately
@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from ceph_tpu.gf import matrix as gfm
-from ceph_tpu.native import BatchQueue, NativeRegistry, build
+from ceph_tpu.native import NativeRegistry, build
 
 
 @pytest.fixture(scope="module")
@@ -136,42 +136,6 @@ class TestNativeRS:
     def test_defaults_are_reed_sol_van_7_3(self, registry):
         codec = registry.factory("cpp_rs", {})
         assert codec.k == 7 and codec.n == 10
-
-
-class TestBatchQueue:
-    def test_batched_dispatch_correct_and_coalesced(self, registry):
-        """Many submits -> few batches; every stripe's parity must match the
-        synchronous native codec."""
-        k, m, chunk = 4, 2, 128
-        codec = registry.factory("cpp_rs", {"k": k, "m": m,
-                                            "technique": "cauchy"})
-        pmat = gfm.cauchy1(k, m)
-
-        def batched_encode(data, n_stripes, chunk_size):
-            # data [n, k, chunk] -> parity [n, m, chunk] (numpy stand-in for
-            # the JAX device dispatch)
-            flat = data.transpose(1, 0, 2).reshape(k, -1)
-            par = gfm.gf_matmul(pmat, flat)
-            return par.reshape(m, n_stripes, chunk_size).transpose(1, 0, 2)
-
-        q = BatchQueue(k, m, chunk, batched_encode, max_batch=64)
-        stripes = [payload(k, chunk, seed=i) for i in range(100)]
-        parities = [q.submit(s) for s in stripes]
-        q.flush()
-        assert q.stripes == 100
-        assert q.batches <= 100     # coalescing happened (often far fewer)
-        for s, p in zip(stripes, parities):
-            assert np.array_equal(p, codec.encode(s))
-        q.close()
-
-    def test_callback_error_propagates(self, registry):
-        def boom(data, n, c):
-            raise RuntimeError("sidecar died")
-        q = BatchQueue(2, 1, 64, boom, max_batch=8)
-        q.submit(payload(2, 64))
-        with pytest.raises(RuntimeError, match="sidecar died"):
-            q.flush()
-        q.close()
 
 
 class TestPythonPluginBridge:

@@ -261,28 +261,6 @@ class TestTracedJitFeedsLedger:
 
 
 class TestRooflineReportTool:
-    def test_renders_bench_artifact(self, tmp_path, capsys):
-        from ceph_tpu.ops.codec import RSCodec
-        codec = RSCodec(8, 4, device="jax")
-        data = np.random.default_rng(3).integers(
-            0, 256, (8, 4096), np.uint8)
-        for _ in range(2):
-            codec.encode(data)
-        block = roofline.bench_block("cpu")
-        art = tmp_path / "art.json"
-        art.write_text(json.dumps(
-            {"metric": "m", "value": 1.0, "efficiency": block}))
-        tool = _load_tool("roofline_report")
-        assert tool.main([str(art)]) == 0
-        out = capsys.readouterr().out
-        row = next(line for line in out.splitlines() if "4x8" in line)
-        # nonzero achieved GB/s + a bound classification on the row
-        assert row.split()[-1] in ("memory", "compute")
-        assert float(row.split()[-4]) > 0            # GB/S column
-        assert tool.main([str(art), "--json"]) == 0
-        doc = json.loads(capsys.readouterr().out)
-        assert doc["executables"]
-
     def test_renders_flight_bundle_and_snapshot(self, tmp_path, capsys):
         key = (((4, 8), "uint8"),)
         roofline.record_compile("enc", key, 100.0, 1000.0)
@@ -498,37 +476,3 @@ class TestHbmWatermarks:
         assert m2["faketpu:0"]["high_water_bytes"] == 90
         assert m2["faketpu:0"]["high_water_ratio"] == pytest.approx(0.9)
         device_telemetry._hbm_high_water.pop("faketpu:0", None)
-
-
-class TestBenchPreflight:
-    """Satellite 1: the r05 silent-CPU-fallback mode dies at the source."""
-
-    def _bench(self):
-        spec = importlib.util.spec_from_file_location(
-            "bench_t", _REPO / "bench.py")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_mismatch_raises_named_error(self, monkeypatch):
-        bench = self._bench()
-        monkeypatch.setenv("BENCH_EXPECT_PLATFORM", "tpu")
-        with pytest.raises(bench.PlatformMismatchError):
-            bench.preflight_platform("cpu")
-        with pytest.raises(bench.PlatformMismatchError):
-            bench.preflight_platform(None)
-        bench.preflight_platform("tpu")            # match passes
-
-    def test_jax_platforms_env_is_the_default_request(self, monkeypatch):
-        bench = self._bench()
-        monkeypatch.delenv("BENCH_EXPECT_PLATFORM", raising=False)
-        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
-        assert bench.requested_platform() == "tpu"
-        with pytest.raises(bench.PlatformMismatchError):
-            bench.preflight_platform("cpu")
-        # a comma list is jax's own fallback chain: no hard request
-        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
-        assert bench.requested_platform() is None
-        bench.preflight_platform("cpu")
-        monkeypatch.delenv("JAX_PLATFORMS")
-        assert bench.requested_platform() is None

@@ -372,26 +372,29 @@ class TestFlightAndArtifacts:
         finally:
             c.shutdown()
 
-    def test_slo_report_from_bench_line(self, tmp_path):
-        """The acceptance pin: slo_report reproduces the attribution
-        table from the bench artifact alone."""
-        line = {"metric": "m", "value": 1.0, "slo": {
-            "device": "cpu",
-            "client": {"p99_ms": 41.0, "ops": 64,
-                       "phases": {"batch_delay": 0.62, "device": 0.21,
-                                  "wire": 0.09, "other": 0.08},
-                       "objective_p99_ms": 100.0,
-                       "budget_remaining": 0.97,
-                       "burn_fast": 0.1, "burn_slow": 0.2}}}
-        p = tmp_path / "bench.json"
-        p.write_text(json.dumps(line))
+    def test_slo_report_main_renders_burn_table(self, tmp_path):
+        """slo_report's CLI reproduces the attribution AND the error-
+        budget table from a flight bundle file alone."""
+        bundle = {"reason": "SLO_BURN", "slo": {"slo": {
+            "attribution": {"client": {
+                "p99_ms": 41.0, "ops": 64,
+                "phases": {"batch_delay": 0.62, "device": 0.21,
+                           "wire": 0.09, "other": 0.08}}},
+            "objectives": {"client": {
+                "objective_p99_ms": 100.0, "budget_remaining": 0.97,
+                "fast": {"burn": 0.1}, "slow": {"burn": 0.2}}}}}}
+        p = tmp_path / "flight.json"
+        p.write_text(json.dumps(bundle))
         mod = self._slo_report()
         assert mod.main([str(p), "--json"]) == 0
-        report = mod.build_report(line)
+        report = mod.build_report(bundle)
         text = mod.render(report)
         assert "client p99 = 41.0 ms (64 ops): 62% batch_delay, " \
                "21% device, 9% wire" in text
         assert "97%" in text
+        # an artifact of neither shape is refused, not guessed at
+        p.write_text(json.dumps({"metric": "m", "value": 1.0}))
+        assert mod.main([str(p)]) == 1
 
     def test_slo_report_from_trace_dump(self, tmp_path):
         tr = default_tracer()
@@ -408,22 +411,3 @@ class TestFlightAndArtifacts:
         assert report["source"] == "trace"
         assert report["classes"]["client"]["ops"] == 1
         assert report["classes"]["client"]["phases"]["device"] > 0.5
-
-    def test_bench_block_shape_gates(self):
-        """The bench `slo` block exposes exactly the paths
-        tools/perf_gate.py digs (slo.client.p99_ms /
-        slo.client.budget_remaining)."""
-        led = CritPathLedger(name="bb")
-        try:
-            tr = _tracker(led, slo_client_p99_ms=100.0)
-            for _ in range(8):
-                led.ingest("client", 0.002, {"device": 0.002})
-            block = tr.bench_block("cpu")
-            assert block["device"] == "cpu"
-            assert block["client"]["p99_ms"] == pytest.approx(2.0)
-            assert block["client"]["budget_remaining"] == 1.0
-            assert sum(block["client"]["phases"].values()) == \
-                pytest.approx(1.0, abs=0.01)
-            tr.close()
-        finally:
-            led.close()

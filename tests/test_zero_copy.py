@@ -123,25 +123,6 @@ class TestSidebandCodec:
         tiny = net.RpcCall(2, "put", {"data": os.urandom(8)}, session="S")
         assert net._encode_parts(tiny, SECRET) is None
 
-    def test_kill_switch_gates_encode_side_only(self):
-        payload = os.urandom(4096)
-        msg = net.RpcCall(9, "put", {"data": payload}, session="S")
-        parts = net._encode_parts(msg, SECRET)
-        assert parts is not None
-        net.set_zero_copy(False)
-        try:
-            assert net._encode_parts(msg, SECRET) is None
-        finally:
-            net.set_zero_copy(True)
-        # decode accepts sideband frames regardless of the switch:
-        # mixed peers interoperate
-        net.set_zero_copy(False)
-        try:
-            got = _parse_one(_flatten(parts), SECRET)
-        finally:
-            net.set_zero_copy(True)
-        assert bytes(got.args["data"]) == payload
-
 
 # -- the stream parser: chunking, reordering, lifetime -----------------------
 
@@ -400,52 +381,70 @@ def served(tmp_path):
 
 
 class TestEndToEnd:
-    def _pings(self, server, keyring, n, size, seed, zero_copy):
+    def _mux(self, server, keyring):
         from ceph_tpu.msg import MuxClient
-        # the cluster cct IS the process default context, so the mux
-        # client's ms_zero_copy observer (adopted at construction) sees
-        # the override — net.set_zero_copy alone would be re-adopted
-        conf = server.cluster.cct.conf
-        saved = conf.get("ms_zero_copy")
-        conf.set("ms_zero_copy", zero_copy)
         mux = MuxClient("127.0.0.1", server.port, keyring, n_conns=1)
-        rng = _rng(seed)
-        try:
-            mux.connect()
-            s = mux.session()
-            for i in range(n):
-                payload = bytes(rng.integers(0, 256, size=size,
-                                             dtype=np.uint8))
-                echoed = s.call("ping", {"payload": payload},
-                                timeout=30.0)
-                assert bytes(echoed) == payload
-        finally:
-            mux.close()
-            conf.set("ms_zero_copy", saved)
+        mux.connect()
+        return mux
 
-    def test_fused_and_legacy_arms_agree_and_contrast(self, served):
-        """Both transport arms echo bulk payloads bitwise; the ledger
-        separates them — the fused arm moves each served byte at most
-        ~1.5 times, the legacy arm at least ~2.5 (pickle + join +
-        unpickle per direction)."""
+    def test_sideband_echo_copies_each_byte_at_most_1p5_times(
+            self, served):
+        """Bulk payloads echo bitwise through the mux transport and the
+        ledger counts each served byte moved at most ~1.5 times, all of
+        it the sanctioned landing copies (staging / materialize), none
+        of it codec copies."""
         server, keyring = served
         led = copy_ledger.ledger()
         led.reset()
-        self._pings(server, keyring, 8, 65536, seed=1, zero_copy=True)
-        fused = led.snapshot()
-        led.reset()
+        mux = self._mux(server, keyring)
+        rng = _rng(1)
         try:
-            self._pings(server, keyring, 8, 65536, seed=2,
-                        zero_copy=False)
+            s = mux.session()
+            for _ in range(8):
+                payload = bytes(rng.integers(0, 256, size=65536,
+                                             dtype=np.uint8))
+                assert bytes(s.call("ping", {"payload": payload},
+                                    timeout=30.0)) == payload
         finally:
-            net.set_zero_copy(True)
-        legacy = led.snapshot()
+            mux.close()
+        fused = led.snapshot()
         assert fused["served"] >= 8 * 2 * 65536
-        assert legacy["served"] >= 8 * 2 * 65536
         assert fused["copies_per_byte"] <= 1.5, fused
-        assert legacy["copies_per_byte"] >= 2.5, legacy
-        # the fused arm's copies are the sanctioned landing copies, not
-        # codec copies
         sanctioned = fused["copied"]["staging"] \
             + fused["copied"]["materialize"]
         assert sanctioned >= 0.9 * fused["copied_total"], fused
+
+    def test_all_pickle_frames_decode_beside_sideband_on_one_connection(
+            self, served):
+        """A peer that frames a bulk payload all-pickle (two segments,
+        as ``net.Channel`` does for every send) is served on the same
+        connection as sideband frames: ``net._decode`` dispatches on
+        the segment count, and every echo is bit-equal."""
+        server, keyring = served
+        led = copy_ledger.ledger()
+        mux = self._mux(server, keyring)
+        rng = _rng(3)
+        size = 4 * net._SB_SPLICE_MIN
+        try:
+            s = mux.session()
+            conn, = [c for c in mux._conns if c is not None]
+            for i in range(6):
+                payload = bytes(rng.integers(0, 256, size=size,
+                                             dtype=np.uint8))
+                if i % 2:       # this request leaves all-pickle
+                    conn._encode_parts = lambda msg: None
+                base = led.snapshot()["copied"]
+                try:
+                    echoed = s.call("ping", {"payload": payload},
+                                    timeout=30.0)
+                finally:
+                    conn.__dict__.pop("_encode_parts", None)
+                assert bytes(echoed) == payload
+                now = led.snapshot()["copied"]
+                if i % 2:
+                    assert now["unpickle"] - base["unpickle"] >= size
+                else:
+                    assert now["unpickle"] == base["unpickle"]
+                    assert now["staging"] - base["staging"] >= size
+        finally:
+            mux.close()

@@ -1,6 +1,7 @@
-"""Operator tooling (benchmarks, gates, reports).
+"""Operator tooling (reports over the program's own dumps, the chaos
+campaign, ceph-lint).
 
-A package so bench.py and the tests can import the reusable entry
-points (``tools.rados_bench.run_mux_bench``, ``tools.perf_gate``)
-without path hacks; each script remains directly runnable too.
+A package so the tests can import the reusable entry points
+(``tools.chaos_run``, ``tools.ceph_lint``) without path hacks; each
+script remains directly runnable too.
 """

@@ -20,7 +20,6 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-from collections import Counter
 
 
 def _analysis():
@@ -30,29 +29,10 @@ def _analysis():
     return A
 
 
-def lint_summary(baseline: str | None = None) -> dict:
-    """The ``lint`` block bench.py embeds in its JSON artifact:
-    per-rule finding counts plus the new-vs-baseline split, so
-    perf_gate history shows the finding-count trajectory."""
-    A = _analysis()
-    findings = A.run_rules(A.default_index())
-    base = A.load_baseline(baseline)
-    new, suppressed, stale = A.split_by_baseline(findings, base)
-    return {
-        "total": len(findings),
-        "new": len(new),
-        "baselined": len(suppressed),
-        "stale_baseline": len(stale),
-        "rules_run": len(A.all_rules()),
-        "by_rule": dict(sorted(Counter(
-            f.rule for f in findings).items())),
-    }
-
-
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         prog="ceph_lint",
-        description="static analysis over ceph_tpu/, tools/, bench.py")
+        description="static analysis over ceph_tpu/ and tools/")
     ap.add_argument("--baseline", metavar="FILE", default=None,
                     help="suppression file; baselined findings don't "
                          "fail the run")

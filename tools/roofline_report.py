@@ -5,8 +5,6 @@ Renders the roofline ledger (common/roofline.py) from an artifact alone
 — no live process required (the ts_report discipline).  Accepted inputs,
 auto-detected:
 
-- a bench.py JSON line (its ``efficiency`` block), or a driver
-  ``BENCH_r*.json`` wrapper (``parsed.efficiency``);
 - a flight-recorder bundle (its ``efficiency`` source — the full
   roofline snapshot);
 - a raw ``roofline.snapshot()`` / ``device roofline`` JSON document.
@@ -15,7 +13,7 @@ For every executable: calls, modeled FLOPs/bytes, arithmetic intensity,
 achieved GB/s and GFLOP/s over the measured dispatch seconds, percent of
 the binding roofline peak, and the memory/compute-bound classification.
 
-    python tools/roofline_report.py BENCH_r08.json
+    python tools/roofline_report.py snapshot.json
     python tools/roofline_report.py flight-....json --json
 
 Stdlib-only, standalone on purpose (tools/trace_report.py's discipline).
@@ -33,10 +31,7 @@ def extract(doc: dict) -> dict | None:
     of rows each carrying an ``executable`` key."""
     if not isinstance(doc, dict):
         return None
-    # driver wrapper -> bench line
-    if isinstance(doc.get("parsed"), dict):
-        doc = doc["parsed"]
-    # bench line / flight bundle -> their efficiency block/source
+    # flight bundle -> its efficiency source
     if isinstance(doc.get("efficiency"), dict):
         doc = doc["efficiency"]
     execs = doc.get("executables")
@@ -48,11 +43,8 @@ def extract(doc: dict) -> dict | None:
     else:
         rows = [dict(r) for r in execs if isinstance(r, dict)]
     return {"peaks": doc.get("peaks") or {},
-            "device": doc.get("device"),
             "totals": doc.get("totals"),
-            "pct_of_peak": doc.get("pct_of_peak"),
-            "executables": rows,
-            "error": doc.get("error")}
+            "executables": rows}
 
 
 def _fmt_qty(v: float) -> str:
@@ -69,15 +61,11 @@ def render(data: dict, limit: int = 20) -> str:
     peaks = data["peaks"]
     lines = []
     head = []
-    if data.get("device"):
-        head.append(f"device={data['device']}")
     if peaks:
         head.append(f"peaks {peaks.get('flops', 0) / 1e12:.1f} TFLOP/s / "
                     f"{peaks.get('hbm_bytes_s', 0) / 1e9:.0f} GB/s "
                     f"({peaks.get('source')})")
-    pct = data.get("pct_of_peak")
-    if pct is None and isinstance(data.get("totals"), dict):
-        pct = data["totals"].get("pct_of_peak")
+    pct = (data.get("totals") or {}).get("pct_of_peak")
     if pct is not None:
         head.append(f"aggregate {pct:.2f}% of peak")
     if head:
@@ -103,8 +91,8 @@ def render(data: dict, limit: int = 20) -> str:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="per-executable roofline table from a bench "
-                    "artifact, flight bundle, or roofline snapshot")
+        description="per-executable roofline table from a flight "
+                    "bundle or roofline snapshot")
     ap.add_argument("artifact", help="JSON document to render")
     ap.add_argument("--limit", type=int, default=20,
                     help="max executable rows (default 20)")
@@ -117,12 +105,8 @@ def main(argv=None) -> int:
     data = extract(doc)
     if data is None:
         print(f"error: no efficiency/roofline data in {args.artifact} "
-              f"(expected a bench line with an 'efficiency' block, a "
-              f"flight bundle, or a roofline snapshot)", file=sys.stderr)
-        return 2
-    if data.get("error") and not data["executables"]:
-        print(f"error: artifact carries an efficiency error marker: "
-              f"{data['error']}", file=sys.stderr)
+              f"(expected a flight bundle or a roofline snapshot)",
+              file=sys.stderr)
         return 2
     try:
         if args.json:

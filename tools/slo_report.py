@@ -10,8 +10,6 @@ is answered post-hoc, from the file alone.
 
 Inputs, auto-detected:
 
-- a ``bench.py`` JSON line (or a driver ``BENCH_r*.json`` wrapper, via
-  its ``parsed`` field) — uses the ``slo`` block;
 - a flight-recorder bundle (``flight-*.json``) — uses its ``slo``
   source (the SLO status + full critical-path ledger snapshot the
   WARN/ERR auto-capture rides);
@@ -20,7 +18,6 @@ Inputs, auto-detected:
   module is stdlib-only and loaded by PATH, so this tool stays
   standalone).
 
-    python tools/slo_report.py BENCH_r11.json
     python tools/slo_report.py DATA_DIR/flight/flight-...-SLO_BURN.json
     python tools/slo_report.py trace.json --json
 """
@@ -49,29 +46,6 @@ def _load_by_path(rel: str, name: str):
 _critpath = _load_by_path("ceph_tpu/common/critpath.py",
                           "_ceph_tpu_critpath")
 _phases_line = _critpath.format_phase_mix
-
-
-def from_bench_line(line: dict) -> dict:
-    """Normalize a bench line's ``slo`` block into the report shape."""
-    block = line.get("slo")
-    if not isinstance(block, dict):
-        raise ValueError("artifact has no `slo` block")
-    classes: dict = {}
-    burn: dict = {}
-    for cls, entry in block.items():
-        if not isinstance(entry, dict) or "p99_ms" not in entry:
-            continue
-        classes[cls] = {"p99_ms": entry["p99_ms"],
-                        "ops": entry.get("ops", 0),
-                        "phases": entry.get("phases", {})}
-        if "budget_remaining" in entry:
-            burn[cls] = {
-                "objective_p99_ms": entry.get("objective_p99_ms"),
-                "burn_fast": entry.get("burn_fast"),
-                "burn_slow": entry.get("burn_slow"),
-                "budget_remaining": entry["budget_remaining"]}
-    return {"source": "bench", "device": block.get("device"),
-            "classes": classes, "burn": burn}
 
 
 def from_flight_bundle(doc: dict) -> dict:
@@ -137,19 +111,14 @@ def from_trace_dump(doc) -> dict:
 
 def build_report(doc) -> dict:
     """Auto-detect the artifact shape and normalize it."""
-    if isinstance(doc, dict) and isinstance(doc.get("parsed"), dict):
-        doc = doc["parsed"]                        # BENCH_r wrapper
     if isinstance(doc, dict) and "slo" in doc and \
             isinstance(doc["slo"], dict) and "slo" in doc["slo"]:
         return from_flight_bundle(doc)
-    if isinstance(doc, dict) and "slo" in doc:
-        return from_bench_line(doc)
     if isinstance(doc, list) or (isinstance(doc, dict)
                                  and "traceEvents" in doc):
         return from_trace_dump(doc)
-    raise ValueError("unrecognized artifact: need a bench line with an "
-                     "`slo` block, a flight bundle with an `slo` "
-                     "source, or a trace dump")
+    raise ValueError("unrecognized artifact: need a flight bundle with "
+                     "an `slo` source, or a trace dump")
 
 
 def render(report: dict) -> str:
@@ -181,8 +150,8 @@ def render(report: dict) -> str:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="render SLO attribution/burn tables from a bench "
-                    "line, flight bundle, or trace dump")
+        description="render SLO attribution/burn tables from a flight "
+                    "bundle or trace dump")
     ap.add_argument("artifact")
     ap.add_argument("--json", action="store_true",
                     help="emit the normalized report as JSON")
