@@ -158,10 +158,10 @@ def gf_apply(mat, data, variant: str = "auto"):
     mat = jnp.asarray(mat, dtype=jnp.uint8)
     data = jnp.asarray(data, dtype=jnp.uint8)
     if variant == "auto":
-        # Fused pallas pipeline on TPU (measured ~1.1-1.3x the XLA bitslice
-        # path at k=8,m=4 — unpacked bit-planes never round-trip HBM);
-        # XLA paths elsewhere.  Tiny matrices with short rows stay on the
-        # VPU lookup path where the MXU can't amortise its unpack.
+        # Fused pallas pipeline on TPU (unpacked bit-planes never
+        # round-trip HBM); XLA paths elsewhere.  Tiny matrices with short
+        # rows stay on the VPU lookup path where the MXU can't amortise
+        # its unpack.
         if mat.shape[0] * mat.shape[1] < 8:
             variant = "lookup"
         elif _runs_on_tpu(data) and data.shape[1] >= 1024:

@@ -7,7 +7,8 @@ references (``gf/ref.py`` in pure numpy, the scalar ``crush_do_rule``
 path, the bytes a client wrote) outside any timed region:
 
   kernel      RS(8,4) cauchy, 64 stripes x 1 MiB resident in HBM, through
-              the vertical and the horizontal kernel selectors; then the
+              the vertical and the horizontal kernel selectors, and the
+              benchmark's 256 stripes through the horizontal one; then the
               HashInfo checksum (crc32c_rows) at the served shard shapes
   served      MiniCluster + serving engine + ClusterServer + TcpRados:
               put, read, degraded read, repair, kill -9 and reload
@@ -42,14 +43,15 @@ REPO = Path(__file__).resolve().parent
 
 # the sizes a deployment would run (ISSUE 21 tentpole §1) and the tiny
 # ones a --rehearsal runs on the CPU
-REAL = dict(stripes=64, chunk=131072, n_osds=12, objects=64,
+REAL = dict(stripes=64, resident_stripes=256, chunk=131072, n_osds=12,
+            objects=64,
             object_bytes=4 << 20, clients=16, overwrite=8,
             bulk_osds=256, bulk_pgs=32768, bulk_sample=1024,
             ec_size=1048576,
             # the served shard of a 4 MiB and of a 64 KiB object, and an
             # odd one: PR 21's wrong crc was at the first, only on the chip
             crc_shapes=((12, 524288), (12, 8192), (5, 777)))
-TINY = dict(stripes=8, chunk=1024, n_osds=12, objects=8,
+TINY = dict(stripes=8, resident_stripes=24, chunk=1024, n_osds=12, objects=8,
             object_bytes=4 * 8 * 1024, clients=4, overwrite=2,
             bulk_osds=32, bulk_pgs=256, bulk_sample=32,
             ec_size=65536, crc_shapes=((12, 8192), (5, 777)))
@@ -205,29 +207,44 @@ def phase_kernels(cfg: dict, rng, on_tpu: bool) -> None:
     def apply_horiz(Mt, Dd):
         return rs_kernels.gf_apply(Mt, Dd, "auto")
 
-    for name, mat, src_h, want_h in cases:
+    def check_apply(layout, fn, name, mat, data, want):
         mat_d = jax.device_put(jnp.asarray(mat))
-        for layout, fn, data, want in (
-                ("vertical", apply_vert, to_vert(src_h), to_vert(want_h)),
-                ("horizontal", apply_horiz, src_h, want_h)):
-            data_d = jax.device_put(jnp.asarray(data))
-            jfn = jax.jit(fn)
-            if on_tpu:
-                # positive proof of WHICH kernel the selector picked
-                check("tpu_custom_call" in jfn.lower(mat_d, data_d).as_text(),
-                      f"{layout} {name}: selector under 'auto' did not "
-                      f"lower to the pallas kernel (no tpu_custom_call)")
-            t0 = time.perf_counter()
-            got = jax.block_until_ready(jfn(mat_d, data_d))
-            first = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            got = jax.block_until_ready(jfn(mat_d, data_d))
-            steady = time.perf_counter() - t0
-            check(np.array_equal(np.asarray(got), want),
-                  f"{layout} {name} {tuple(data.shape)} differs from gf/ref")
-            say(f"  {layout:10s} {name:8s} {tuple(mat.shape)} x "
-                f"{tuple(data.shape)}: bit-equal; first_call_s={first:.3f} "
-                f"steady_call_s={steady:.5f}")
+        data_d = jax.device_put(jnp.asarray(data))
+        jfn = jax.jit(fn)
+        if on_tpu:
+            # positive proof of WHICH kernel the selector picked
+            check("tpu_custom_call" in jfn.lower(mat_d, data_d).as_text(),
+                  f"{layout} {name}: selector under 'auto' did not "
+                  f"lower to the pallas kernel (no tpu_custom_call)")
+        t0 = time.perf_counter()
+        got = jax.block_until_ready(jfn(mat_d, data_d))
+        first = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        got = jax.block_until_ready(jfn(mat_d, data_d))
+        steady = time.perf_counter() - t0
+        check(np.array_equal(np.asarray(got), want),
+              f"{layout} {name} {tuple(data.shape)} differs from gf/ref")
+        say(f"  {layout:10s} {name:8s} {tuple(mat.shape)} x "
+            f"{tuple(data.shape)}: bit-equal; first_call_s={first:.3f} "
+            f"steady_call_s={steady:.5f}")
+
+    for name, mat, src_h, want_h in cases:
+        check_apply("vertical", apply_vert, name, mat,
+                    to_vert(src_h), to_vert(want_h))
+        check_apply("horizontal", apply_horiz, name, mat, src_h, want_h)
+
+    # the benchmark's resident row (ec_resident_b256: 256 stripes x 1 MiB
+    # as one [8, 33554432] array), fresh bytes: four times the grid steps
+    # of the row above, the shape whose rate the ledger records
+    wide = rng.integers(0, 256, size=(K, cfg["resident_stripes"] * n),
+                        dtype=np.uint8)
+    wide_parity = gfref.apply_matrix(pm, wide)
+    check_apply("horizontal", apply_horiz, "encode", pm, wide, wide_parity)
+    D, src = decode_matrix(pm, ERASURES_TWO)
+    wide_full = np.concatenate([wide, wide_parity], axis=0)
+    check_apply("horizontal", apply_horiz, "decode2", D, wide_full[src],
+                wide_full[ERASURES_TWO])
+    del wide, wide_parity, wide_full
 
     # the HashInfo checksum, bit for bit against the host's crc32c: rows
     # given from the host (words before the upload), rows on the device

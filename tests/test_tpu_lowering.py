@@ -120,6 +120,27 @@ def test_vertical_kernel_lowers_off_the_even_corners(v5e, as_tpu_host,
         assert "tpu_custom_call" in _vertical(v5e[0], k, r)
 
 
+@pytest.mark.parametrize("x64", [False, True])
+@pytest.mark.parametrize("r,k,n", [
+    (4, 8, 33554432),    # ec_resident_b256: 256 stripes x 1 MiB, encode
+    (2, 8, 33554432),    # ... and its two-erasure decode
+    (4, 8, 524288),      # a served 4 MiB put
+    (1, 8, 4096),        # off the even corners, as the vertical kernel
+    (3, 10, 4096),       # is tested above: odd r, k no multiple of 4,
+    (3, 7, 4096),        # chunks of 4 KiB
+    (2, 4, 4096),
+])
+def test_horizontal_kernel_lowers(v5e, r, k, n, x64):
+    """Column groups stacked on the sublanes, planes from packed words,
+    one block-diagonal int8 matmul: the scoped-VMEM limit at the
+    widest grid step and Mosaic's block rules at every (k, r), with the
+    i64 constants a placing process would trace."""
+    with jax.enable_x64(x64):
+        text = _compile(pallas_kernels.gf_apply_pallas,
+                        _sds(v5e[0], (r, k)), _sds(v5e[0], (k, n)))
+    assert "tpu_custom_call" in text
+
+
 def test_pallas_lowers_with_x64_on(v5e, as_tpu_host):
     """A process that places PGs has x64 on; it must still encode."""
     with jax.enable_x64(True):
