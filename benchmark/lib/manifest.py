@@ -29,8 +29,9 @@ def load_benchmark(repo: Path = REPO) -> dict:
 def load_cell(name: str, repo: Path = REPO) -> dict:
     """``{name, chips, config, traffic, end_to_end, per_layer}`` of one
     workload: the configuration's and the traffic's own files, the
-    end-to-end metrics the cell reports, and the per-layer metric files
-    whose ``workloads`` list it."""
+    end-to-end metrics the cell reports, and the files of the per-layer
+    metrics whose ``BENCHMARK.json`` entries list it under ``workloads``
+    (the entry is the one list; a metric's file has no such key)."""
     bench = load_benchmark(repo)
     cell = next((w for w in bench["workloads"] if w["name"] == name), None)
     if cell is None:
