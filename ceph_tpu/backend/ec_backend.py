@@ -839,6 +839,7 @@ class ECBackend(PGBackend):
         k = self.ec_impl.get_data_chunk_count()
         result: dict[str, list[tuple[int, int, bytes]]] = {}
         errors: dict[str, int] = {}
+        chunks_reconstructed = 0
         for oid, extents in rop.to_read.items():
             by_chunk = rop.results.get(oid, {})
             by_chunk = {c: v for c, v in by_chunk.items()
@@ -857,7 +858,11 @@ class ECBackend(PGBackend):
                 continue
             # keep exactly k shards for decode
             chosen = dict(sorted(by_chunk.items())[:k])
+            erasures = sum(1 for i in range(k)
+                           if self.ec_impl.chunk_index(i) not in chosen)
+            chunks_reconstructed += erasures
             with trace_span("ec.decode", oid=oid, kind="client_read",
+                            erasures=erasures,
                             backend=self.instance_name), \
                     self.perf.time("decode_time"):
                 logical = self._serving_decode(chosen)
@@ -873,6 +878,9 @@ class ECBackend(PGBackend):
         del self.in_progress_reads[rop.tid]
         if result:
             self.perf.inc("reads")
+        if chunks_reconstructed:
+            self.perf.inc("reads_reconstructed")
+            self.perf.inc("chunks_reconstructed", chunks_reconstructed)
         if errors:
             self.perf.inc("read_errors", len(errors))
         self.perf.inc("read_bytes", sum(

@@ -856,6 +856,11 @@ class PGBackend:
             .add_u64_counter("write_rollbacks",
                              "in-flight writes rolled back (min_size)")
             .add_u64_counter("reads", "client reads completed")
+            .add_u64_counter("reads_reconstructed",
+                             "client reads that decoded at least one "
+                             "erased data chunk")
+            .add_u64_counter("chunks_reconstructed",
+                             "data chunks recovered by those reads")
             .add_u64_counter("read_errors", "per-object read failures (EIO)")
             .add_u64_counter("write_bytes", "client bytes written")
             .add_u64_counter("stripe_bytes_encoded",

@@ -40,6 +40,10 @@ PG_PREFIXES = ("ec_backend.", "replicated_backend.", "pg_backend.")
 # bus + TCP messenger byte/op counters, per-op-class rollups
 WIRE_PREFIXES = ("wire.",)
 
+# the device-occupancy ledger (common/device_attribution.py): its
+# `batches` counts work the chip did, never a host-only pipeline item
+DEVICE_PREFIXES = ("device_attribution",)
+
 
 def live_aggregators() -> list["StatsAggregator"]:
     return list(_AGGREGATORS)
@@ -257,7 +261,9 @@ class StatsAggregator:
                     self.wire_bytes_per_byte_repaired(),
             },
             "serving": {
-                "batch_s": self.rate("batches"),
+                # device batches only: the coalescer's own `batches`
+                # also counts a clean read's host-only batch
+                "batch_s": self.rate("batches", DEVICE_PREFIXES),
                 "op_s": self.rate("ops_completed"),
                 "bytes_s": self.rate("bytes_in"),
                 # client+serving wire bytes per completed client op

@@ -414,7 +414,10 @@ class CodecPipeline:
             fut._event.wait()
             return fut
         result, error = None, None
-        recorded = device_ok = False
+        # a host-only item (a clean read's relayout) occupies no device:
+        # it records no device batch, here or on a failure below
+        recorded = fut._dev is None
+        device_ok = False
         try:
             with activate_trace(fut.trace), \
                     trace_span("pipeline.complete", kind=fut.kind,
@@ -432,9 +435,10 @@ class CodecPipeline:
                 # device_get transfer and the host-side unpack below are
                 # HOST time — charging them would inflate busy_s and the
                 # owner's share while the chip sits idle
-                device_attribution.record_batch(fut.owner,
-                                                fut._dispatched_at, nbytes)
-                recorded = True
+                if not recorded:
+                    device_attribution.record_batch(
+                        fut.owner, fut._dispatched_at, nbytes)
+                    recorded = True
                 host = None
                 if dev is not None:
                     with trace_span("pipeline.fetch", bytes=nbytes):
