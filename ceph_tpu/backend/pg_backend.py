@@ -815,6 +815,13 @@ class PGBackend:
         # = the point already announced to the shards
         self.committed_to = self.pg_log.head
         self._rolled_forward_to = self.pg_log.head
+        # handed down by whoever hosts this PG: is another op waiting for
+        # the cluster right now?  True defers the standalone roll-forward
+        # kick of a drained pipeline — the host notes the PG and calls
+        # kick_roll_forward() at its next idle moment, unless the PG's
+        # next sub-write has carried the point by then.  Nobody waits on
+        # the in-process API.
+        self.defer_kick = lambda backend: False
         self._rollback_pending = 0
         # shards that revived but have not been repaired yet: excluded from
         # reads AND from write fan-out until a shard repair completes (the
@@ -853,6 +860,14 @@ class PGBackend:
                              "of those, writes that adopted both (the "
                              "rest re-ran them live: the plan was not "
                              "the one prepared for)")
+            .add_u64_counter("rollforward_kicks",
+                             "standalone RollForward messages sent (one "
+                             "a current shard, each a store transaction "
+                             "of its own there)")
+            .add_u64_counter("rollforward_deferred",
+                             "pipeline drains that sent none because "
+                             "another op waited for the cluster: the "
+                             "PG's next sub-write carries the point")
             .add_u64_counter("write_rollbacks",
                              "in-flight writes rolled back (min_size)")
             .add_u64_counter("reads", "client reads completed")
@@ -1240,6 +1255,7 @@ class PGBackend:
         self.try_finish_rmw()
 
     def try_finish_rmw(self) -> None:
+        drained = False
         while self.waiting_commit:
             op = self.waiting_commit[0]
             # shards that died after dispatch can never ack
@@ -1257,6 +1273,7 @@ class PGBackend:
                 self._rollback_incomplete()
                 return
             self.waiting_commit.popleft()
+            drained = True
             self.committed_to = max(self.committed_to, op.at_version)
             self._op_reset_extra(op)
             del self.tid_to_op[op.tid]
@@ -1270,14 +1287,29 @@ class PGBackend:
                 op.tracked.finish()
             if op.on_commit:
                 op.on_commit(op.tid)
-        # pipeline drained with an unannounced roll-forward point: kick it
-        # to the shards so they drop rollback data (the reference's dummy
-        # transaction, ECBackend.cc:2106-2120)
+        # pipeline drained with an unannounced roll-forward point.  While
+        # another op waits for the cluster the PG's next sub-write carries
+        # it (roll_forward_to, applied inside that sub-write's transaction)
+        # and the host settles whatever is still owed once nobody waits;
+        # otherwise kick it to the shards now
         if self.committed_to > self._rolled_forward_to:
-            self._rolled_forward_to = self.committed_to
-            for shard in sorted(self.current_shards()):
-                self.bus.send(shard, RollForward(self.whoami,
-                                                 self.committed_to))
+            if not self.defer_kick(self):
+                self.kick_roll_forward()
+            elif drained:
+                self.perf.inc("rollforward_deferred")
+
+    def kick_roll_forward(self) -> None:
+        """Announce an unannounced roll-forward point to the current
+        shards in a message of its own, so they drop rollback data (the
+        reference's dummy transaction, ECBackend.cc:2106-2120).  Nothing
+        to announce (the next sub-write carried it): nothing sent."""
+        if self.committed_to <= self._rolled_forward_to:
+            return
+        self._rolled_forward_to = self.committed_to
+        shards = sorted(self.current_shards())
+        for shard in shards:
+            self.bus.send(shard, RollForward(self.whoami, self.committed_to))
+        self.perf.inc("rollforward_kicks", len(shards))
 
     def _rollback_incomplete(self) -> None:
         """Undo every in-flight commit-stage write (head first failed; all

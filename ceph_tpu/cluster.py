@@ -175,6 +175,13 @@ class MiniCluster:
         # next deliver_all() surfaces them (raising from inside the
         # daemon drain would strand the rest of the queue)
         self._deferred_errors: list[tuple[str, int, str]] = []
+        # is another op waiting for this cluster?  Whoever serves it says
+        # (ClusterServer: its dispatch queue and the workers at its lock);
+        # on the in-process API nobody does.  While somebody waits, a PG
+        # whose pipeline drains defers its standalone roll-forward kick
+        # (PGBackend.defer_kick) and is noted here until settle_kicks()
+        self.others_waiting = lambda: False
+        self.kicks_owed: set = set()
         # ONE cluster-wide message bus: each OSD registers a single
         # endpoint that demuxes PG-enveloped traffic to its hosted PGs —
         # the reference's one-messenger-per-OSD topology
@@ -793,6 +800,7 @@ class MiniCluster:
                                   pool.pool_id, ps),
                               epoch=self.osdmap.epoch,
                               bus=self.bus)
+            pgs[ps].backend.defer_kick = self._defer_kick
             self.osds[acting[0]].register_pg(pgid, pgs[ps])
             self._arm_hit_sets(pgs[ps], pool)
             if self.serving is not None and ec is not None:
@@ -1231,6 +1239,24 @@ class MiniCluster:
             raise IOError(out["errors"])
         return out["result"][oid][0][2][:length]
 
+    def _defer_kick(self, backend) -> bool:
+        if not self.others_waiting():
+            return False
+        self.kicks_owed.add(backend)
+        return True
+
+    def settle_kicks(self) -> None:
+        """Send the roll-forward kicks that drained PGs deferred while
+        other ops waited (those whose next sub-write has carried the
+        point since send nothing), so that an idle pool holds no rollback
+        data.  The host calls this once nobody waits."""
+        if not self.kicks_owed:
+            return
+        owed, self.kicks_owed = self.kicks_owed, set()
+        for backend in owed:
+            backend.kick_roll_forward()
+        self.bus.deliver_all()
+
     def deliver_all(self) -> None:
         """Run everything queued: daemon op queues FIRST (batched
         deliver=False ops park there — bus delivery alone would never
@@ -1605,6 +1631,8 @@ class MiniCluster:
             header = store.get_omap_header(gobj) if ec is None and \
                 store.exists(gobj) else b""
             metadata[oid] = (attrs, omap, header)
+        # its collections go below, and a kick it still owed with them
+        self.kicks_owed.discard(old.backend)
         old.shutdown(discard_stores=self.data_dir is not None)
         # destroy the outgoing incarnation's collections: the new group
         # reuses the same collection name, and OSDs present in BOTH
@@ -1622,6 +1650,7 @@ class MiniCluster:
                       store_factory=self._store_factory(pool_id, ps),
                       epoch=self.osdmap.epoch,
                       bus=self.bus)
+        new.backend.defer_kick = self._defer_kick
         for oid, data in contents.items():
             t = PGTransaction().write(oid, 0, data)
             attrs, omap, header = metadata[oid]

@@ -600,6 +600,12 @@ class ClusterServer:
     def __init__(self, cluster, host: str = "127.0.0.1", port: int = 0):
         self.cluster = cluster
         self.lock = threading.Lock()          # ONE cluster at a time
+        # calls on their way to the lock: dequeued by a worker, preparing
+        # or waiting for it.  With the dispatch queue's depth it is what
+        # the cluster reads as "another op is waiting" (_others_waiting)
+        self._bound_for_lock = 0
+        self._bound_lock = threading.Lock()
+        cluster.others_waiting = self._others_waiting
         self.keyserver = KeyServer()
         self._load_or_create_keys()
         self.handler = CephxServiceHandler(SERVICE, self.keyserver)
@@ -772,7 +778,33 @@ class ClusterServer:
 
     # -- RPC dispatch --------------------------------------------------------
 
+    def _others_waiting(self) -> bool:
+        """Asked by the op that holds the lock: does another call wait
+        behind it, at the lock or in the dispatch queue?"""
+        if self._bound_for_lock:
+            return True
+        t = self._transport
+        return t is not None and t.dispatcher.depth > 0
+
+    def _bound(self, n: int) -> None:
+        with self._bound_lock:
+            self._bound_for_lock += n
+
     def _dispatch(self, ch: Channel, call: RpcCall) -> RpcResult:
+        try:
+            return self._dispatch_call(ch, call)
+        finally:
+            # a call that leaves nobody waiting settles the roll-forward
+            # kicks PGs deferred while somebody was: it delays no one.
+            # Here and not inside the call's hold, because the call a PG
+            # deferred for may never take the lock (a resend answered
+            # from the cache, an unknown method)
+            if self.cluster.kicks_owed and not self._others_waiting():
+                with self.lock:
+                    if not self._others_waiting():
+                        self.cluster.settle_kicks()
+
+    def _dispatch_call(self, ch: Channel, call: RpcCall) -> RpcResult:
         t0 = time.perf_counter()
         if instruments.enabled():
             # copy-ledger denominator: request payload bytes reaching
@@ -823,15 +855,21 @@ class ClusterServer:
                 sname = _RPC_SPAN_NAMES[call.method] = "rpc." + call.method
             track = "server" if trace is not None else None
             args = call.args
-            if call.method == "put":
-                # a put's codec work runs HERE, in this worker, while
-                # another op holds the lock; the locked section adopts it
-                with tr.activate(trace, track=track):
-                    args = self._prepare_put(args)
-            t_ask = t_got = time.perf_counter()
+            self._bound(1)
+            bound = True
+            t_ask = t_got = t0
             try:
+                if call.method == "put":
+                    # a put's codec work runs HERE, in this worker, while
+                    # another op holds the lock; the locked section
+                    # adopts it
+                    with tr.activate(trace, track=track):
+                        args = self._prepare_put(args)
+                t_ask = t_got = time.perf_counter()
                 with self.lock:
                     t_got = time.perf_counter()
+                    self._bound(-1)
+                    bound = False
                     if trace is not None:
                         with tr.activate(trace, track="server"), \
                                 tr.span(sname, cat="rpc"):
@@ -844,6 +882,8 @@ class ClusterServer:
                         value = fn(ch, **args)
                         tr.observe(sname, t_got, cat="rpc")
             finally:
+                if bound:
+                    self._bound(-1)
                 # the wait for the one cluster lock, recorded after the
                 # lock is released so it adds nothing to the hold; a
                 # put's prepare (worker dequeue -> lock asked for) comes

@@ -207,6 +207,51 @@ class TestClusterRestart:
             assert not shard.pending_rollbacks, \
                 "stale rollback data survived boot roll-forward"
 
+    @pytest.mark.parametrize("store_backend", ["file", "bluestore"])
+    def test_stop_with_a_deferred_kick_owed_drops_it_on_boot(
+            self, tmp_path, store_backend):
+        """ISSUE 34's twin of the case above: the write was ACKED, and
+        its roll-forward kick deferred because other ops waited, when
+        the process stopped.  Boot keeps the write and drops the undo
+        record (the old chunk) from RAM and from the pgmeta omap."""
+        from ceph_tpu.backend.ec_backend import OSDShard
+        from ceph_tpu.backend.pg_backend import PG_META
+
+        def shards(g):
+            return [h if isinstance(h, OSDShard) else h.local_shard
+                    for h in g.bus.handlers.values()]
+
+        def rb_keys(shard):
+            return [k for k in shard.store.get_omap(
+                GObject(PG_META, shard.shard)) if k.startswith("rb.")]
+        c1 = MiniCluster(n_osds=12, chunk_size=256, data_dir=tmp_path,
+                         store_backend=store_backend)
+        pid = c1.create_ec_pool("pool", self.PROFILE, pg_num=1)
+        c1.others_waiting = lambda: True            # a busy server
+        c1.put(pid, "x", payload(2048, seed=1))
+        new = payload(2048, seed=2)
+        acked = []
+        c1.put(pid, "x", new, on_commit=acked.append)
+        assert acked
+        g = c1.pools[pid]["pgs"][0]
+        assert g.backend in c1.kicks_owed
+        for shard in shards(g):
+            assert len(shard.pending_rollbacks) == 1 and rb_keys(shard)
+        c1.shutdown()                               # process stops here
+
+        c2 = MiniCluster.load(tmp_path)
+        pid2 = c2.pool_ids["pool"]
+        assert c2.get(pid2, "x", 2048) == new, "an acked write was lost"
+        gg = c2.pools[pid2]["pgs"][0]
+        for shard in shards(gg):
+            assert not shard.pending_rollbacks, \
+                "stale rollback data survived boot roll-forward"
+            assert not rb_keys(shard)
+        assert all(gg.backend.be_deep_scrub("x").values())
+        c2.put(pid2, "x", payload(2048, seed=3))
+        assert c2.get(pid2, "x", 2048) == payload(2048, seed=3)
+        c2.shutdown()
+
     def test_writes_after_restart(self, tmp_path):
         c1 = MiniCluster(n_osds=12, chunk_size=256, data_dir=tmp_path)
         pid = c1.create_ec_pool("pool", self.PROFILE, pg_num=2)

@@ -200,6 +200,49 @@ class TestRollback:
             assert not shard_obj(bus, backend, s).pending_rollbacks, \
                 f"shard {s} still holds rollback data after drain"
 
+    def test_rollback_spares_the_committed_write_whose_kick_was_deferred(
+            self, cluster):
+        """ISSUE 34: while other ops wait, a drained pipeline announces
+        its roll-forward point with the PG's next sub-write instead of a
+        kick.  A later write that falls below min_size unwinds itself
+        alone: the committed write under it stays, kick or no kick."""
+        backend, bus = cluster
+        backend.defer_kick = lambda b: True          # somebody always waits
+        data1 = payload(STRIPE, seed=1)
+        self._commit_initial(backend, bus, data1)
+        assert backend.perf.get("rollforward_deferred") == 1
+        assert backend.perf.get("rollforward_kicks") == 0
+        for s in range(N):                  # v1's undo record: still held
+            assert list(shard_obj(bus, backend, s).pending_rollbacks) == [1]
+        old_chunks = {s: store_of(bus, backend, s).read(GObject("obj", s))
+                      for s in range(N)}
+        committed = []
+        backend.submit_transaction(
+            PGTransaction().write("obj", 0, payload(STRIPE, seed=2)),
+            on_commit=committed.append)
+        while bus.deliver_one(1) or bus.deliver_one(2):
+            pass
+        # the sub-write carried the point: v1's record went inside its
+        # transaction, v2's took its place
+        for s in (1, 2):
+            assert list(shard_obj(bus, backend, s).pending_rollbacks) == [2]
+        bus.mark_down(3)
+        bus.mark_down(4)
+        bus.deliver_all()
+        assert not committed, "write acked below min_size"
+        for s in (0, 1, 2, 5):
+            assert store_of(bus, backend, s).read(GObject("obj", s)) == \
+                old_chunks[s], f"shard {s} lost the committed write"
+            assert not shard_obj(bus, backend, s).pending_rollbacks
+        assert read_obj(backend, bus, "obj", STRIPE) == data1
+        assert backend.pg_log.head == 1 and backend.committed_to == 1
+        assert len(backend.waiting_state) == 1      # parked, not lost
+        bus.mark_up(3)
+        bus.deliver_all()
+        assert committed
+        assert read_obj(backend, bus, "obj", STRIPE) == payload(STRIPE,
+                                                                seed=2)
+
     def test_deep_scrub_clean_after_rollback_cycle(self, cluster):
         backend, bus = cluster
         data1 = payload(STRIPE, seed=1)
