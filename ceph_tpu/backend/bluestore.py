@@ -39,6 +39,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any
 
+from ..common.perf_counters import PerfCountersBuilder
 from ..common.tracer import trace_span
 from .ecutil import crc32c
 from .memstore import GObject, Transaction, _Object
@@ -154,8 +155,22 @@ class BlueStoreLite:
 
     def __init__(self, path: str | os.PathLike, min_alloc: int = 4096,
                  compression: str | None = None, sync: bool = False,
-                 checkpoint_every: int = 512):
+                 checkpoint_every: int = 512, name: str | None = None,
+                 cct=None):
         self.path = Path(path)
+        # what a committed transaction cost, summed: `perf dump` shows
+        # the collection while the store lives where a Context is given
+        self.perf = (
+            PerfCountersBuilder(f"bluestore.{name or self.path.name}")
+            .add_u64_counter("transactions", "transactions committed")
+            .add_u64_counter("txn_ops", "ops in them")
+            .add_u64_counter("block_bytes",
+                             "bytes written to the block file, as "
+                             "allocated (whole min_alloc units)")
+            .add_u64_counter("wal_bytes",
+                             "bytes appended to the metadata journal "
+                             "(frame header + pickled record)")
+            .create_perf_counters())
         self.path.mkdir(parents=True, exist_ok=True)
         self.min_alloc = min_alloc
         self.sync = sync
@@ -175,6 +190,9 @@ class BlueStoreLite:
         self._load()
         self._block = open(self.path / _BLOCK, "r+b")
         self._wal = open(self.path / _WAL, "ab")
+        self._cct = cct
+        if cct is not None:
+            cct.perf.add(self.perf)
 
     # -- persistence --------------------------------------------------------
 
@@ -241,6 +259,8 @@ class BlueStoreLite:
         self._wal_records = 0
 
     def close(self, checkpoint: bool = True) -> None:
+        if self._cct is not None:
+            self._cct.perf.remove(self.perf.name)
         if checkpoint:
             self.checkpoint()
         self._wal.close()
@@ -392,6 +412,11 @@ class BlueStoreLite:
             self._wal.flush()
             if self.sync:
                 os.fsync(self._wal.fileno())
+        perf = self.perf
+        perf.inc("transactions")
+        perf.inc("txn_ops", len(t.ops))
+        perf.inc("block_bytes", sum(b.alloc for b in new_blobs.values()))
+        perf.inc("wal_bytes", _FRAME.size + len(payload))
         self._wal_records += 1
         if self._wal_records >= self.checkpoint_every:
             self.checkpoint()

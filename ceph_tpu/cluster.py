@@ -867,7 +867,9 @@ class MiniCluster:
             return MemStore()
         if self.store_backend == "bluestore":
             from .backend.bluestore import BlueStoreLite
-            return BlueStoreLite(self.data_dir / f"osd.{osd}" / "store")
+            return BlueStoreLite(self.data_dir / f"osd.{osd}" / "store",
+                                 name=f"c{self.cluster_id}.osd{osd}",
+                                 cct=self.cct)
         from .backend.filestore import FileStore
         return FileStore(self.data_dir / f"osd.{osd}" / "store")
 

@@ -49,14 +49,17 @@ import time
 import weakref
 from collections import defaultdict, deque
 
-# the tracer ring's event capacity (mirrors tracer.TRACE_CAPACITY
-# without importing it: this module must stay loadable by PATH for
-# tools/slo_report.py).  Sizes the ledger's seen-trace bound: the ring
-# holds at most this many events, hence at most this many distinct
-# trace ids — a seen-set twice as large can never evict an id whose
-# events are still foldable.
-TRACE_CAPACITY_HINT = int(os.environ.get("CEPH_TPU_TRACE_CAPACITY",
-                                         16384))
+
+def _trace_capacity_hint() -> int:
+    """The tracer ring's event capacity, read when a ledger is made as
+    ``tracer.default_tracer()`` reads it when it makes the tracer
+    (mirrored without importing it: this module must stay loadable by
+    PATH for tools/slo_report.py).  Sizes the ledger's seen-trace bound:
+    the ring holds at most this many events, hence at most this many
+    distinct trace ids — a seen-set twice as large can never evict an id
+    whose events are still foldable."""
+    return int(os.environ.get("CEPH_TPU_TRACE_CAPACITY", 16384))
+
 
 try:
     from .device_attribution import canonical_owner
@@ -409,7 +412,7 @@ class CritPathLedger:
         # evicted from here is guaranteed gone from the ring too and
         # can never be re-folded as a duplicate
         self._seen_order: deque[int] = deque(
-            maxlen=2 * max(TRACE_CAPACITY_HINT, capacity))
+            maxlen=2 * max(_trace_capacity_hint(), capacity))
         self.unmapped: dict[str, int] = {}
         self.folded = 0
         _LEDGERS.add(self)

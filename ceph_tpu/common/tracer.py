@@ -46,7 +46,11 @@ from . import instruments
 # log-spaced span-latency bounds (seconds); one overflow bucket follows
 LATENCY_BUCKETS_S = (1e-6, 1e-5, 1e-4, 1e-3, 1e-2, 1e-1, 1.0, 10.0)
 
-TRACE_CAPACITY = int(os.environ.get("CEPH_TPU_TRACE_CAPACITY", 16384))
+# events the ring holds; the process-wide tracer takes
+# CEPH_TPU_TRACE_CAPACITY instead where it is set when default_tracer()
+# first makes it (not when this module is imported: a driver that is
+# imported after it can still size the ring for a busy cell)
+TRACE_CAPACITY = 16384
 
 # finished events buffered per thread before the batch folds into the
 # shared ring: the owning thread touches the ring lock once per batch
@@ -631,7 +635,8 @@ def default_tracer() -> Tracer:
     if _default_tracer is None:
         with _default_lock:
             if _default_tracer is None:
-                _default_tracer = Tracer()
+                _default_tracer = Tracer(int(os.environ.get(
+                    "CEPH_TPU_TRACE_CAPACITY", TRACE_CAPACITY)))
     return _default_tracer
 
 

@@ -39,11 +39,18 @@ def _data(n, seed=0):
 def _served(tmp_path, workers=None):
     c = MiniCluster(n_osds=K + M, osds_per_host=1, chunk_size=1024,
                     data_dir=tmp_path, store_backend="bluestore")
-    if workers is not None:
-        c.cct.conf.set("ms_async_op_threads", workers)
     serving = c.enable_serving(start=True)
     server = ClusterServer(c)
-    server.start()
+    # the context is the process's: the option is read at start() and
+    # put back at once, so that no later test file inherits it
+    conf = c.cct.conf
+    default = conf.get("ms_async_op_threads")
+    if workers is not None:
+        conf.set("ms_async_op_threads", workers)
+    try:
+        server.start()
+    finally:
+        conf.set("ms_async_op_threads", default)
     return c, serving, server
 
 

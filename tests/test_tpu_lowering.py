@@ -125,6 +125,9 @@ def test_vertical_kernel_lowers_off_the_even_corners(v5e, as_tpu_host,
     (4, 8, 33554432),    # ec_resident_b256: 256 stripes x 1 MiB, encode
     (2, 8, 33554432),    # ... and its two-erasure decode
     (4, 8, 524288),      # a served 4 MiB put
+    (4, 8, 8192),        # a served 64 KiB put at a 4 KiB stripe unit (two
+    (4, 8, 16384),       # stripes), and the buckets two and four of them
+    (4, 8, 32768),       # coalesce into (rados_write_64k_qd64)
     (1, 8, 4096),        # off the even corners, as the vertical kernel
     (3, 10, 4096),       # is tested above: odd r, k no multiple of 4,
     (3, 7, 4096),        # chunks of 4 KiB

@@ -48,6 +48,22 @@ class TestSpans:
         assert len(events) == 8
         assert [e["name"] for e in events] == [f"s{n}" for n in range(12, 20)]
 
+    def test_default_tracer_reads_its_capacity_when_it_is_made(
+            self, monkeypatch):
+        """CEPH_TPU_TRACE_CAPACITY is read when the process-wide tracer
+        is made, not when the module is imported: a driver imported
+        after it can still size the ring (ISSUE 35, ROADMAP B8)."""
+        from ceph_tpu.common import tracer as mod
+        assert mod.TRACE_CAPACITY == 16384
+        monkeypatch.setattr(mod, "_default_tracer", None)
+        monkeypatch.setenv("CEPH_TPU_TRACE_CAPACITY", "24")
+        assert default_tracer()._events.maxlen == 24
+        assert default_tracer() is default_tracer()
+        monkeypatch.setattr(mod, "_default_tracer", None)
+        monkeypatch.delenv("CEPH_TPU_TRACE_CAPACITY")
+        assert default_tracer()._events.maxlen == 16384
+        assert Tracer()._events.maxlen == 16384
+
     def test_chrome_trace_event_schema(self):
         t = Tracer()
         with t.span("work", cat="test", items=3):
