@@ -23,7 +23,10 @@ The durable store modeled on the reference's flagship ObjectStore
   table) journals through a WAL and periodic checkpoints, exactly like
   :class:`~ceph_tpu.backend.filestore.FileStore` — but checkpoints carry
   ONLY metadata, so their cost scales with object count, not data volume
-  (the r4 whole-store-pickle weakness).  The allocator's free list is
+  (the r4 whole-store-pickle weakness), and a WAL record carries what its
+  transaction CHANGED: a touched onode's small fields and the omap keys
+  set and removed, never the omap (a PG's ``_pgmeta_`` omap holds its
+  whole log, and every sub-write touches it).  The allocator's free list is
   REBUILT from the blob table on open (self-healing, like the
   reference's freelist-from-RocksDB startup).
 
@@ -45,6 +48,11 @@ from .ecutil import crc32c
 from .memstore import GObject, Transaction, _Object
 
 _FRAME = struct.Struct("<II")        # payload length, crc32c(payload)
+# first item of a WAL record: the shape of the rest.  "omap-delta": per
+# touched object None (removed) or (size, extents, xattrs, omap_header,
+# omap_cleared, omap_set, omap_rm).  A record without it (the whole-onode
+# records of the code before) is refused at open, never guessed at.
+_RECORD = "omap-delta"
 _SNAP = "kv.snap"
 _WAL = "kv.log"
 _BLOCK = "block"
@@ -84,11 +92,40 @@ class Onode:
     omap: dict[str, bytes] = field(default_factory=dict)
     omap_header: bytes = b""
 
-    def copy(self) -> "Onode":
-        return Onode(self.size,
-                     [Extent(e.loff, e.length, e.blob, e.boff)
-                      for e in self.extents],
-                     dict(self.xattrs), dict(self.omap), self.omap_header)
+
+@dataclass
+class _StagedOnode:
+    """A touched onode inside one transaction: the small fields copied,
+    the omap as an overlay on the live one — keys set, keys removed, a
+    "cleared" mark — never a copy of it."""
+    size: int = 0
+    extents: list[Extent] = field(default_factory=list)
+    xattrs: dict[str, Any] = field(default_factory=dict)
+    omap_header: bytes = b""
+    omap_base: dict[str, bytes] | None = None   # the live omap; None = cleared
+    omap_set: dict[str, bytes] = field(default_factory=dict)
+    omap_rm: set[str] = field(default_factory=set)
+
+    @classmethod
+    def of(cls, o: "Onode | _StagedOnode", omap_base) -> "_StagedOnode":
+        """``o``'s small fields copied, over ``omap_base``."""
+        return cls(o.size, [Extent(e.loff, e.length, e.blob, e.boff)
+                            for e in o.extents],
+                   dict(o.xattrs), o.omap_header, omap_base)
+
+    def clone(self) -> "_StagedOnode":
+        """A clone's destination: cleared + the source's keys as they
+        stand in this transaction."""
+        dst = self.of(self, None)
+        dst.omap_set.update(self.omap_base or ())
+        for key in self.omap_rm:
+            dst.omap_set.pop(key, None)
+        dst.omap_set.update(self.omap_set)
+        return dst
+
+    def record(self) -> tuple:
+        return (self.size, self.extents, self.xattrs, self.omap_header,
+                self.omap_base is None, self.omap_set, self.omap_rm)
 
 
 class RunListAllocator:
@@ -170,6 +207,9 @@ class BlueStoreLite:
             .add_u64_counter("wal_bytes",
                              "bytes appended to the metadata journal "
                              "(frame header + pickled record)")
+            .add_u64_counter("checkpoints",
+                             "metadata snapshots written (every onode "
+                             "pickled whole, the journal restarted)")
             .create_perf_counters())
         self.path.mkdir(parents=True, exist_ok=True)
         self.min_alloc = min_alloc
@@ -216,8 +256,13 @@ class BlueStoreLite:
                         crc32c(0xFFFFFFFF, payload) != crc:
                     break             # torn tail: never committed
                 off += _FRAME.size + length
-                seq, onode_delta, blob_delta, freed, nb = \
-                    pickle.loads(payload)
+                record = pickle.loads(payload)
+                if record[0] != _RECORD:
+                    raise RuntimeError(
+                        f"{wal}: a committed record of another shape than "
+                        f"{_RECORD!r} (a store killed under older code): "
+                        f"reopen and close it with the code that wrote it")
+                _, seq, onode_delta, blob_delta, freed, nb = record
                 if seq <= snap_seq:
                     continue          # predates the checkpoint
                 self._apply_meta(onode_delta, blob_delta, freed)
@@ -233,30 +278,47 @@ class BlueStoreLite:
         for bid in freed:
             self.blobs.pop(bid, None)
         self.blobs.update(blob_delta)
-        for obj, onode in onode_delta.items():
-            if onode is None:
+        self._apply_onodes(onode_delta)
+
+    def _apply_onodes(self, onode_delta) -> None:
+        """What a record says of its touched objects, onto the onode table:
+        a commit's last step on the live table, and a replay's."""
+        for obj, rec in onode_delta.items():
+            if rec is None:
                 self.onodes.pop(obj, None)
-            else:
-                self.onodes[obj] = onode
+                continue
+            # the overlay lands on the onode the records before left
+            o = self.onodes.get(obj)
+            if o is None:
+                o = self.onodes[obj] = Onode()
+            (o.size, o.extents, o.xattrs, o.omap_header,
+             cleared, omap_set, omap_rm) = rec
+            if cleared:
+                o.omap.clear()
+            for key in omap_rm:
+                o.omap.pop(key, None)
+            o.omap.update(omap_set)
 
     def checkpoint(self) -> None:
         """Metadata-only snapshot (onodes + blob table): cost scales with
         object count, never data volume — the block file IS the data."""
-        self._block.flush()
-        if self.sync:
-            os.fsync(self._block.fileno())
-        tmp = self.path / (_SNAP + ".tmp")
-        with open(tmp, "wb") as f:
-            pickle.dump((self.committed_seq, self.onodes, self.blobs,
-                         self.next_blob), f,
-                        protocol=pickle.HIGHEST_PROTOCOL)
-            f.flush()
+        with trace_span("store.checkpoint"):
+            self._block.flush()
             if self.sync:
-                os.fsync(f.fileno())
-        os.replace(tmp, self.path / _SNAP)
-        self._wal.close()
-        self._wal = open(self.path / _WAL, "wb")
-        self._wal_records = 0
+                os.fsync(self._block.fileno())
+            tmp = self.path / (_SNAP + ".tmp")
+            with open(tmp, "wb") as f:
+                pickle.dump((self.committed_seq, self.onodes, self.blobs,
+                             self.next_blob), f,
+                            protocol=pickle.HIGHEST_PROTOCOL)
+                f.flush()
+                if self.sync:
+                    os.fsync(f.fileno())
+            os.replace(tmp, self.path / _SNAP)
+            self._wal.close()
+            self._wal = open(self.path / _WAL, "wb")
+            self._wal_records = 0
+        self.perf.inc("checkpoints")
 
     def close(self, checkpoint: bool = True) -> None:
         if self._cct is not None:
@@ -357,17 +419,20 @@ class BlueStoreLite:
     def queue_transaction(self, t: Transaction) -> int:
         """Apply atomically; journal the metadata delta; return the seq.
 
-        Staging mirrors MemStore: copies of only the touched onodes; blob
-        refcount changes are tracked and only applied on success."""
+        Staging mirrors MemStore, less the omap: the touched onodes'
+        small fields are copied, their omap changes collect as an overlay
+        (:class:`_StagedOnode`) that is applied to the live omap in place
+        on success and is all the record says of the omap; blob refcount
+        changes are tracked and only applied on success."""
         touched: set[GObject] = set()
         for op in t.ops:
             touched.add(op[1])
             if op[0] == "clone":
                 touched.add(op[2])
-        staged: dict[GObject, Onode | None] = {}
+        staged: dict[GObject, _StagedOnode | None] = {}
         for obj in touched:
             o = self.onodes.get(obj)
-            staged[obj] = o.copy() if o is not None else None
+            staged[obj] = None if o is None else _StagedOnode.of(o, o.omap)
         new_blobs: dict[int, Blob] = {}
         deref: list[int] = []       # blob ids losing one reference
         addref: list[int] = []      # blob ids gaining one (clone/split)
@@ -389,18 +454,16 @@ class BlueStoreLite:
                 self.blobs[bid].refs += 1
             freed: list[int] = []
             self._deref(deref, freed)
-            for obj, onode in staged.items():
-                if onode is None:
-                    self.onodes.pop(obj, None)
-                else:
-                    self.onodes[obj] = onode
+            onode_delta = {obj: o.record() if o is not None else None
+                           for obj, o in staged.items()}
+            blob_delta = {bid: self.blobs[bid] for bid in
+                          set(new_blobs) - set(freed)} | \
+                         {bid: self.blobs[bid] for bid in addref + deref
+                          if bid in self.blobs}
+            self._apply_onodes(onode_delta)
             self.committed_seq += 1
             payload = pickle.dumps(
-                (self.committed_seq, staged,
-                 {bid: self.blobs[bid] for bid in
-                  set(new_blobs) - set(freed)} |
-                 {bid: self.blobs[bid] for bid in addref + deref
-                  if bid in self.blobs},
+                (_RECORD, self.committed_seq, onode_delta, blob_delta,
                  freed, self.next_blob),
                 protocol=pickle.HIGHEST_PROTOCOL)
             self._block.flush()          # data precedes its metadata
@@ -426,9 +489,9 @@ class BlueStoreLite:
         kind = op[0]
         obj = op[1]
 
-        def node() -> Onode:
+        def node() -> _StagedOnode:
             if staged.get(obj) is None:
-                staged[obj] = Onode()
+                staged[obj] = _StagedOnode()    # new: nothing to overlay
             return staged[obj]
 
         if kind == "write":
@@ -465,23 +528,29 @@ class BlueStoreLite:
             if old is not None:
                 deref.extend(e.blob for e in old.extents)
             if so is None:
-                staged[dst] = Onode()
+                staged[dst] = _StagedOnode()
             else:
-                staged[dst] = so.copy()
+                staged[dst] = so.clone()
                 addref.extend(e.blob for e in so.extents)
         elif kind == "setattr":
             node().xattrs[op[2]] = op[3]
         elif kind == "rmattr":
             node().xattrs.pop(op[2], None)
         elif kind == "omap_setkeys":
-            node().omap.update(op[2])
+            o = node()
+            o.omap_set.update(op[2])
+            o.omap_rm.difference_update(op[2])
         elif kind == "omap_rmkeys":
             o = node()
             for key in op[2]:
-                o.omap.pop(key, None)
+                o.omap_set.pop(key, None)
+            if o.omap_base is not None:
+                o.omap_rm.update(op[2])
         elif kind == "omap_clear":
             o = node()
-            o.omap.clear()
+            o.omap_base = None
+            o.omap_set.clear()
+            o.omap_rm.clear()
             o.omap_header = b""
         elif kind == "omap_setheader":
             node().omap_header = op[2]

@@ -35,7 +35,8 @@ PROFILE = {"plugin": "jax_rs", "k": str(K), "m": str(M),
            "technique": "cauchy", "device": "jax"}
 SIZES = {"one_stripe": WIDTH, "two_stripes": 2 * WIDTH,
          "three_stripes": 3 * WIDTH, "two_stripes_less_100": 2 * WIDTH - 100}
-COUNTERS = ("transactions", "txn_ops", "block_bytes", "wal_bytes")
+COUNTERS = ("transactions", "txn_ops", "block_bytes", "wal_bytes",
+            "checkpoints")
 
 
 def _payload(n, seed):
