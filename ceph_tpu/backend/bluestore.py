@@ -190,6 +190,20 @@ class BlueStoreLite:
     """Durable ObjectStore over ONE block file + metadata WAL/checkpoint;
     same surface as MemStore/FileStore."""
 
+    # one committed transaction in this many is timed: a phase clock
+    # books where its wall time went and its thread's CPU time (two
+    # reads, first and last in the store.commit span and in no phase),
+    # each microsecond this many times, so the sums estimate all
+    # transactions'.  The CPU clock is a system call that keeps the
+    # interpreter, 5.6 us a read on the chip's host and some 17 in a
+    # busy server, and it ticks at 100 Hz there: six reads on every
+    # commit lengthened a put's hold by 15% (PERF.md, PR 37).  A
+    # constant, and a prime, so that no short pattern in a store's
+    # transactions keeps step with it (a sub-write and its roll-forward
+    # kick alternate on an idle pool: an even stride would time one
+    # kind only); tests patch it to 1
+    TIMED_EVERY = 17
+
     def __init__(self, path: str | os.PathLike, min_alloc: int = 4096,
                  compression: str | None = None, sync: bool = False,
                  checkpoint_every: int = 512, name: str | None = None,
@@ -210,6 +224,34 @@ class BlueStoreLite:
             .add_u64_counter("checkpoints",
                              "metadata snapshots written (every onode "
                              "pickled whole, the journal restarted)")
+            # where a committed transaction's wall time went, in
+            # microseconds: four consecutive phases of one clock that
+            # tile the store.commit span (BlueStore.cc's state_*_lat and
+            # kv_*_lat, as sums), and the thread's CPU clock over the
+            # same stretch; from one transaction in TIMED_EVERY, each
+            # booked that many times
+            .add_u64_counter("stage_us",
+                             "staging: the ops applied to the staged "
+                             "onodes (less their block writes), blob "
+                             "refcounts, the record's deltas, the onode "
+                             "table")
+            .add_u64_counter("record_us",
+                             "the journal record pickled and its crc")
+            .add_u64_counter("block_io_us",
+                             "block file calls: a blob's seek + write, "
+                             "the flush (and fsync where sync) before "
+                             "the record")
+            .add_u64_counter("wal_io_us",
+                             "journal calls: the frame and record "
+                             "written, the flush (and fsync where sync)")
+            .add_u64_counter("commit_cpu_us",
+                             "the committing thread's CPU clock over "
+                             "the four phases; their sum less this is "
+                             "what the thread did not run: blocked in "
+                             "a call or waiting for the interpreter")
+            .add_u64_counter("checkpoint_us",
+                             "wall time of the metadata snapshots "
+                             "(beside checkpoints)")
             .create_perf_counters())
         self.path.mkdir(parents=True, exist_ok=True)
         self.min_alloc = min_alloc
@@ -227,6 +269,10 @@ class BlueStoreLite:
             self._compressor = CompressorRegistry.instance().create(
                 compression)
         self._wal_records = 0
+        # which of every TIMED_EVERY: by the store's name, so that a
+        # put's twelve stores do not all time the same put's commits
+        self._timed_at = crc32c(0, self.perf.name.encode())
+        self._clk = self.perf.phase_clock("commit_cpu_us")
         self._load()
         self._block = open(self.path / _BLOCK, "r+b")
         self._wal = open(self.path / _WAL, "ab")
@@ -302,7 +348,7 @@ class BlueStoreLite:
     def checkpoint(self) -> None:
         """Metadata-only snapshot (onodes + blob table): cost scales with
         object count, never data volume — the block file IS the data."""
-        with trace_span("store.checkpoint"):
+        with trace_span("store.checkpoint") as span:
             self._block.flush()
             if self.sync:
                 os.fsync(self._block.fileno())
@@ -319,6 +365,7 @@ class BlueStoreLite:
             self._wal = open(self.path / _WAL, "wb")
             self._wal_records = 0
         self.perf.inc("checkpoints")
+        self.perf.inc("checkpoint_us", int(span.dur * 1e6 + 0.5))
 
     def close(self, checkpoint: bool = True) -> None:
         if self._cct is not None:
@@ -347,8 +394,10 @@ class BlueStoreLite:
                 stored = candidate
                 comp = self.compression
         poff, alloc = self.alloc.alloc(max(1, len(stored)))
+        self._clk.mark("stage_us")
         self._block.seek(poff)
         self._block.write(stored)
+        self._clk.mark("block_io_us")
         bid = self.next_blob
         self.next_blob += 1
         blob = Blob(poff=poff, plen=len(stored), alloc=alloc,
@@ -438,7 +487,10 @@ class BlueStoreLite:
         addref: list[int] = []      # blob ids gaining one (clone/split)
         # what makes the transaction durable: the block writes (in
         # _apply), the block flush + fsync, the WAL append + fsync
+        clk = self._clk
         with trace_span("store.commit"):
+            clk.start(not (self.committed_seq + self._timed_at)
+                      % self.TIMED_EVERY)
             try:
                 for op in t.ops:
                     self._apply(staged, op, new_blobs, deref, addref)
@@ -462,19 +514,24 @@ class BlueStoreLite:
                           if bid in self.blobs}
             self._apply_onodes(onode_delta)
             self.committed_seq += 1
+            clk.mark("stage_us")
             payload = pickle.dumps(
                 (_RECORD, self.committed_seq, onode_delta, blob_delta,
                  freed, self.next_blob),
                 protocol=pickle.HIGHEST_PROTOCOL)
+            frame = _FRAME.pack(len(payload), crc32c(0xFFFFFFFF, payload))
+            clk.mark("record_us")
             self._block.flush()          # data precedes its metadata
             if self.sync:
                 os.fsync(self._block.fileno())
-            self._wal.write(_FRAME.pack(len(payload),
-                                        crc32c(0xFFFFFFFF, payload)))
+            clk.mark("block_io_us")
+            self._wal.write(frame)
             self._wal.write(payload)
             self._wal.flush()
             if self.sync:
                 os.fsync(self._wal.fileno())
+            clk.stop("wal_io_us")
+        clk.commit(self.TIMED_EVERY)
         perf = self.perf
         perf.inc("transactions")
         perf.inc("txn_ops", len(t.ops))

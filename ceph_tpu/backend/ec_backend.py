@@ -465,8 +465,6 @@ class ECBackend(PGBackend):
                 logical = np.concatenate(
                     [np.frombuffer(b, dtype=np.uint8) for _, b in pieces])
                 encoded = self._encode_traced(logical, oid=oid)
-            self.perf.inc("stripe_bytes_encoded",
-                          sum(len(b) for _, b in pieces))
             if op.tracked:
                 op.tracked.mark_event("encoded")
             # scatter per-extent chunk ranges into shard transactions

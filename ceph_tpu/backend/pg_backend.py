@@ -878,9 +878,6 @@ class PGBackend:
                              "data chunks recovered by those reads")
             .add_u64_counter("read_errors", "per-object read failures (EIO)")
             .add_u64_counter("write_bytes", "client bytes written")
-            .add_u64_counter("stripe_bytes_encoded",
-                             "stripe-aligned bytes through encode (>= "
-                             "write_bytes: RMW pads to whole stripes)")
             .add_u64_counter("read_bytes", "logical bytes returned")
             .add_u64_counter("recoveries", "recovery ops completed")
             .add_u64_counter("recovery_bytes",

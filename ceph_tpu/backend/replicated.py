@@ -130,8 +130,6 @@ class ReplicatedBackend(PGBackend):
                         raise ValueError(f"unknown omap op {oop[0]!r}")
                 if not is_delete:
                     t.setattr(obj, VERSION_KEY, entry.version)
-            self.perf.inc("stripe_bytes_encoded", sum(
-                len(d) for _, d in objop.buffer_updates))
         return shard_txns, log_entries
 
     # -- read path -----------------------------------------------------------

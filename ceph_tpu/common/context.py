@@ -33,6 +33,11 @@ class Context:
         # the process-wide jit telemetry collection: shared by every
         # Context so any `perf dump` / prometheus render carries it
         self.perf.add(tracer_mod.jit_perf_counters())
+        # CPU time beside wall time, process-wide too: by span name for
+        # the spans that read their thread's CPU clock, and by role for
+        # the service threads
+        self.perf.add(tracer_mod.span_cpu_perf_counters())
+        self.perf.add(tracer_mod.thread_cpu_perf_counters())
         # the device-time attribution ledger (who occupies the chip, by
         # owner class) — process-wide for the same reason
         from . import device_attribution

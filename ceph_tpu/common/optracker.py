@@ -9,9 +9,9 @@ points).  Slow-op handling follows the reference's complaint path
 duration exceeds the configurable threshold is flagged ``slow``, counted on
 the owning subsystem's ``slow_ops`` perf counter, and kept in the historic
 dump with the flag set.  Every ``mark_event`` also lands on the process
-span tracer as an instant event, and ``finish`` emits the whole op as a
-complete span, so ``trace dump`` interleaves op timelines with the
-codec/kernel spans they caused.
+span tracer as an instant event, so ``trace dump`` interleaves op
+timelines with the codec/kernel spans they caused; the whole op's
+duration is its own (``dump_historic_ops``), not a span.
 """
 from __future__ import annotations
 
@@ -34,9 +34,6 @@ class TrackedOp:
     events: list[tuple[float, str]] = field(default_factory=list)
     slow: bool = False
     _done: bool = False
-    # the same instant as ``initiated_at`` on the tracer's clock: the
-    # dumps keep wall time, the ``op`` span is stamped from this
-    _t0: float = field(default_factory=time.perf_counter)
 
     def mark_event(self, event: str) -> None:
         self.events.append((time.time(), event))
@@ -48,9 +45,6 @@ class TrackedOp:
             self._done = True
             self.mark_event("done")
             self.tracker._finish(self)
-            default_tracer().observe("op", self._t0, cat="optracker",
-                                     seq=self.seq, desc=self.description,
-                                     slow=self.slow)
 
     @property
     def age(self) -> float:

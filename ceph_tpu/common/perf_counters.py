@@ -14,6 +14,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from . import instruments
+
 PERFCOUNTER_U64 = "u64"
 PERFCOUNTER_COUNTER = "counter"
 PERFCOUNTER_AVG = "avg"
@@ -54,6 +56,13 @@ class PerfCounters:
         # bucket_counts|None]} cells.  Registered under _lock; folded
         # (non-destructively) by readers under _lock.
         self._cells: dict[int, dict] = {}
+
+    def declare_counter(self, key: str, description: str) -> None:
+        """A u64 counter on a live collection, for a name first seen at
+        run time; nothing where it is there."""
+        with self._lock:
+            self._metrics.setdefault(
+                key, _Metric(PERFCOUNTER_COUNTER, description))
 
     # -- per-thread cells ---------------------------------------------------
 
@@ -169,6 +178,71 @@ class PerfCounters:
 
     def time(self, key: str) -> "_Timer":
         return self._Timer(self, key)
+
+    class _PhaseClock:
+        """A transaction's phases on one clock, for a caller whose
+        transactions are serial: ``start``, then consecutive ``mark``s
+        and a ``stop``, so the phases tile the stretch from the start to
+        the stop; ``commit`` adds the booked sums to the collection's
+        u64 adders (microseconds), and a transaction that fails before
+        it counts nothing: the next ``start`` drops what it booked.
+        The thread's CPU clock is read too, in ``start`` and ``stop``,
+        outside the wall clock's reads at both ends, so that no phase
+        holds a read, and ``commit`` books it under ``cpu_key``: the
+        phases' wall time less it is what the thread did not run,
+        blocked in a call or waiting for the interpreter.  (Only sums
+        say that: a CPU clock that ticks reads one transaction a whole
+        tick and the next ones nothing.)  A transaction started with
+        ``on`` false is not timed: no clock is read and every call up to
+        the next ``start`` is a no-op, as where ``instruments_enabled``
+        is false."""
+
+        __slots__ = ("pc", "cpu_key", "sums", "t", "cpu_ns", "on")
+
+        def __init__(self, pc, cpu_key: str):
+            self.pc = pc
+            self.cpu_key = cpu_key
+            self.sums: dict[str, float] = {}
+            self.on = False
+
+        def start(self, on: bool = True) -> None:
+            self.on = on = on and instruments.enabled()
+            if on:
+                self.sums.clear()
+                self.cpu_ns = -time.thread_time_ns()
+                self.t = time.perf_counter()
+
+        def mark(self, key: str) -> None:
+            """Book the time since the start or the previous mark under
+            ``key``."""
+            if self.on:
+                now = time.perf_counter()
+                self.sums[key] = self.sums.get(key, 0.0) + now - self.t
+                self.t = now
+
+        def stop(self, key: str) -> None:
+            """The last ``mark``."""
+            if self.on:
+                self.mark(key)
+                self.cpu_ns += time.thread_time_ns()
+
+        def commit(self, weight: int = 1) -> None:
+            """After ``stop``: the sums onto the adders, ``weight``
+            times each, for a caller that times one transaction in that
+            many."""
+            if not self.on:
+                return
+            self.on = False
+            # straight onto this thread's cells: the keys are u64 adders
+            # by the caller's contract
+            cell = self.pc._cell
+            for k, s in self.sums.items():
+                cell(k)[0] += int(s * 1e6 + 0.5) * weight
+            cell(self.cpu_key)[0] += self.cpu_ns // 1000 * weight
+
+    def phase_clock(self, cpu_key: str) -> "_PhaseClock":
+        """A phase clock over this collection's u64 adders."""
+        return self._PhaseClock(self, cpu_key)
 
     # -- dump --------------------------------------------------------------
 

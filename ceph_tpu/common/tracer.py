@@ -131,14 +131,19 @@ class Span:
 
     __slots__ = ("tracer", "name", "cat", "args", "ts_us", "dur",
                  "_t0", "trace_id", "span_id", "parent_id", "track",
-                 "op_class", "sampled", "weight")
+                 "op_class", "sampled", "weight", "cpu_us", "_c0")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
+    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict,
+                 cpu: bool = False):
         self.tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
         self.dur = 0.0
+        # the thread's CPU microseconds inside the span, for a span that
+        # asked (``cpu``): None until the exit, and for every other
+        self.cpu_us: float | None = None
+        self._c0: int | None = 0 if cpu else None
         # distributed-trace linkage (span_id/parent/class/weight) is
         # filled on __enter__ only when a TraceContext is active; a
         # nonzero trace_id is the "linked" flag (_trace_ids starts at 1)
@@ -178,9 +183,15 @@ class Span:
         self.track = track
         self._t0 = time.perf_counter()
         self.ts_us = (self._t0 - tracer._t0) * 1e6
+        if self._c0 is not None:
+            # read inside the wall clock's two reads, so that wall - CPU
+            # is the time the thread did not run and never below zero
+            self._c0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._c0 is not None:
+            self.cpu_us = (time.thread_time_ns() - self._c0) * 1e-3
         self.dur = time.perf_counter() - self._t0
         tracer = self.tracer
         if self.trace_id:
@@ -225,8 +236,8 @@ class Tracer:
 
     def __init__(self, capacity: int = TRACE_CAPACITY):
         # finished events: dicts, or lite tuples (name, cat, ts_us,
-        # dur_us, tid) from the untraced fast path — materialized by
-        # dump()
+        # dur_us, tid[, cpu_us]) from the untraced fast path —
+        # materialized by dump()
         self._events: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._local = threading.local()
@@ -358,10 +369,16 @@ class Tracer:
 
     # -- recording ----------------------------------------------------------
 
-    def span(self, name: str, cat: str = "", **args) -> Span:
+    def span(self, name: str, cat: str = "", cpu: bool = False,
+             **args) -> Span:
+        """``cpu=True`` also reads the thread's CPU clock at both ends:
+        the event carries ``cpu_us`` and the fold books it, and the
+        wall time less it, under the span's name in ``span_cpu``.  The
+        clock is a system call, and dear on some hosts (5.6 us and more
+        where ``perf_counter`` is 0.07; PERF.md, PR 37)."""
         if not instruments.enabled():
             return _NULL_SPAN
-        return Span(self, name, cat, args)
+        return Span(self, name, cat, args, cpu)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
         if not instruments.enabled():
@@ -378,10 +395,14 @@ class Tracer:
 
     def observe(self, name: str, t0: float, t1: float | None = None,
                 cat: str = "", ctx: TraceContext | None = None,
-                track: str | None = None, **args) -> None:
+                track: str | None = None, cpu_s: float | None = None,
+                **args) -> None:
         """Record a finished region measured with ``time.perf_counter()``
         (``t1`` defaults to now) — the ONE after-the-fact path: waits
         measured at dequeue, sends measured at return, TrackedOp ops.
+        ``cpu_s`` is the thread's CPU seconds inside the region where
+        the caller read ``time.thread_time_ns()`` at both ends: booked
+        as a ``cpu=True`` span's is.
 
         Bare (no ``ctx``/``track``/args) it is the allocation-light fast
         path for hot untraced regions: no Span object, no event dict, a
@@ -404,8 +425,9 @@ class Tracer:
             buf = getattr(self._local, "pending", None)
             if buf is None:
                 buf = self._pending_buf()
-            buf.append((name, cat, (t0 - self._t0) * 1e6, (t1 - t0) * 1e6,
-                        threading.get_ident()))
+            ev = (name, cat, (t0 - self._t0) * 1e6, (t1 - t0) * 1e6,
+                  threading.get_ident())
+            buf.append(ev if cpu_s is None else ev + (cpu_s * 1e6,))
             if len(buf) >= FLUSH_BATCH:
                 self._flush_buf(buf)
             return
@@ -431,11 +453,14 @@ class Tracer:
                 args["promoted"] = True
             elif getattr(ctx, "weight", 1.0) != 1.0:
                 args["sample_weight"] = ctx.weight
+        cpu_us = None
+        if cpu_s is not None:
+            args["cpu_us"] = cpu_us = cpu_s * 1e6
         if args:
             ev["args"] = args
         if track is not None:
             ev["track"] = track
-        self._emit(ev, name, dur_s)
+        self._emit(ev, name, dur_s, cpu_us)
 
     def _finish_span(self, span: Span) -> None:
         promoted = False
@@ -452,9 +477,11 @@ class Tracer:
         if not span.trace_id and not span.args and span.track is None:
             # the hot shape (untraced, no args, no track): defer the
             # event-dict build to dump() — evicted events never pay it
-            self._emit_lite((span.name, span.cat,
-                             span.ts_us, span.dur * 1e6,
-                             threading.get_ident()))
+            ev = (span.name, span.cat, span.ts_us, span.dur * 1e6,
+                  threading.get_ident())
+            if span.cpu_us is not None:
+                ev += (span.cpu_us,)
+            self._emit_lite(ev)
             return
         ev = {"name": span.name, "cat": span.cat or "span", "ph": "X",
               "ts": span.ts_us, "dur": span.dur * 1e6,
@@ -472,11 +499,13 @@ class Tracer:
                 args["promoted"] = True
             elif span.weight != 1.0:
                 args["sample_weight"] = span.weight
+        if span.cpu_us is not None:
+            args["cpu_us"] = span.cpu_us
         if args:
             ev["args"] = args
         if span.track is not None:
             ev["track"] = span.track
-        self._emit(ev, span.name, span.dur)
+        self._emit(ev, span.name, span.dur, span.cpu_us)
 
     # -- per-thread batching -------------------------------------------------
 
@@ -494,16 +523,17 @@ class Tracer:
         return buf
 
     def _emit(self, ev: dict, name: str | None = None,
-              dur_s: float = 0.0) -> None:
+              dur_s: float = 0.0, cpu_us: float | None = None) -> None:
         buf = self._pending_buf()
-        buf.append((ev, name, dur_s))
+        buf.append((ev, name, dur_s, cpu_us))
         if len(buf) >= FLUSH_BATCH:
             self._flush_buf(buf)
 
     def _emit_lite(self, ev: tuple) -> None:
         # a lite event rides the buffer BARE (no wrapper triple): the
-        # fold recognizes the 5-tuple shape and derives name/duration
-        # from it, so the hot path allocates one tuple per op, not two
+        # fold recognizes it by its first field, the name, and derives
+        # name/duration from it, so the hot path allocates one tuple per
+        # op, not two
         buf = getattr(self._local, "pending", None)
         if buf is None:
             buf = self._pending_buf()
@@ -525,15 +555,20 @@ class Tracer:
         items = buf[:n]
         del buf[:n]
         for item in items:
-            if len(item) == 5:
-                # bare lite event: (name, cat, ts_us, dur_us, tid)
+            if type(item[0]) is str:
+                # bare lite event: (name, cat, ts_us, dur_us, tid) and,
+                # from a span that read the CPU clock, cpu_us
                 self._events.append(item)
                 self._hist_add_locked(item[0], item[3] * 1e-6)
+                if len(item) > 5:
+                    _span_cpu_add(item[0], item[3], item[5])
             else:
-                ev, name, dur_s = item
+                ev, name, dur_s, cpu_us = item
                 self._events.append(ev)
                 if name is not None:
                     self._hist_add_locked(name, dur_s)
+                if cpu_us is not None:
+                    _span_cpu_add(name, dur_s * 1e6, cpu_us)
 
     def flush(self) -> None:
         """Fold the CALLING thread's pending batch into the ring — the
@@ -566,9 +601,12 @@ class Tracer:
         untraced span/observe fast path) build their dict HERE, once
         per surviving event, instead of once per op."""
         if type(ev) is tuple:
-            name, cat, ts, dur, tid = ev
-            return {"name": name, "cat": cat or "span", "ph": "X",
-                    "ts": ts, "dur": dur, "pid": self.pid, "tid": tid}
+            name, cat, ts, dur, tid = ev[:5]
+            out = {"name": name, "cat": cat or "span", "ph": "X",
+                   "ts": ts, "dur": dur, "pid": self.pid, "tid": tid}
+            if len(ev) > 5:
+                out["args"] = {"cpu_us": ev[5]}
+            return out
         return dict(ev)
 
     def dump(self, stitched: bool = True) -> dict:
@@ -661,9 +699,10 @@ def wire_config(conf) -> None:
         conf.add_observer("osd_op_complaint_time", _on_complaint)
 
 
-def trace_span(name: str, cat: str = "", **args) -> Span:
+def trace_span(name: str, cat: str = "", cpu: bool = False,
+               **args) -> Span:
     """Convenience: a span on the process-default tracer."""
-    return default_tracer().span(name, cat, **args)
+    return default_tracer().span(name, cat, cpu, **args)
 
 
 def trace_instant(name: str, cat: str = "", **args) -> None:
@@ -694,6 +733,121 @@ def root_or_ambient(op_class: str) -> _Activation:
     over the default)."""
     tr = default_tracer()
     return tr.activate(tr.current_ctx() or tr.new_trace(op_class))
+
+
+# -- CPU time beside wall time ----------------------------------------------
+#
+# Two process-wide collections every Context registers beside ``jit``.
+# ``span_cpu``: for each span name that read its thread's CPU clock
+# (``cpu=True`` / ``observe(cpu_s=)``), the CPU microseconds inside its
+# spans and the rest of their wall time: what the thread stood blocked
+# in a call or waiting for the interpreter.  Booked in the fold, beside
+# the name's histogram and from the same events, so a reader that drains
+# (``histograms()``) and then dumps sees sums of one set of spans;
+# ``perf dump`` alone lags a thread's unfolded batch, as the histograms
+# do.  ``thread_cpu``: each service thread's own CPU, by role.
+
+_cpu_lock = threading.Lock()
+_span_cpu_perf = None
+# span name -> [cpu key, off-cpu key, what the off-cpu sum is owed (<= 0)]
+_span_cpu_keys: dict[str, list] = {}
+_thread_cpu_perf = None
+_thread_cpu_local = threading.local()
+
+#: the roles a service thread charges its CPU to (``charge_thread_cpu``)
+THREAD_ROLES = ("reactor", "dispatch", "coalescer", "finisher")
+# a thread reads its CPU clock at most this often: the read is a system
+# call that keeps the interpreter (5.6 us on the chip's host, some 17
+# in a busy server) and a reactor runs thousands of rounds a second;
+# what a role lags by is under this a thread
+CHARGE_EVERY_S = 0.1
+
+
+def span_cpu_perf_counters():
+    """The process-wide ``span_cpu`` PerfCounters: ``<span>.cpu_us`` and
+    ``<span>.offcpu_us``, declared when a name's first event folds."""
+    global _span_cpu_perf
+    with _cpu_lock:
+        if _span_cpu_perf is None:
+            from .perf_counters import PerfCounters
+            _span_cpu_perf = PerfCounters("span_cpu")
+        return _span_cpu_perf
+
+
+def _span_cpu_add(name: str, dur_us: float, cpu_us: float) -> None:
+    # in a fold, under the folding tracer's lock
+    pc = _span_cpu_perf or span_cpu_perf_counters()
+    st = _span_cpu_keys.get(name)
+    if st is None:
+        st = [f"{name}.cpu_us", f"{name}.offcpu_us", 0]
+        pc.declare_counter(
+            st[0], f"microseconds of their thread's CPU clock inside "
+                   f"{name} spans")
+        pc.declare_counter(
+            st[1], f"microseconds of {name} spans' wall time their "
+                   f"thread did not run: blocked in a call or waiting "
+                   f"for the interpreter (wall - CPU, never below 0)")
+        _span_cpu_keys[name] = st
+    cpu = int(cpu_us + 0.5)
+    pc.inc(st[0], cpu)
+    # wall - CPU, summed: a CPU clock that ticks (100 Hz on the chip's
+    # host) charges one short span a whole tick and the next ninety
+    # nothing, so a span that reads more CPU than wall time is owed to
+    # the ones after it and not dropped.  The sum is what is never
+    # below 0, and cpu + off-cpu stays the spans' wall time
+    off = int(dur_us + 0.5) - cpu + st[2]
+    if off > 0:
+        pc.inc(st[1], off)
+        off = 0
+    st[2] = off
+
+
+def thread_cpu_perf_counters():
+    """The process-wide ``thread_cpu`` PerfCounters: microseconds of CPU
+    by service-thread role, and ``process`` — every thread of the
+    process, JAX's and the runtime's too — read from the process's own
+    clock at a reactor's charge, so as current as the roles are.  What
+    no role claims is ``process`` less the roles."""
+    global _thread_cpu_perf
+    with _cpu_lock:
+        if _thread_cpu_perf is None:
+            from .perf_counters import PerfCountersBuilder
+            b = PerfCountersBuilder("thread_cpu")
+            for role in THREAD_ROLES:
+                b.add_u64_counter(
+                    role, f"CPU microseconds of the {role} threads, "
+                          f"each charging its own thread clock at the "
+                          f"end of a round of its loop")
+            b.add_u64("process",
+                      "CPU microseconds of the whole process (user + "
+                      "system, every thread), as of a reactor thread's "
+                      "last charge")
+            _thread_cpu_perf = b.create_perf_counters()
+        return _thread_cpu_perf
+
+
+def charge_thread_cpu(role: str) -> None:
+    """Charge the calling thread's CPU since its last charge (since its
+    start, the first time) to ``role``: a read of the thread's CPU clock
+    and one sharded ``inc``, the state thread-local.  For a service
+    thread's loop, once a round, outside its locks; a round inside
+    ``CHARGE_EVERY_S`` of the thread's last charge reads the wall clock
+    only and leaves its CPU to the next.  A reactor's charge also
+    brings ``process`` up to date."""
+    if not instruments.enabled():
+        return
+    local = _thread_cpu_local
+    t = time.perf_counter()
+    if t < getattr(local, "due", 0.0):
+        return
+    local.due = t + CHARGE_EVERY_S
+    now = time.thread_time_ns()
+    us, rem = divmod(now - getattr(local, "ns", 0), 1000)
+    local.ns = now - rem
+    pc = _thread_cpu_perf or thread_cpu_perf_counters()
+    pc.inc(role, us)
+    if role == "reactor":
+        pc.set("process", time.process_time_ns() // 1000)
 
 
 # -- JIT telemetry registry (fed by ceph_tpu.ops.traced_jit) ----------------

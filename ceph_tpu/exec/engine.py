@@ -38,7 +38,8 @@ import numpy as np
 from ..backend import ecutil
 from ..common import default_context
 from ..common.perf_counters import PerfCountersBuilder
-from ..common.tracer import LATENCY_BUCKETS_S, default_tracer
+from ..common.tracer import (LATENCY_BUCKETS_S, charge_thread_cpu,
+                             default_tracer)
 from ..ops.pipeline import CodecPipeline
 from ..osd.mclock import CLIENT_OP, MClockOpClassQueue
 from .batcher import (BatchFuture, DECODE, ENCODE, bucket_pad_stripes,
@@ -60,7 +61,6 @@ def _build_perf(name: str):
     return (PerfCountersBuilder(name)
             .add_u64("queue_depth", "ops waiting for a batch slot")
             .add_u64("queue_bytes", "bytes waiting for a batch slot")
-            .add_u64_counter("ops_submitted", "ops admitted")
             .add_u64_counter("ops_rejected",
                              "fail-fast admissions refused (backpressure)")
             .add_u64_counter("ops_completed", "ops finished")
@@ -326,7 +326,6 @@ class ServingEngine:
                 self._eager += 1
             self.perf.set("queue_depth", self._depth)
             self.perf.set("queue_bytes", self._qbytes)
-            self.perf.inc("ops_submitted")
             self.perf.inc("bytes_in", op.cost_bytes)
             self._cond.notify()
         return op
@@ -532,6 +531,7 @@ class ServingEngine:
                 # idle edge: nothing to pack — retire the oldest in-flight
                 # device batch (completions ride the finisher as usual)
                 self.pipeline.complete_one()
+            charge_thread_cpu("coalescer")
 
     # -- deterministic driving (tests / inline mode) -----------------------
 

@@ -18,6 +18,8 @@ from __future__ import annotations
 import threading
 from collections import deque
 
+from ..common.tracer import charge_thread_cpu
+
 FINISHER_QUEUE_BOUND = 65536      # callbacks; far above any sane in-flight
 
 
@@ -101,6 +103,7 @@ class Finisher:
                 self._in_progress += 1
                 self._nonfull.notify()
             self._run_one(item)
+            charge_thread_cpu("finisher")
             with self._lock:
                 self._in_progress -= 1
                 if not self._queue and not self._in_progress:

@@ -469,13 +469,14 @@ def render(cct=None, prefix: str = "ceph_tpu") -> str:
     for coll_name, pc in sorted(cct.perf.snapshot().items()):
         label = f'collection="{coll_name}"'
         # fold the per-thread counter cells: hot-path inc/tinc/hinc land
-        # in thread-local shards, and a scrape must see them
+        # in thread-local shards, and a scrape must see them (a
+        # collection may declare a counter while it lives: the walk is
+        # over what the fold saw)
         with pc._lock:
-            folded = {key: pc._folded_locked(m, key)
+            folded = {key: (m, pc._folded_locked(m, key))
                       for key, m in pc._metrics.items()}
-        for key, m in sorted(pc._metrics.items()):
+        for key, (m, (value, total, count, bc)) in sorted(folded.items()):
             metric = f"{prefix}_{_sanitize(key)}"
-            value, total, count, bc = folded[key]
             if m.kind in (PERFCOUNTER_AVG, PERFCOUNTER_TIME_AVG):
                 fam = family(metric, "summary", m.description)
                 fam.lines.append(f"{metric}_sum{{{label}}} {total}")

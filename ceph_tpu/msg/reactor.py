@@ -27,6 +27,8 @@ import selectors
 import threading
 import time
 
+from ..common.tracer import charge_thread_cpu
+
 
 class Timer:
     """A cancellable :meth:`Reactor.call_later` handle."""
@@ -195,6 +197,7 @@ class Reactor:
                     except Exception:      # noqa: BLE001
                         pass
             self._run_ready()
+            charge_thread_cpu("reactor")
         self._drain_on_stop()
 
     def _run_ready(self) -> None:

@@ -34,7 +34,7 @@ import threading
 import time
 
 from ..common import instruments
-from ..common.tracer import default_tracer
+from ..common.tracer import charge_thread_cpu, default_tracer
 from ..osd.mclock import (CLIENT_OP, ClientInfo, DEFAULT_OP_CLASS_INFO,
                           MClockOpClassQueue)
 from .connection import AsyncConnection, riding_calls, stamp_calls
@@ -236,8 +236,10 @@ class Dispatcher:
                 # client's resend on the next connection collects them
                 pass
             # dispatcher completion boundary: fold this worker's pending
-            # span batch into the ring once per frame, not per span
+            # span batch into the ring once per frame, not per span, and
+            # charge the frame's CPU to the role
             default_tracer().flush()
+            charge_thread_cpu("dispatch")
 
 
 class AsyncServerTransport:

@@ -56,6 +56,8 @@ NOTIFY_TIMEOUT = 10.0
 # interned "rpc.<method>" span names: dispatch records one tracer event
 # per op, and building the name fresh each time is measurable at that rate
 _RPC_SPAN_NAMES: dict[str, str] = {}
+# the methods whose hold of the cluster lock reads the CPU clock too
+_CPU_HOLDS = frozenset(("put", "get"))
 
 
 # -- socket RPC messages (own registry: these never ride the PG bus) ---------
@@ -865,6 +867,11 @@ class ClusterServer:
                     # adopts it
                     with tr.activate(trace, track=track):
                         args = self._prepare_put(args)
+                # a put's and a get's hold also read the worker's CPU
+                # clock: wall - CPU of the hold is what the holder did
+                # not run, blocked in a call or waiting for the
+                # interpreter, while every other op waited for the lock
+                cpu = call.method in _CPU_HOLDS and instruments.enabled()
                 t_ask = t_got = time.perf_counter()
                 with self.lock:
                     t_got = time.perf_counter()
@@ -872,15 +879,19 @@ class ClusterServer:
                     bound = False
                     if trace is not None:
                         with tr.activate(trace, track="server"), \
-                                tr.span(sname, cat="rpc"):
+                                tr.span(sname, cat="rpc", cpu=cpu):
                             value = fn(ch, **args)
                     else:
                         # untraced op: no context/track to adopt and
                         # nothing to link — record through the
                         # allocation-light observe() path instead of the
                         # full Span protocol
+                        c_got = time.thread_time_ns() if cpu else None
                         value = fn(ch, **args)
-                        tr.observe(sname, t_got, cat="rpc")
+                        tr.observe(
+                            sname, t_got, cat="rpc",
+                            cpu_s=None if c_got is None else
+                            (time.thread_time_ns() - c_got) * 1e-9)
             finally:
                 if bound:
                     self._bound(-1)

@@ -386,6 +386,46 @@ def test_two_puts_encode_and_checksum_while_the_lock_is_held(served):
         assert served.r.get("p", f"held-{i}") == data[i]
 
 
+def test_a_holder_that_waits_for_the_interpreter_reads_off_cpu_time(served):
+    """ISSUE 37: the hold reads the worker's CPU clock beside the wall
+    clock.  Three spinning Python threads take the interpreter from the
+    worker that holds the cluster lock for a put: the hold's wall time
+    less its CPU time, ``span_cpu``'s ``rpc.put.offcpu_us``, says so."""
+    from ceph_tpu.common.tracer import span_cpu_perf_counters
+    tr = default_tracer()
+
+    def held():
+        h = tr.histograms().get("rpc.put", {"sum": 0.0, "count": 0})
+        dump = span_cpu_perf_counters().dump()
+        return (h["sum"] * 1e6, h["count"], dump.get("rpc.put.cpu_us", 0),
+                dump.get("rpc.put.offcpu_us", 0))
+    data = _data(2 * STRIPE, 37)
+    served.r.put("p", "calm", data)
+    before = held()
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            pass
+    spinners = [threading.Thread(target=spin) for _ in range(3)]
+    for t in spinners:
+        t.start()
+    try:
+        for i in range(3):
+            served.r.put("p", f"contended-{i}", data)
+    finally:
+        stop.set()
+        for t in spinners:
+            t.join(20.0)
+    wall, puts, cpu, off = (a - b for a, b in zip(held(), before))
+    assert puts == 3 and cpu > 0
+    assert off > 0
+    # the two are the holds' wall time, parted
+    assert cpu + off == pytest.approx(wall, abs=3 * 2)
+    for i in range(3):
+        assert served.r.get("p", f"contended-{i}") == data
+
+
 # -- (e) the engine from three threads --------------------------------------
 
 def test_encode_from_three_threads_is_bit_equal_in_batches_of_1_2_3():

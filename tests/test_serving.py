@@ -391,7 +391,7 @@ class TestClusterIntegration:
         for oid, data in objs.items():
             a.put(pa, oid, data)
             b.put(pb, oid, data)
-        assert eng.perf.get("ops_submitted") >= len(objs)
+        assert eng.perf.get("ops_completed") >= len(objs)
         for oid, data in objs.items():
             assert b.get(pb, oid, len(data)) == data, oid
             ga, gb = a.pg_group(pa, oid), b.pg_group(pb, oid)

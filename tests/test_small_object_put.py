@@ -8,6 +8,11 @@ the payload.  The stores count what the put cost them: one
 its roll-forward kicks), ``block_bytes`` as allocated, ``wal_bytes`` as
 the journal files grew, ``txn_ops``; a failed transaction counts
 nothing; the collection is in ``perf dump`` while its store lives.
+Since ISSUE 37 they also say where a commit's wall time went: four
+phases of one clock that tile the ``store.commit`` span, the thread's
+CPU clock over the same stretch, and the checkpoints' time —
+from one transaction in ``BlueStoreLite.TIMED_EVERY``, booked that many
+times (the CPU clock is dear where the chip is).
 CPU, tiny sizes: counts and correctness only.
 """
 import sys
@@ -35,8 +40,9 @@ PROFILE = {"plugin": "jax_rs", "k": str(K), "m": str(M),
            "technique": "cauchy", "device": "jax"}
 SIZES = {"one_stripe": WIDTH, "two_stripes": 2 * WIDTH,
          "three_stripes": 3 * WIDTH, "two_stripes_less_100": 2 * WIDTH - 100}
+PHASES = ("stage_us", "record_us", "block_io_us", "wal_io_us")
 COUNTERS = ("transactions", "txn_ops", "block_bytes", "wal_bytes",
-            "checkpoints")
+            "checkpoints") + PHASES + ("commit_cpu_us", "checkpoint_us")
 
 
 def _payload(n, seed):
@@ -147,7 +153,117 @@ def test_the_store_counters_rise_by_what_a_put_cost_the_stores(size, served):
     assert served.backends("write_bytes") - written == n
 
 
-def test_a_failed_transaction_counts_nothing(tmp_path):
+def _fifty_puts(served, tag, monkeypatch):
+    """What fifty puts added: each committed transaction's
+    ``store.commit`` span (us, in order), each timed transaction's
+    phases as its clock booked them (us, in order), the stores'
+    counters."""
+    from ceph_tpu.common.perf_counters import PerfCounters
+    from ceph_tpu.common.tracer import default_tracer
+    clock = PerfCounters._PhaseClock
+    timed, real = [], clock.commit
+
+    def spy(self, weight=1):
+        if self.on:
+            timed.append(sum(self.sums.values()) * 1e6)
+        real(self, weight)
+    monkeypatch.setattr(clock, "commit", spy)
+    tr = default_tracer()
+    done = tr.histograms().get("store.commit", {"count": 0})["count"]
+    before = served.stores()
+    data = _payload(2 * WIDTH, 37)
+    for i in range(50):
+        served.r.put("p", f"{tag}.{i}", data)
+    monkeypatch.setattr(clock, "commit", real)
+    rose = {key: served.stores()[key] - before[key] for key in COUNTERS}
+    n = tr.histograms()["store.commit"]["count"] - done
+    # this cluster's alone (the tracer is the process's: no other store
+    # commits meanwhile), serial under the cluster lock: by start time
+    spans = [e["dur"] for e in sorted(
+        (e for e in tr.dump()["traceEvents"] if e["name"] == "store.commit"),
+        key=lambda e: e["ts"])][-n:]
+    assert len(spans) == n == rose["transactions"] >= 50 * (K + M)
+    return spans, timed, rose
+
+
+def _less_the_stalls(us):
+    """The sum without its largest fiftieth: a busy machine takes the
+    core away inside a few commits, for milliseconds."""
+    kept = sorted(us)[:len(us) - max(1, len(us) // 50)]
+    return sum(kept) / len(kept) * len(us)
+
+
+def test_the_four_phases_tile_the_commit_spans_of_fifty_puts(served,
+                                                             monkeypatch):
+    monkeypatch.setattr(BlueStoreLite, "TIMED_EVERY", 1)
+    spans, timed, rose = _fifty_puts(served, "tiled", monkeypatch)
+    assert len(timed) == len(spans)
+    # what the clock booked is what the counters rose by (each phase
+    # rounds to a microsecond)
+    phases = sum(rose[p] for p in PHASES)
+    assert phases == pytest.approx(sum(timed), abs=2.0 * len(spans))
+    assert all(rose[p] > 0 for p in PHASES)
+    # consecutive marks of one clock, from the span's first statement
+    # to its last: a timed span is its phases and the two reads of the
+    # CPU clock around them (a microsecond or two each here, of commits
+    # of some fifty), which no phase holds.  A read is a system call,
+    # where a busy machine takes the core away: the median commit is
+    # held to it, and every commit to its phases lying inside its span
+    around = [span - inside for span, inside in zip(spans, timed)]
+    assert min(around) > -1.0
+    assert sorted(around)[len(around) // 2] <= 25.0
+    # the same stretch by the thread's CPU clock, read outside the wall
+    # clock's reads: a part of the phases, or where the thread ran
+    # throughout, all of them and the CPU of a read besides
+    assert 0 < rose["commit_cpu_us"] <= phases + 25.0 * len(spans)
+    # no checkpoint, no checkpoint time (a store's 512th record is far)
+    assert rose["checkpoints"] == rose["checkpoint_us"] == 0
+
+
+def test_one_transaction_in_many_is_timed_and_stands_for_them(served,
+                                                              monkeypatch):
+    every = BlueStoreLite.TIMED_EVERY
+    assert every == 17
+    spans, timed, rose = _fifty_puts(served, "sampled", monkeypatch)
+    # one in seventeen a store, and every counter of a timed transaction
+    # booked seventeen-fold (each rounds to a microsecond before it is
+    # multiplied)
+    assert len(spans) - every * (K + M) < every * len(timed) \
+        < len(spans) + every * (K + M)
+    phases = sum(rose[p] for p in PHASES)
+    assert phases % every == 0 and rose["commit_cpu_us"] % every == 0
+    assert phases == pytest.approx(every * sum(timed),
+                                   abs=2.0 * every * len(timed))
+    assert 0 < rose["commit_cpu_us"] <= phases + 25.0 * len(spans)
+    # an estimate of all the spans from some seventy of them.  The
+    # stride is odd, so a store's timed transactions are sub-writes and
+    # roll-forward kicks by turns (each put here is both, on each
+    # store): an even stride times one kind only and reads up to twice
+    # the spans
+    assert 0.6 * _less_the_stalls(spans) \
+        <= every * _less_the_stalls(timed) \
+        <= 1.6 * _less_the_stalls(spans)
+
+
+def test_the_512th_record_checkpoints_and_its_time_is_counted(tmp_path):
+    store = BlueStoreLite(tmp_path / "s")
+    obj = GObject("o", 0)
+    for i in range(511):
+        store.queue_transaction(Transaction().setattr(obj, "a", i))
+    counted = store.perf.dump()
+    assert counted["checkpoints"] == counted["checkpoint_us"] == 0
+    store.queue_transaction(Transaction().setattr(obj, "a", 511))
+    counted = store.perf.dump()
+    assert counted["checkpoints"] == 1 and counted["checkpoint_us"] > 0
+    assert counted["transactions"] == 512
+    # a transaction that writes no data still passes the block flush
+    # (30 of the 512 were timed)
+    assert counted["block_io_us"] > 0 and counted["block_bytes"] == 0
+    store.close()
+
+
+def test_a_failed_transaction_counts_nothing(tmp_path, monkeypatch):
+    monkeypatch.setattr(BlueStoreLite, "TIMED_EVERY", 1)
     store = BlueStoreLite(tmp_path / "s")
     obj = GObject("o", 0)
     store.queue_transaction(Transaction().write(obj, 0, b"x" * 5000)

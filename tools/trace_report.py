@@ -17,6 +17,10 @@ went — the number that ranks optimization targets, which total time
 (double-counting every parent) cannot.  p50/p99 columns give each span
 name's per-occurrence duration distribution — the serving-latency view
 (a `serving.op` row's p99 IS the op tail) that a mean-only table hides.
+Where events carry ``cpu_us`` (spans that read their thread's CPU clock:
+``trace_span(cpu=True)`` / ``observe(cpu_s=)``), the table adds the
+name's CPU time and the rest of those events' wall time — off the CPU:
+blocked in a call or waiting for the interpreter.
 """
 from __future__ import annotations
 
@@ -74,12 +78,13 @@ def event_weight(ev: dict) -> float:
 
 
 def self_times(events: list[dict]) -> dict[str, dict]:
-    """name -> {count, weight, total_us, self_us, durs_us, wdurs};
-    nesting resolved per (pid, tid) with a containment stack sweep over
-    ts-sorted complete events.  ``durs_us`` holds every occurrence's
-    total duration (the p50/p99 source); ``wdurs`` pairs each with its
-    sample weight and ``weight`` sums them (the de-biased op-count
-    estimate for head-sampled dumps)."""
+    """name -> {count, weight, total_us, self_us, durs_us, wdurs} and,
+    for a name whose events carry ``cpu_us``, {cpu_us, offcpu_us} over
+    those events; nesting resolved per (pid, tid) with a containment
+    stack sweep over ts-sorted complete events.  ``durs_us`` holds every
+    occurrence's total duration (the p50/p99 source); ``wdurs`` pairs
+    each with its sample weight and ``weight`` sums them (the de-biased
+    op-count estimate for head-sampled dumps)."""
     agg: dict[str, dict] = defaultdict(
         lambda: {"count": 0, "weight": 0.0, "total_us": 0.0,
                  "self_us": 0.0, "durs_us": [], "wdurs": []})
@@ -107,7 +112,17 @@ def self_times(events: list[dict]) -> dict[str, dict]:
             a["self_us"] += dur
             a["durs_us"].append(dur)
             a["wdurs"].append((dur, w))
+            cpu = ev.get("args", {}).get("cpu_us")
+            if cpu is not None:
+                a["cpu_us"] = a.get("cpu_us", 0.0) + float(cpu)
+                a["cpu_wall_us"] = a.get("cpu_wall_us", 0.0) + dur
             stack.append(ev)
+    for a in agg.values():
+        if "cpu_us" in a:
+            # wall - CPU over the name, not a span: a CPU clock that
+            # ticks (100 Hz on some hosts) reads one span a whole tick
+            # and the next ones nothing
+            a["offcpu_us"] = max(0.0, a.pop("cpu_wall_us") - a["cpu_us"])
     return dict(agg)
 
 
@@ -130,17 +145,24 @@ def render_table(agg: dict[str, dict], limit: int = 0) -> str:
         n = sum(a["count"] for _n, a in rows)
         lines.append(f"sampled trace: p50/p99 weighted by sample_weight "
                      f"(~{est} ops estimated from {n} recorded spans)")
+    with_cpu = any("cpu_us" in a for _n, a in rows)
     lines.append(f"{'span':<{width}}  {'count':>7}  {'total ms':>10}  "
                  f"{'self ms':>10}  {'avg ms':>9}  {'p50 ms':>9}  "
-                 f"{'p99 ms':>9}")
+                 f"{'p99 ms':>9}"
+                 + (f"  {'cpu ms':>10}  {'off-cpu ms':>10}"
+                    if with_cpu else ""))
     for name, a in rows:
         avg = a["total_us"] / a["count"] / 1e3 if a["count"] else 0.0
         pairs = a.get("wdurs") or [(d, 1.0) for d in a.get("durs_us", [])]
-        lines.append(
+        line = (
             f"{name:<{width}}  {a['count']:>7}  "
             f"{a['total_us'] / 1e3:>10.3f}  {a['self_us'] / 1e3:>10.3f}  "
             f"{avg:>9.3f}  {percentile_us_w(pairs, 50) / 1e3:>9.3f}  "
             f"{percentile_us_w(pairs, 99) / 1e3:>9.3f}")
+        if "cpu_us" in a:
+            line += (f"  {a['cpu_us'] / 1e3:>10.3f}"
+                     f"  {a['offcpu_us'] / 1e3:>10.3f}")
+        lines.append(line)
     return "\n".join(lines)
 
 
@@ -154,6 +176,9 @@ def render_json(agg: dict[str, dict], limit: int = 0) -> str:
     spans = []
     for name, a in rows:
         pairs = a.get("wdurs") or [(d, 1.0) for d in a.get("durs_us", [])]
+        cpu = {"cpu_ms": round(a["cpu_us"] / 1e3, 6),
+               "offcpu_ms": round(a["offcpu_us"] / 1e3, 6)} \
+            if "cpu_us" in a else {}
         spans.append({
             "name": name,
             "count": a["count"],
@@ -164,6 +189,7 @@ def render_json(agg: dict[str, dict], limit: int = 0) -> str:
             if a["count"] else 0.0,
             "p50_ms": round(percentile_us_w(pairs, 50) / 1e3, 6),
             "p99_ms": round(percentile_us_w(pairs, 99) / 1e3, 6),
+            **cpu,
         })
     return json.dumps({"spans": spans, "num_spans": len(spans),
                        "sampled": is_sampled(agg)})
@@ -203,6 +229,14 @@ def trace_tree(events: list[dict], trace_id: int,
             track = tracks.get(e.get("pid"), str(e.get("pid")))
             owner = e["args"].get("owner") or e["args"].get("op_class", "")
             extra = f" [{owner}]" if owner else ""
+            cpu = e["args"].get("cpu_us")
+            if cpu is not None:
+                extra += f"  cpu {cpu / 1e3:.3f} ms"
+                # (a clock that ticks may read one span more than it
+                # lasted: the name's row in the table has the sums)
+                if cpu <= e.get("dur", 0.0):
+                    extra += (f", off-cpu "
+                              f"{(e['dur'] - cpu) / 1e3:.3f} ms")
             lines.append(
                 f"{'  ' * depth}{e['name']:<{max(1, 40 - 2 * depth)}} "
                 f"{e.get('dur', 0.0) / 1e3:>9.3f} ms  @{track}{extra}")
