@@ -28,16 +28,29 @@ The durable store modeled on the reference's flagship ObjectStore
   set and removed, never the omap (a PG's ``_pgmeta_`` omap holds its
   whole log, and every sub-write touches it).  The allocator's free list is
   REBUILT from the blob table on open (self-healing, like the
-  reference's freelist-from-RocksDB startup).
+  reference's freelist-from-RocksDB startup);
+- **a commit callback**: ``queue_transaction(t, on_commit)`` stages on the
+  caller's thread and queues the I/O (blob writes, journal record, their
+  fsyncs where ``sync``); a group commit (:func:`commit_queued`, run by
+  whoever waits) writes the queued transactions of the stores it waits
+  for in one native call and calls each ``on_commit`` once its record is
+  in (BlueStore's
+  txc state machine, ``queue_transactions``'s ``on_commit`` context and
+  the kv sync thread's batching).  A store's transactions reach its files
+  in ``committed_seq`` order, and every other entry point waits for its
+  queue first.
 
 Implements the full MemStore/FileStore ObjectStore surface, so it can
 back OSD daemons via collections unchanged.
 """
 from __future__ import annotations
 
+import ctypes
+import errno
 import os
 import pickle
 import struct
+import time
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any
@@ -56,6 +69,111 @@ _RECORD = "omap-delta"
 _SNAP = "kv.snap"
 _WAL = "kv.log"
 _BLOCK = "block"
+
+
+_store_io = None
+
+
+def _native_io():
+    """The native library's batched transaction I/O, or None where it
+    cannot be built (the calls are then made one by one from Python)."""
+    global _store_io
+    if _store_io is None:
+        try:
+            from ..native import registry_lib
+            _store_io = registry_lib().ec_store_commit_batch
+        except Exception:                   # noqa: BLE001 — no toolchain
+            _store_io = False
+    return _store_io or None
+
+
+def _write_txns(txns: list) -> list:
+    """Transactions' I/O, in order: each its blobs' bytes (and the block
+    fsync), then the journal's frame and record (and its fsync) — data
+    precedes its metadata.  ``txns``: (store, blob writes, frame, record).
+    Returns (errno or 0, block ns, journal ns, CPU ns) for each; after a
+    failure a store's later transactions are not written (ECANCELED).
+    In one native call where the library is built, which lets the
+    interpreter go once and times each transaction with its own clocks;
+    else call by call, timed around the calls."""
+    io = _native_io()
+    if io is None:
+        out, failed = [], set()
+        for store, writes, frame, payload in txns:
+            if store in failed:
+                out.append((errno.ECANCELED, 0, 0, 0))
+                continue
+            c0, t0 = time.thread_time_ns(), time.perf_counter_ns()
+            t1 = t0
+            try:
+                fd = store._block.fileno()
+                for poff, stored in writes:
+                    os.pwrite(fd, stored, poff)
+                if store.sync:
+                    os.fsync(fd)
+                t1 = time.perf_counter_ns()
+                fd = store._wal.fileno()
+                os.writev(fd, (frame, payload))
+                if store.sync:
+                    os.fsync(fd)
+                rc = 0
+            except OSError as e:
+                rc = e.errno or errno.EIO
+                failed.add(store)
+            out.append((rc, t1 - t0, time.perf_counter_ns() - t1,
+                        time.thread_time_ns() - c0))
+        return out
+    n = len(txns)
+    blobs = [w for txn in txns for w in txn[1]]
+    m = len(blobs)
+    i64, i32 = ctypes.c_int64, ctypes.c_int
+    rcs, ns = (i32 * n)(), (i64 * (3 * n))()
+    io(n, (i32 * n)(*(t[0]._block.fileno() for t in txns)),
+       (i32 * n)(*(t[0]._wal.fileno() for t in txns)),
+       (i32 * n)(*(t[0].sync for t in txns)),
+       (i32 * n)(*(len(t[1]) for t in txns)),
+       (ctypes.c_char_p * m)(*(w[1] for w in blobs)),
+       (i64 * m)(*(len(w[1]) for w in blobs)),
+       (i64 * m)(*(w[0] for w in blobs)),
+       (ctypes.c_char_p * n)(*(t[2] for t in txns)),
+       (i64 * n)(*(len(t[2]) for t in txns)),
+       (ctypes.c_char_p * n)(*(t[3] for t in txns)),
+       (i64 * n)(*(len(t[3]) for t in txns)), rcs, ns)
+    return [(-rcs[i], ns[3 * i], ns[3 * i + 1], ns[3 * i + 2])
+            for i in range(n)]
+
+
+def commit_queued(stores) -> None:
+    """Write the queued transactions of ``stores`` (distinct
+    :class:`BlueStoreLite`), all in one batch, each store's in their
+    order, then call their ``on_commit`` in that order.  A transaction
+    that fails keeps its error on its store (raised by the store's next
+    :meth:`~BlueStoreLite.wait_committed`), and neither it nor the
+    store's later ones call back.  Run by whoever waits: the bus for the
+    stores its held replies wait for, a store for itself."""
+    items = [(s, item) for s in stores if s._failed is None
+             for item in s._queue]
+    try:
+        results = _write_txns([(s, item[1], item[2], item[3])
+                               for s, item in items]) if items else []
+        for (s, item), (rc, *ns) in zip(items, results):
+            if s._failed is not None:
+                continue
+            if rc:
+                s._failed = OSError(rc, f"{s.path}: transaction "
+                                        f"{item[0]}: {os.strerror(rc)}")
+                continue
+            if item[5]:
+                # a timed transaction: its I/O phases by the call's own
+                # clocks, weighted as its caller's phases were
+                block_ns, wal_ns, cpu_ns = ns
+                s._clk.commit_booked(
+                    {"block_io_us": block_ns * 1e-9,
+                     "wal_io_us": wal_ns * 1e-9}, cpu_ns, s.TIMED_EVERY)
+            item[4]()
+    finally:
+        for s in stores:
+            s._queue = []
 
 
 class ChecksumError(IOError):
@@ -214,6 +332,10 @@ class BlueStoreLite:
         self.perf = (
             PerfCountersBuilder(f"bluestore.{name or self.path.name}")
             .add_u64_counter("transactions", "transactions committed")
+            .add_u64_counter("commits_queued",
+                             "of them, those whose I/O was queued for a "
+                             "group commit, acked by their on_commit "
+                             "callback")
             .add_u64_counter("txn_ops", "ops in them")
             .add_u64_counter("block_bytes",
                              "bytes written to the block file, as "
@@ -229,7 +351,9 @@ class BlueStoreLite:
             # tile the store.commit span (BlueStore.cc's state_*_lat and
             # kv_*_lat, as sums), and the thread's CPU clock over the
             # same stretch; from one transaction in TIMED_EVERY, each
-            # booked that many times
+            # booked that many times.  The two I/O phases are timed by
+            # the native call that makes them; a queued commit's run in
+            # the group commit, after the span ends
             .add_u64_counter("stage_us",
                              "staging: the ops applied to the staged "
                              "onodes (less their block writes), blob "
@@ -245,10 +369,13 @@ class BlueStoreLite:
                              "journal calls: the frame and record "
                              "written, the flush (and fsync where sync)")
             .add_u64_counter("commit_cpu_us",
-                             "the committing thread's CPU clock over "
-                             "the four phases; their sum less this is "
-                             "what the thread did not run: blocked in "
-                             "a call or waiting for the interpreter")
+                             "the committing threads' CPU clocks over "
+                             "the four phases (the caller's over the "
+                             "first two and the group commit's over "
+                             "the I/O of a queued commit); their sum "
+                             "less this is what the threads did not "
+                             "run: blocked in a call or waiting for "
+                             "the interpreter")
             .add_u64_counter("checkpoint_us",
                              "wall time of the metadata snapshots "
                              "(beside checkpoints)")
@@ -274,8 +401,17 @@ class BlueStoreLite:
         self._timed_at = crc32c(0, self.perf.name.encode())
         self._clk = self.perf.phase_clock("commit_cpu_us")
         self._load()
-        self._block = open(self.path / _BLOCK, "r+b")
+        # unbuffered: every write reaches the file at once (the commits
+        # write by descriptor, at their offsets)
+        self._block = open(self.path / _BLOCK, "r+b", buffering=0)
         self._wal = open(self.path / _WAL, "ab")
+        # staged transactions whose I/O is still to do, in order: (seq,
+        # blob writes, frame, record, on_commit, timed); the first I/O
+        # error of a group commit, kept and raised on the caller's
+        # thread (the store then refuses work: a later record must not
+        # follow one that never landed)
+        self._queue: list = []
+        self._failed: OSError | None = None
         self._cct = cct
         if cct is not None:
             cct.perf.add(self.perf)
@@ -347,9 +483,11 @@ class BlueStoreLite:
 
     def checkpoint(self) -> None:
         """Metadata-only snapshot (onodes + blob table): cost scales with
-        object count, never data volume — the block file IS the data."""
+        object count, never data volume — the block file IS the data.
+        Follows every queued record: the snapshot covers what they say
+        and the journal restarts after them."""
+        self.wait_committed()
         with trace_span("store.checkpoint") as span:
-            self._block.flush()
             if self.sync:
                 os.fsync(self._block.fileno())
             tmp = self.path / (_SNAP + ".tmp")
@@ -368,20 +506,41 @@ class BlueStoreLite:
         self.perf.inc("checkpoint_us", int(span.dur * 1e6 + 0.5))
 
     def close(self, checkpoint: bool = True) -> None:
+        """Write the queued commits and checkpoint, then close the files;
+        a kept I/O error is raised once they are closed."""
         if self._cct is not None:
             self._cct.perf.remove(self.perf.name)
-        if checkpoint:
-            self.checkpoint()
-        self._wal.close()
-        self._block.close()
+        try:
+            self.wait_committed()
+            if checkpoint:
+                self.checkpoint()
+        finally:
+            self._wal.close()
+            self._block.close()
+
+    @property
+    def committer(self) -> "BlueStoreLite":
+        """The store whose queue holds this one's commits: itself (a
+        collection or a fault wrapper answers with the store behind)."""
+        return self
+
+    def wait_committed(self) -> None:
+        """Return once every queued transaction is written; raise the I/O
+        error the store kept, if any."""
+        if self._queue:
+            commit_queued([self])
+        if self._failed is not None:
+            raise self._failed
 
     # -- blob IO ------------------------------------------------------------
 
-    def _write_blob(self, data: bytes, new_blobs: dict[int, Blob]) -> int:
+    def _write_blob(self, data: bytes, new_blobs: dict[int, Blob],
+                    writes: list) -> int:
         """Store ``data`` as a fresh blob (maybe compressed); returns the
-        blob id.  The bytes hit the block file NOW, before the metadata
-        commits — old metadata never references them, so a crash in
-        between leaks nothing and tears nothing (COW)."""
+        blob id.  The bytes join ``writes``, which reach the block file
+        before the record that names them — old metadata never references
+        them, so a crash in between leaks nothing and tears nothing
+        (COW)."""
         raw_len = len(data)
         comp = None
         stored = data
@@ -394,10 +553,7 @@ class BlueStoreLite:
                 stored = candidate
                 comp = self.compression
         poff, alloc = self.alloc.alloc(max(1, len(stored)))
-        self._clk.mark("stage_us")
-        self._block.seek(poff)
-        self._block.write(stored)
-        self._clk.mark("block_io_us")
+        writes.append((poff, stored))
         bid = self.next_blob
         self.next_blob += 1
         blob = Blob(poff=poff, plen=len(stored), alloc=alloc,
@@ -409,9 +565,8 @@ class BlueStoreLite:
 
     def _read_blob(self, bid: int) -> bytes:
         b = self.blobs[bid]
-        self._block.flush()
-        self._block.seek(b.poff)
-        stored = self._block.read(b.plen)
+        self.wait_committed()           # its bytes may still be queued
+        stored = os.pread(self._block.fileno(), b.plen, b.poff)
         if crc32c(0xFFFFFFFF, stored) != b.csum:
             raise ChecksumError(
                 f"blob {bid} at {b.poff}+{b.plen}: stored crc mismatch "
@@ -465,14 +620,29 @@ class BlueStoreLite:
 
     # -- transactions --------------------------------------------------------
 
-    def queue_transaction(self, t: Transaction) -> int:
+    def queue_transaction(self, t: Transaction, on_commit=None) -> int:
         """Apply atomically; journal the metadata delta; return the seq.
 
         Staging mirrors MemStore, less the omap: the touched onodes'
         small fields are copied, their omap changes collect as an overlay
         (:class:`_StagedOnode`) that is applied to the live omap in place
         on success and is all the record says of the omap; blob refcount
-        changes are tracked and only applied on success."""
+        changes are tracked and only applied on success.  Staging, the
+        onode table's update and the record's pickle run here, so a later
+        transaction sees this one's state at once.
+
+        Without ``on_commit`` the I/O runs here too, after every queued
+        transaction's, and the call returns durable.  With it, the I/O
+        is queued for the next group commit (:func:`commit_queued`, run
+        by whoever waits: the bus that holds the sub-write's reply, a
+        read, a checkpoint), which calls ``on_commit()`` once the record
+        is written (and fsynced where ``sync``); an I/O error there is
+        kept, ``on_commit`` is never called, and the next
+        :meth:`wait_committed` raises it."""
+        if self._failed is not None:
+            raise self._failed
+        if on_commit is None:
+            self.wait_committed()
         touched: set[GObject] = set()
         for op in t.ops:
             touched.add(op[1])
@@ -483,20 +653,21 @@ class BlueStoreLite:
             o = self.onodes.get(obj)
             staged[obj] = None if o is None else _StagedOnode.of(o, o.omap)
         new_blobs: dict[int, Blob] = {}
+        writes: list[tuple[int, bytes]] = []    # (block offset, bytes)
         deref: list[int] = []       # blob ids losing one reference
         addref: list[int] = []      # blob ids gaining one (clone/split)
-        # what makes the transaction durable: the block writes (in
-        # _apply), the block flush + fsync, the WAL append + fsync
         clk = self._clk
         with trace_span("store.commit"):
             clk.start(not (self.committed_seq + self._timed_at)
                       % self.TIMED_EVERY)
+            timed = clk.on
             try:
                 for op in t.ops:
-                    self._apply(staged, op, new_blobs, deref, addref)
+                    self._apply(staged, op, new_blobs, deref, addref,
+                                writes)
             except Exception:
-                # all-or-nothing: orphan the data already written for this
-                # transaction (nothing references it) and free its space
+                # all-or-nothing: nothing was written; free the space the
+                # transaction's blobs took (nothing references it)
                 for bid, b in new_blobs.items():
                     self.blobs.pop(bid, None)
                     self.alloc.free(b.poff, b.alloc)
@@ -519,21 +690,26 @@ class BlueStoreLite:
                 (_RECORD, self.committed_seq, onode_delta, blob_delta,
                  freed, self.next_blob),
                 protocol=pickle.HIGHEST_PROTOCOL)
-            frame = _FRAME.pack(len(payload), crc32c(0xFFFFFFFF, payload))
-            clk.mark("record_us")
-            self._block.flush()          # data precedes its metadata
-            if self.sync:
-                os.fsync(self._block.fileno())
-            clk.mark("block_io_us")
-            self._wal.write(frame)
-            self._wal.write(payload)
-            self._wal.flush()
-            if self.sync:
-                os.fsync(self._wal.fileno())
-            clk.stop("wal_io_us")
+            frame = _FRAME.pack(len(payload),
+                                crc32c(0xFFFFFFFF, payload))
+            if on_commit is None:
+                clk.mark("record_us")
+                [(rc, block_ns, wal_ns, _cpu)] = _write_txns(
+                    [(self, writes, frame, payload)])
+                if rc:
+                    raise OSError(rc, f"{self.path}: {os.strerror(rc)}")
+                clk.book("block_io_us", block_ns * 1e-9)
+                clk.book("wal_io_us", wal_ns * 1e-9)
+                clk.stop(None)
+            else:
+                clk.stop("record_us")
+                self._queue.append((self.committed_seq, writes, frame,
+                                    payload, on_commit, timed))
         clk.commit(self.TIMED_EVERY)
         perf = self.perf
         perf.inc("transactions")
+        if on_commit is not None:
+            perf.inc("commits_queued")
         perf.inc("txn_ops", len(t.ops))
         perf.inc("block_bytes", sum(b.alloc for b in new_blobs.values()))
         perf.inc("wal_bytes", _FRAME.size + len(payload))
@@ -542,7 +718,7 @@ class BlueStoreLite:
             self.checkpoint()
         return self.committed_seq
 
-    def _apply(self, staged, op, new_blobs, deref, addref) -> None:
+    def _apply(self, staged, op, new_blobs, deref, addref, writes) -> None:
         kind = op[0]
         obj = op[1]
 
@@ -556,7 +732,7 @@ class BlueStoreLite:
             o = node()
             if data:
                 self._punch(o, offset, len(data), deref, addref)
-                bid = self._write_blob(bytes(data), new_blobs)
+                bid = self._write_blob(bytes(data), new_blobs, writes)
                 o.extents.append(Extent(offset, len(data), bid, 0))
                 o.extents.sort(key=lambda e: e.loff)
             o.size = max(o.size, offset + len(data))

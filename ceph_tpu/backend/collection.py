@@ -86,12 +86,18 @@ class Collection:
 
     # -- writes -------------------------------------------------------------
 
-    def queue_transaction(self, t: Transaction) -> int:
+    def queue_transaction(self, t: Transaction, on_commit=None) -> int:
         nt = Transaction()
         nt.ops = [tuple(self._in(x) if isinstance(x, GObject) else x
                         for x in op)
                   for op in t.ops]
-        return self.base.queue_transaction(nt)
+        return self.base.queue_transaction(nt, on_commit)
+
+    @property
+    def committer(self):
+        """The store whose queue holds this view's commits (see
+        BlueStoreLite)."""
+        return self.base.committer
 
     # -- reads --------------------------------------------------------------
 

@@ -42,17 +42,29 @@ def _make_crc_tables(n_tables: int = 16) -> list[list[int]]:
 _CRC_TABLES = _make_crc_tables()
 
 _native_crc = None
+# up to this many bytes the native crc keeps the interpreter (some 2 us at
+# 8 KiB): letting it go means waiting to win it back from the threads
+# that took it, for longer than the crc takes (the store's blob and record
+# crcs inside the cluster lock's hold); a 512 KiB shard's (75 us) lets it
+# go, for the threads that wait for it
+SMALL_CRC_MAX = 64 * 1024
 
 
 def _load_native_crc():
-    """SSE4.2 CRC32C from the native lib (gf8_simd.cc ec_crc32c); the pure
+    """SSE4.2 CRC32C from the native lib (gf8_simd.cc ec_crc32c) as two
+    calls of one symbol: (lets the interpreter go, keeps it).  The pure
     Python path below stays as the oracle and no-toolchain fallback."""
     global _native_crc
     if _native_crc is not None:
         return _native_crc or None
     try:
+        import ctypes
         from ..native import registry_lib
-        _native_crc = registry_lib().ec_crc32c
+        lib = registry_lib()
+        held = ctypes.PYFUNCTYPE(ctypes.c_uint32, ctypes.c_uint32,
+                                 ctypes.c_void_p, ctypes.c_size_t)(
+            ("ec_crc32c", lib))
+        _native_crc = (lib.ec_crc32c, held)
     except Exception:
         _native_crc = False
     return _native_crc or None
@@ -62,20 +74,23 @@ def crc32c(seed: int, data: bytes | np.ndarray) -> int:
     """ceph_crc32c semantics: raw reflected CRC-32C update, no final xor —
     the caller chains seeds (standard crc32c(x) = crc32c(0xffffffff, x) ^ 0xffffffff).
 
-    Dispatches to the native SSE4.2/table kernel when built; pure-Python
-    slice-by-16 otherwise (one iteration consumes 16 bytes).
+    Dispatches to the native SSE4.2/table kernel when built (keeping the
+    interpreter up to ``SMALL_CRC_MAX`` bytes); pure-Python slice-by-16
+    otherwise (one iteration consumes 16 bytes).
     """
-    fn = _load_native_crc()
-    if fn is not None and isinstance(data, np.ndarray):
+    fns = _load_native_crc()
+    if fns is not None and isinstance(data, np.ndarray):
         # zero-copy for contiguous arrays: the kernel needs pointer+length
         arr = np.ascontiguousarray(data).reshape(-1)
-        return fn(seed & 0xFFFFFFFF, arr.ctypes.data, arr.nbytes)
+        return fns[arr.nbytes <= SMALL_CRC_MAX](
+            seed & 0xFFFFFFFF, arr.ctypes.data, arr.nbytes)
     if isinstance(data, np.ndarray):
         buf = np.ascontiguousarray(data.ravel()).tobytes()
     else:
         buf = bytes(data)
-    if fn is not None:
-        return fn(seed & 0xFFFFFFFF, buf, len(buf))
+    if fns is not None:
+        return fns[len(buf) <= SMALL_CRC_MAX](
+            seed & 0xFFFFFFFF, buf, len(buf))
     crc = seed & 0xFFFFFFFF
     t = _CRC_TABLES
     (t15, t14, t13, t12, t11, t10, t9, t8,

@@ -128,9 +128,10 @@ class FileStore:
     def committed_seq(self) -> int:
         return self._mem.committed_seq
 
-    def queue_transaction(self, t: Transaction) -> int:
+    def queue_transaction(self, t: Transaction, on_commit=None) -> int:
         # apply first (all-or-nothing staging) so only transactions that
         # succeed reach the log; then journal before acking the caller
+        # (``on_commit`` too: it is called here, once the record is in)
         with trace_span("store.commit"):
             seq = self._mem.queue_transaction(t)
             self._append_wal(pickle.dumps(
@@ -138,6 +139,8 @@ class FileStore:
         self._wal_records += 1
         if self._wal_records >= self.checkpoint_every:
             self.checkpoint()
+        if on_commit is not None:
+            on_commit()
         return seq
 
     def read(self, obj: GObject, offset: int = 0,

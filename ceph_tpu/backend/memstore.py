@@ -105,8 +105,10 @@ class MemStore:
 
     # -- transactions ------------------------------------------------------
 
-    def queue_transaction(self, t: Transaction) -> int:
+    def queue_transaction(self, t: Transaction, on_commit=None) -> int:
         """Apply atomically; returns the commit sequence number.
+        ``on_commit`` is called before the return: a commit here is done
+        when it is applied.
 
         Atomicity by staging copies of only the objects the transaction
         names (not the whole store): on any op error nothing merges back."""
@@ -128,6 +130,8 @@ class MemStore:
             else:
                 self.objects.pop(obj, None)
         self.committed_seq += 1
+        if on_commit is not None:
+            on_commit()
         return self.committed_seq
 
     def _apply(self, objs: dict[GObject, _Object], op: tuple) -> None:

@@ -6,7 +6,9 @@ Analog of the reference's ``ECSubWrite``/``ECSubRead``(+replies) payloads
 them between shards (reference: src/osd/ECBackend.cc:2036-2070).  The bus is
 deterministic: sends enqueue, ``deliver_all`` drains — tests step it to
 exercise pipeline orderings; a down shard silently drops its queue the way a
-dead OSD would.
+dead OSD would.  A reply that waits for a store's commit (``send_on_commit``)
+is sent by the bus once the store says the commit is durable, in the order
+the commits were queued.
 """
 from __future__ import annotations
 
@@ -14,9 +16,10 @@ from collections import deque
 from dataclasses import dataclass, field
 
 from .extent import ExtentSet
+from .bluestore import commit_queued
 from .memstore import Transaction
 from ..common import wire_accounting
-from ..common.tracer import default_tracer
+from ..common.tracer import default_tracer, trace_span
 # the bus fault plane now lives in the unified failure/ schema (one
 # schema, one seed across bus/transport/store/device); re-exported here
 # so every existing `from ceph_tpu.backend.messages import FaultConfig`
@@ -403,6 +406,24 @@ class OSDEndpoint:
             handler.handle_message(msg.msg)
 
 
+class _PendingAck:
+    """A reply held until its commit is durable.  ``committed`` is the
+    store's ``on_commit``: it only sets the flag (it runs wherever the
+    store writes the commit); the bus sends the reply itself, in the
+    order the replies were held."""
+
+    __slots__ = ("store", "to_shard", "msg", "durable")
+
+    def __init__(self, store, to_shard: int, msg):
+        self.store = store
+        self.to_shard = to_shard
+        self.msg = msg
+        self.durable = store is None
+
+    def committed(self) -> None:
+        self.durable = True
+
+
 class PGChannel:
     """A PG's view of the shared cluster bus.
 
@@ -434,6 +455,9 @@ class PGChannel:
                 ep.pg_channels.pop(self.pgid, None)
 
     def send(self, to_shard: int, msg) -> None:
+        self.bus.send(to_shard, self._envelope(msg))
+
+    def _envelope(self, msg) -> PGEnvelope:
         # trace propagation across the daemon boundary: stamp the
         # sender's active context onto the envelope AND onto payloads
         # that declare a trace field (ECSubRead/ECSubWrite — the wire
@@ -441,8 +465,15 @@ class PGChannel:
         ctx = default_tracer().current_ctx()
         if ctx is not None and getattr(msg, "trace", True) is None:
             msg.trace = ctx
-        self.bus.send(to_shard, PGEnvelope(
-            self.pgid, msg, getattr(msg, "from_shard", None), trace=ctx))
+        return PGEnvelope(self.pgid, msg, getattr(msg, "from_shard", None),
+                          trace=ctx)
+
+    def send_on_commit(self, store, t, to_shard: int, msg) -> int:
+        return self.bus.send_on_commit(store, t, to_shard,
+                                       self._envelope(msg))
+
+    def send_after_commits(self, to_shard: int, msg) -> None:
+        self.bus.send_after_commits(to_shard, self._envelope(msg))
 
     # -- delegation to the shared bus ---------------------------------------
 
@@ -536,6 +567,8 @@ class MessageBus:
         # a FaultInjector.record, so bus drops/dups/reorders land in the
         # same seeded campaign log as every other plane's events
         self.fault_log = None
+        # replies held for their store's commit, in the order queued
+        self._acks: deque[_PendingAck] = deque()
 
     def inject_faults(self, cfg) -> None:
         """Enable (or, with None, disable) fault injection.  Accepts the
@@ -607,6 +640,47 @@ class MessageBus:
             acct.note_queue_depth(len(q) + 1)
         q.append(msg)
 
+    # -- replies that follow a commit ----------------------------------------
+
+    def send_on_commit(self, store, t, to_shard: int, msg) -> int:
+        """Commit ``t`` on ``store`` and send ``msg`` once it is durable:
+        at once where the store commits inline (MemStore, FileStore) and
+        no reply is held; else when the bus settles (``deliver_*``).
+        Returns the store's seq."""
+        ack = _PendingAck(store, to_shard, msg)
+        seq = store.queue_transaction(t, on_commit=ack.committed)
+        if ack.durable and not self._acks:
+            self.send(to_shard, msg)
+        else:
+            self._acks.append(ack)
+        return seq
+
+    def send_after_commits(self, to_shard: int, msg) -> None:
+        """Send ``msg`` behind every reply still held for its commit (a
+        re-ack never precedes the record it acknowledges)."""
+        if self._acks:
+            self._acks.append(_PendingAck(None, to_shard, msg))
+        else:
+            self.send(to_shard, msg)
+
+    def _settle(self) -> None:
+        """Write the commits the held replies wait for, in one group
+        commit of their stores, and send the replies in the order they
+        were held.  Where a commit failed, the replies before it go out,
+        its own never does, and its store's error is raised; the rest
+        stay held."""
+        acks = self._acks
+        with trace_span("bus.commit_wait", cat="bus"):
+            commit_queued(list(dict.fromkeys(
+                ack.store.committer for ack in acks if not ack.durable)))
+        while acks and acks[0].durable:
+            ack = acks.popleft()
+            self.send(ack.to_shard, ack.msg)
+        if acks:
+            acks.popleft().store.committer.wait_committed()
+            raise RuntimeError("a held reply's commit neither landed nor "
+                               "failed")
+
     def _pick(self, q: deque):
         """Next message to deliver.  Under reorder injection: the earliest
         message of a uniformly random sender (per-sender FIFO preserved,
@@ -628,6 +702,15 @@ class MessageBus:
         return q.popleft()        # unreachable
 
     def deliver_one(self, shard: int) -> bool:
+        """Deliver ``shard``'s next message, and send the replies its
+        commits hold before returning."""
+        if not self._deliver(shard):
+            return False
+        if self._acks:
+            self._settle()
+        return True
+
+    def _deliver(self, shard: int) -> bool:
         q = self.queues.get(shard)
         if not q or shard in self.down:
             return False
@@ -652,16 +735,22 @@ class MessageBus:
         return True
 
     def deliver_all(self, max_rounds: int = 10000) -> int:
-        """Drain every queue to quiescence; returns messages delivered."""
+        """Drain every queue to quiescence; returns messages delivered.
+        Commits queued by the deliveries wait for the bus: once no
+        message is left, their stores write them (one group commit) and
+        the replies they hold are sent (``_settle``), and delivery goes
+        on; it returns with none held."""
         n = 0
         for _ in range(max_rounds):
             for hook in self.pre_deliver_hooks:
                 hook()
             progressed = False
             for shard in list(self.queues):
-                while self.deliver_one(shard):
+                while self._deliver(shard):
                     progressed = True
                     n += 1
             if not progressed:
-                return n
+                if not self._acks:
+                    return n
+                self._settle()
         raise RuntimeError("message storm: bus did not quiesce")

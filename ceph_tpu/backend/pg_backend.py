@@ -216,10 +216,12 @@ class OSDShard:
         if isinstance(msg, ECSubWrite):
             if msg.log_entries and msg.at_version <= self.pg_log.head:
                 # duplicate delivery of an already-applied write: re-ack
-                # without re-applying (reqid dedup in the reference)
-                self.bus.send(msg.from_shard,
-                              ECSubWriteReply(self.shard, msg.tid,
-                                              gen=msg.gen))
+                # without re-applying (reqid dedup in the reference),
+                # behind the first delivery's ack if its commit is still
+                # queued
+                self.bus.send_after_commits(
+                    msg.from_shard,
+                    ECSubWriteReply(self.shard, msg.tid, gen=msg.gen))
                 return
             t = msg.t
             if msg.log_entries:
@@ -249,9 +251,12 @@ class OSDShard:
                 self._roll_forward(msg.trim_to, txn=t)
             if msg.log_entries or msg.trim_to:
                 self._persist_vi(t)
-            self.store.queue_transaction(t)
-            self.bus.send(msg.from_shard,
-                          ECSubWriteReply(self.shard, msg.tid, gen=msg.gen))
+            # the ack leaves from the store's commit callback: the bus
+            # sends it once the record is durable (MOSDECSubOpWriteReply
+            # from BlueStore's on_commit context)
+            self.bus.send_on_commit(
+                self.store, t, msg.from_shard,
+                ECSubWriteReply(self.shard, msg.tid, gen=msg.gen))
         elif isinstance(msg, RollForward):
             self._roll_forward(msg.to)
         elif isinstance(msg, Rollback):

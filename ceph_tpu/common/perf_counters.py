@@ -220,11 +220,32 @@ class PerfCounters:
                 self.sums[key] = self.sums.get(key, 0.0) + now - self.t
                 self.t = now
 
-        def stop(self, key: str) -> None:
-            """The last ``mark``."""
+        def book(self, key: str, seconds: float) -> None:
+            """Book a stretch that the call which made it timed itself
+            (a native call's own clock) under ``key``; the wall clock's
+            next mark is not moved."""
             if self.on:
-                self.mark(key)
+                self.sums[key] = self.sums.get(key, 0.0) + seconds
+
+        def stop(self, key: str | None) -> None:
+            """The last ``mark`` (none where ``key`` is None: the last
+            stretches were booked)."""
+            if self.on:
+                if key is not None:
+                    self.mark(key)
                 self.cpu_ns += time.thread_time_ns()
+
+        def commit_booked(self, booked: dict[str, float], cpu_ns: int,
+                          weight: int = 1) -> None:
+            """A transaction's phases that a call timed with its own
+            clocks, away from this clock's start and stop (a native
+            call's wall seconds by phase and CPU nanoseconds), onto the
+            adders as ``commit`` puts them; this clock's state is left
+            alone."""
+            cell = self.pc._cell
+            for k, s in booked.items():
+                cell(k)[0] += int(s * 1e6 + 0.5) * weight
+            cell(self.cpu_key)[0] += cpu_ns // 1000 * weight
 
         def commit(self, weight: int = 1) -> None:
             """After ``stop``: the sums onto the adders, ``weight``

@@ -46,7 +46,7 @@ class FaultyStore:
             time.sleep(f.slow_read_ms / 1000.0)
         return self._store.read(obj, offset, length)
 
-    def queue_transaction(self, t):
+    def queue_transaction(self, t, on_commit=None):
         f = self._inj.plan.store
         if self._inj.roll("store", "eio_write", f.eio_write_prob,
                           target=self._target):
@@ -63,7 +63,7 @@ class FaultyStore:
                         f"{len(t.ops)} ops applied)")
             e.errno = errno.EIO
             raise e
-        return self._store.queue_transaction(t)
+        return self._store.queue_transaction(t, on_commit)
 
     # -- delegation --------------------------------------------------------
 

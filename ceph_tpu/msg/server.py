@@ -29,6 +29,7 @@ frame + EOF.
 """
 from __future__ import annotations
 
+import select
 import socket
 import threading
 import time
@@ -305,6 +306,21 @@ class AsyncServerTransport:
     def connections(self) -> int:
         with self._conns_lock:
             return len(self._conns)
+
+    def receiving(self) -> bool:
+        """Is a call on its way in: part of a frame that the reactor is
+        still reading or verifying, or bytes in a connection's socket
+        that it has not read yet?"""
+        with self._conns_lock:
+            conns = list(self._conns)
+        if any(c.parser.pending() for c in conns):
+            return True
+        ready = select.poll()
+        for c in conns:
+            fd = c.sock.fileno()
+            if fd >= 0:
+                ready.register(fd, select.POLLIN)
+        return bool(ready.poll(0))
 
     # -- accept + handshake state machine (reactor thread) -------------------
 

@@ -55,6 +55,8 @@ def registry_lib() -> C.CDLL:
     lib.ec_apply_matrix.restype = C.c_int
     lib.ec_apply_matrix.argtypes = [
         C.c_void_p, C.c_int, C.c_int, C.c_void_p, C.c_void_p, C.c_size_t]
+    lib.ec_store_commit_batch.restype = C.c_int
+    lib.ec_store_commit_batch.argtypes = [C.c_int] + [C.c_void_p] * 13
     with _build_lock:
         _registry_lib = lib
     return _registry_lib

@@ -782,11 +782,12 @@ class ClusterServer:
 
     def _others_waiting(self) -> bool:
         """Asked by the op that holds the lock: does another call wait
-        behind it, at the lock or in the dispatch queue?"""
+        behind it, at the lock, in the dispatch queue or on its way in
+        (a frame the reactor is still reading)?"""
         if self._bound_for_lock:
             return True
         t = self._transport
-        return t is not None and t.dispatcher.depth > 0
+        return t is not None and (t.dispatcher.depth > 0 or t.receiving())
 
     def _bound(self, n: int) -> None:
         with self._bound_lock:
@@ -808,6 +809,11 @@ class ClusterServer:
 
     def _dispatch_call(self, ch: Channel, call: RpcCall) -> RpcResult:
         t0 = time.perf_counter()
+        # on its way to the lock from the moment a worker took it off the
+        # dispatch queue, so that _others_waiting never misses it between
+        # the two
+        self._bound(1)
+        bound = True
         if instruments.enabled():
             # copy-ledger denominator: request payload bytes reaching
             # their consumer (the handler) — pairs with the client-side
@@ -830,6 +836,9 @@ class ClusterServer:
                     running = self._rpc_inflight.get(key)
                     if running is None:
                         self._rpc_inflight[key] = threading.Event()
+            if hit is not None or running is not None:
+                self._bound(-1)
+                bound = False
             if hit is not None:
                 self.rpc_dedup_hits += 1
                 return hit
@@ -857,8 +866,6 @@ class ClusterServer:
                 sname = _RPC_SPAN_NAMES[call.method] = "rpc." + call.method
             track = "server" if trace is not None else None
             args = call.args
-            self._bound(1)
-            bound = True
             t_ask = t_got = t0
             try:
                 if call.method == "put":
@@ -895,6 +902,7 @@ class ClusterServer:
             finally:
                 if bound:
                     self._bound(-1)
+                    bound = False
                 # the wait for the one cluster lock, recorded after the
                 # lock is released so it adds nothing to the hold; a
                 # put's prepare (worker dequeue -> lock asked for) comes
@@ -914,6 +922,8 @@ class ClusterServer:
                                getattr(e, "errno", 0) or 0,
                                trace=getattr(call, "trace", None)))
         finally:
+            if bound:                       # refused before the lock
+                self._bound(-1)
             # RPC latency lands in the wire histogram whether the call
             # succeeded or not — a failing method is still served time
             self.wire.observe_rpc(call.method,

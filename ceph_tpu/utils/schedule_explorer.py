@@ -105,7 +105,13 @@ class ScheduledBus(MessageBus):
         while n < max_steps:
             opts = self._options()
             if not opts:
-                return n
+                if not self._acks:
+                    return n
+                # replies held for their stores' commits go out once
+                # nothing else can be delivered, as MessageBus.deliver_all
+                # sends them
+                self._settle()
+                continue
             pick = self.controller.choose(len(opts))
             shard, sender = opts[pick]
             self._deliver_from(shard, sender)

@@ -32,12 +32,12 @@ def _payload(seed):
         0, 256, STRIPE, dtype=np.uint8).tobytes()
 
 
-def _mk_backend(bus):
+def _mk_backend(bus, stores=(None, None, None)):
     from ceph_tpu.backend.pg_backend import OSDShard
     backend = ECBackend(_codec(), StripeInfo(K, CHUNK), bus,
-                        acting=[0, 1, 2], whoami=0)
+                        acting=[0, 1, 2], whoami=0, store=stores[0])
     for s in (1, 2):
-        OSDShard(s, bus)
+        OSDShard(s, bus, store=stores[s])
     return backend
 
 
@@ -52,12 +52,12 @@ def _read(backend, bus, oid):
     return bytes(out["result"][oid][0][2])
 
 
-def scenario_concurrent_writes(bus):
+def scenario_concurrent_writes(bus, stores=(None, None, None)):
     """Two in-flight writes to one object + a concurrent write to
     another: in EVERY delivery order, acked writes are durable, the
     pipeline commits them in submission order, and all shards scrub
     clean."""
-    backend = _mk_backend(bus)
+    backend = _mk_backend(bus, stores)
     a, b, c = _payload(1), _payload(2), _payload(3)
     commits = []
     backend.submit_transaction(PGTransaction().write("obj", 0, a),
@@ -109,6 +109,28 @@ def test_concurrent_writes_dfs():
     assert res.ok, f"trace {res.failure_trace}: {res.failure}"
     assert res.schedules_run == 120          # tree is larger than the bound
     assert len(res.traces_seen) == 120       # every schedule distinct
+
+
+def test_concurrent_writes_on_durable_stores_random_schedules(tmp_path):
+    """The same scenario with every shard on a BlueStoreLite: a sub-write's
+    reply waits for its store's commit, and the explorer sends it once
+    nothing else is deliverable, as MessageBus.deliver_all does."""
+    from ceph_tpu.backend.bluestore import BlueStoreLite
+    runs = iter(range(1000))
+
+    def scenario(bus):
+        run = next(runs)
+        stores = [BlueStoreLite(tmp_path / f"r{run}.s{s}") for s in range(3)]
+        try:
+            scenario_concurrent_writes(bus, stores)
+            assert not bus._acks
+            assert all(st.perf.get("commits_queued") for st in stores)
+        finally:
+            for st in stores:
+                st.close(checkpoint=False)
+    res = explore_random(scenario, schedules=10)
+    assert res.ok, f"trace {res.failure_trace}: {res.failure}"
+    assert len(res.traces_seen) > 1
 
 
 def test_write_vs_recovery_schedules():

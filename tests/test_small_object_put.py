@@ -41,8 +41,9 @@ PROFILE = {"plugin": "jax_rs", "k": str(K), "m": str(M),
 SIZES = {"one_stripe": WIDTH, "two_stripes": 2 * WIDTH,
          "three_stripes": 3 * WIDTH, "two_stripes_less_100": 2 * WIDTH - 100}
 PHASES = ("stage_us", "record_us", "block_io_us", "wal_io_us")
-COUNTERS = ("transactions", "txn_ops", "block_bytes", "wal_bytes",
-            "checkpoints") + PHASES + ("commit_cpu_us", "checkpoint_us")
+COUNTERS = ("transactions", "commits_queued", "txn_ops", "block_bytes",
+            "wal_bytes", "checkpoints") + PHASES + ("commit_cpu_us",
+                                                    "checkpoint_us")
 
 
 def _payload(n, seed):
@@ -156,18 +157,27 @@ def test_the_store_counters_rise_by_what_a_put_cost_the_stores(size, served):
 def _fifty_puts(served, tag, monkeypatch):
     """What fifty puts added: each committed transaction's
     ``store.commit`` span (us, in order), each timed transaction's
-    phases as its clock booked them (us, in order), the stores'
+    phases as its clock booked them on the caller's thread (us, in
+    order: all four for a synchronous commit, staging and record for a
+    queued one), the I/O phases of the timed queued ones as the group
+    commit booked them from the native call's clocks (us), the stores'
     counters."""
     from ceph_tpu.common.perf_counters import PerfCounters
     from ceph_tpu.common.tracer import default_tracer
     clock = PerfCounters._PhaseClock
     timed, real = [], clock.commit
+    booked, real_booked = [], clock.commit_booked
 
     def spy(self, weight=1):
         if self.on:
             timed.append(sum(self.sums.values()) * 1e6)
         real(self, weight)
+
+    def spy_booked(self, phases, cpu_ns, weight=1):
+        booked.append(sum(phases.values()) * 1e6)
+        real_booked(self, phases, cpu_ns, weight)
     monkeypatch.setattr(clock, "commit", spy)
+    monkeypatch.setattr(clock, "commit_booked", spy_booked)
     tr = default_tracer()
     done = tr.histograms().get("store.commit", {"count": 0})["count"]
     before = served.stores()
@@ -175,6 +185,7 @@ def _fifty_puts(served, tag, monkeypatch):
     for i in range(50):
         served.r.put("p", f"{tag}.{i}", data)
     monkeypatch.setattr(clock, "commit", real)
+    monkeypatch.setattr(clock, "commit_booked", real_booked)
     rose = {key: served.stores()[key] - before[key] for key in COUNTERS}
     n = tr.histograms()["store.commit"]["count"] - done
     # this cluster's alone (the tracer is the process's: no other store
@@ -183,7 +194,9 @@ def _fifty_puts(served, tag, monkeypatch):
         (e for e in tr.dump()["traceEvents"] if e["name"] == "store.commit"),
         key=lambda e: e["ts"])][-n:]
     assert len(spans) == n == rose["transactions"] >= 50 * (K + M)
-    return spans, timed, rose
+    # every sub-write's commit is queued, its reply sent from its callback
+    assert rose["commits_queued"] == 50 * (K + M)
+    return spans, timed, booked, rose
 
 
 def _less_the_stalls(us):
@@ -196,12 +209,14 @@ def _less_the_stalls(us):
 def test_the_four_phases_tile_the_commit_spans_of_fifty_puts(served,
                                                              monkeypatch):
     monkeypatch.setattr(BlueStoreLite, "TIMED_EVERY", 1)
-    spans, timed, rose = _fifty_puts(served, "tiled", monkeypatch)
+    spans, timed, booked, rose = _fifty_puts(served, "tiled", monkeypatch)
     assert len(timed) == len(spans)
-    # what the clock booked is what the counters rose by (each phase
-    # rounds to a microsecond)
+    assert len(booked) == rose["commits_queued"]
+    # what the clock and the group commit booked is what the counters
+    # rose by (each phase rounds to a microsecond)
     phases = sum(rose[p] for p in PHASES)
-    assert phases == pytest.approx(sum(timed), abs=2.0 * len(spans))
+    assert phases == pytest.approx(sum(timed) + sum(booked),
+                                   abs=2.0 * (len(spans) + len(booked)))
     assert all(rose[p] > 0 for p in PHASES)
     # consecutive marks of one clock, from the span's first statement
     # to its last: a timed span is its phases and the two reads of the
@@ -224,7 +239,8 @@ def test_one_transaction_in_many_is_timed_and_stands_for_them(served,
                                                               monkeypatch):
     every = BlueStoreLite.TIMED_EVERY
     assert every == 17
-    spans, timed, rose = _fifty_puts(served, "sampled", monkeypatch)
+    spans, timed, booked, rose = _fifty_puts(served, "sampled",
+                                             monkeypatch)
     # one in seventeen a store, and every counter of a timed transaction
     # booked seventeen-fold (each rounds to a microsecond before it is
     # multiplied)
@@ -232,8 +248,9 @@ def test_one_transaction_in_many_is_timed_and_stands_for_them(served,
         < len(spans) + every * (K + M)
     phases = sum(rose[p] for p in PHASES)
     assert phases % every == 0 and rose["commit_cpu_us"] % every == 0
-    assert phases == pytest.approx(every * sum(timed),
-                                   abs=2.0 * every * len(timed))
+    assert phases == pytest.approx(
+        every * (sum(timed) + sum(booked)),
+        abs=2.0 * every * (len(timed) + len(booked)))
     assert 0 < rose["commit_cpu_us"] <= phases + 25.0 * len(spans)
     # an estimate of all the spans from some seventy of them.  The
     # stride is odd, so a store's timed transactions are sub-writes and
@@ -256,9 +273,11 @@ def test_the_512th_record_checkpoints_and_its_time_is_counted(tmp_path):
     counted = store.perf.dump()
     assert counted["checkpoints"] == 1 and counted["checkpoint_us"] > 0
     assert counted["transactions"] == 512
-    # a transaction that writes no data still passes the block flush
-    # (30 of the 512 were timed)
-    assert counted["block_io_us"] > 0 and counted["block_bytes"] == 0
+    # a transaction that writes no data makes no block call (blobs are
+    # written unbuffered, at their offsets: nothing waits for a flush
+    # since PR 38), and its journal record is timed (30 of the 512 were)
+    assert counted["block_bytes"] == 0 and counted["wal_io_us"] > 0
+    assert counted["block_io_us"] <= counted["wal_io_us"]
     store.close()
 
 
