@@ -29,6 +29,10 @@ from .rados_clients import POOL
 
 
 class Driver:
+    #: the clients' class, built in their process by this dotted path: a
+    #: driver module that brings a client op names its subclass here
+    clients_class = rados_clients.CLIENTS
+
     def __init__(self, config: dict, traffic: dict, seed: int,
                  run_dir: Path, control: str | None = None):
         self.p = dict(config["driver_params"])
@@ -75,7 +79,8 @@ class Driver:
         if p["profile"]["technique"] != "cauchy":
             raise ValueError("the reference knows technique=cauchy only")
         self.clients = rados_clients.ClientProcess(
-            self.server.port, str(self.keyring), t, self.seed)
+            self.server.port, str(self.keyring), t, self.seed,
+            self.clients_class)
         made = self.clients.setup()
         self.object_set = [tuple(o) for o in made["object_set"]]
         self.acked = [tuple(o) for o in made["acked"]]

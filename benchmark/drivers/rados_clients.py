@@ -11,15 +11,28 @@ clients apart (PERF.md, findings of PR 24).
 
 Times are ``time.perf_counter`` (CLOCK_MONOTONIC), one clock for both
 processes of a machine.
+
+A driver module brings an op of its own without editing this file: a
+subclass of :class:`Clients` adds an entry to ``OPS``, and its
+``Driver`` names that subclass in ``clients_class``; the clients'
+process builds the class it is handed by that dotted path.
 """
 from __future__ import annotations
 
+import importlib
 import itertools
 import os
 import random
 import threading
 
 POOL = "bench"
+CLIENTS = f"{__name__}.Clients"
+
+
+def load_class(path: str):
+    """The class a dotted ``module.Class`` path names."""
+    module, _, name = path.rpartition(".")
+    return getattr(importlib.import_module(module), name)
 
 
 def make_payloads(seed: int, traffic: dict):
@@ -34,6 +47,11 @@ def make_payloads(seed: int, traffic: dict):
 
 
 class Clients:
+    #: the traffic's ``op`` -> (the method that warms it in set-up and
+    #: returns the objects it acked, the method that makes the window's
+    #: op); a subclass adds an entry, an op with none is refused
+    OPS = {"put": ("warm_puts", "put_op"), "get": ("warm_gets", "get_op")}
+
     def __init__(self, port: int, keyring: str, traffic: dict, seed: int):
         from ceph_tpu.net import TcpRados
         from ..lib.loadgen import Reservoir
@@ -50,10 +68,16 @@ class Clients:
                       for ci in range(traffic["clients"])] \
             if traffic["op"] == "get" else []
 
+    def _entry(self) -> tuple[str, str]:
+        entry = self.OPS.get(self.t["op"])
+        if entry is None:
+            raise ValueError(f"driver rados has no op {self.t['op']!r}")
+        return entry
+
     def setup(self) -> dict:
         """The object set a read mix reads, put through the same path,
-        then one warm op per client through every shape the window
-        uses."""
+        then the op's warm-up through every shape the window uses."""
+        warm, _make = self._entry()
         t, payloads = self.t, self.payloads
         n_set = t.get("object_set", 0)
         if n_set:
@@ -70,20 +94,25 @@ class Clients:
                     self.clients[ci].put(POOL, oid, payloads[pi])
             self._all_clients(put_set)
             self.object_set = names
+        return {"acked": getattr(self, warm)(), "object_set": self.object_set}
+
+    def warm_puts(self) -> list[tuple[str, int]]:
+        """One put a client, in turn, under a name of its own."""
         acked = []
         for ci, r in enumerate(self.clients):
-            pi = ci % len(payloads)
-            if t["op"] == "put":
-                oid = f"{self.prefix}.warm.{ci:02d}"
-                r.put(POOL, oid, payloads[pi])
-                acked.append((oid, pi))
-        if t["op"] == "get":
-            # one pass over the whole object set, shared by the clients:
-            # the window re-reads the set many times over, so whatever
-            # the first read of an object fills (the stores' caches)
-            # fills here and not in the window's first second
-            self._all_clients(lambda ci: self._warm_reads(ci))
-        return {"acked": acked, "object_set": self.object_set}
+            pi = ci % len(self.payloads)
+            oid = f"{self.prefix}.warm.{ci:02d}"
+            r.put(POOL, oid, self.payloads[pi])
+            acked.append((oid, pi))
+        return acked
+
+    def warm_gets(self) -> list[tuple[str, int]]:
+        """One pass over the whole object set, shared by the clients:
+        the window re-reads the set many times over, so whatever the
+        first read of an object fills (the stores' caches) fills here
+        and not in the window's first second."""
+        self._all_clients(self._warm_reads)
+        return []
 
     def _warm_reads(self, ci: int) -> None:
         for oid, pi in self.object_set[ci::len(self.clients)]:
@@ -112,26 +141,31 @@ class Clients:
             raise errors[0]
 
     def _op(self):
-        t = self.t
-        payloads, clients, prefix = self.payloads, self.clients, self.prefix
-        per = t["payloads_per_client"]
-        if t["op"] == "put":
-            def put(ci, seq):
-                pi = ci * per + seq % per
-                oid = f"{prefix}.{ci:02d}.{seq:07d}"
-                clients[ci].put(POOL, oid, payloads[pi])
-                return len(payloads[pi]), (oid, pi)
-            return put
-        if t["op"] == "get":
-            objects, nxt, kept = self.object_set, self._read_seq, self._kept
+        """The window's op: ``fn(client index, seq) -> (bytes, key)``,
+        key ``(oid, payload index)`` for an object the op stored."""
+        return getattr(self, self._entry()[1])()
 
-            def get(ci, _seq):
-                oid, pi = objects[next(nxt) % len(objects)]
-                data = clients[ci].get(POOL, oid)
-                kept[ci].offer((oid, pi, data))
-                return len(data), None
-            return get
-        raise ValueError(f"driver rados has no op {t['op']!r}")
+    def put_op(self):
+        payloads, clients, prefix = self.payloads, self.clients, self.prefix
+        per = self.t["payloads_per_client"]
+
+        def put(ci, seq):
+            pi = ci * per + seq % per
+            oid = f"{prefix}.{ci:02d}.{seq:07d}"
+            clients[ci].put(POOL, oid, payloads[pi])
+            return len(payloads[pi]), (oid, pi)
+        return put
+
+    def get_op(self):
+        clients = self.clients
+        objects, nxt, kept = self.object_set, self._read_seq, self._kept
+
+        def get(ci, _seq):
+            oid, pi = objects[next(nxt) % len(objects)]
+            data = clients[ci].get(POOL, oid)
+            kept[ci].offer((oid, pi, data))
+            return len(data), None
+        return get
 
     def window(self, seconds: float, on_start=None) -> dict:
         from ..lib.loadgen import closed_loop
@@ -169,16 +203,18 @@ class Clients:
         self.clients = []
 
 
-def serve(conn, port: int, keyring: str, traffic: dict, seed: int) -> None:
-    """The child's main: build the clients, then answer the parent's
-    requests over ``conn`` until told to close."""
+def serve(conn, port: int, keyring: str, traffic: dict, seed: int,
+          clients_class: str = CLIENTS) -> None:
+    """The child's main: build the clients (``clients_class``, a dotted
+    path), then answer the parent's requests over ``conn`` until told
+    to close."""
     os.environ["JAX_PLATFORMS"] = "cpu"       # never the parent's chip
     import gc
     from ..lib import allocator
     allocator.pin()
     clients = None
     try:
-        clients = Clients(port, keyring, traffic, seed)
+        clients = load_class(clients_class)(port, keyring, traffic, seed)
         while True:
             msg = conn.recv()
             what = msg[0]
@@ -214,14 +250,16 @@ def serve(conn, port: int, keyring: str, traffic: dict, seed: int) -> None:
 
 class ClientProcess:
     """The parent's handle on the clients' process: the same three calls
-    as :class:`Clients`."""
+    as :class:`Clients` (or the subclass ``clients_class`` names)."""
 
-    def __init__(self, port: int, keyring: str, traffic: dict, seed: int):
+    def __init__(self, port: int, keyring: str, traffic: dict, seed: int,
+                 clients_class: str = CLIENTS):
         import multiprocessing
         ctx = multiprocessing.get_context("spawn")
         self.conn, child = ctx.Pipe()
         self.proc = ctx.Process(target=serve, name="bench-clients",
-                                args=(child, port, keyring, traffic, seed))
+                                args=(child, port, keyring, traffic, seed,
+                                      clients_class))
         self.proc.start()
         child.close()
 

@@ -87,9 +87,10 @@ def test_traced_rehearsal_reads_spans_and_counters_and_no_device_number(
     assert "busy_s" not in res["device"]
     if cell.startswith("rados_"):
         assert res["metrics"]["rpc_work_ms"]["value"] > 0
-        assert res["metrics"]["rpc_lock_wait_ms"]["value"] >= 0
-        assert res["metrics"]["dispatches_per_op"]["value"] >= 0
+        assert res["metrics"]["lock_wait_ms"]["value"] >= 0
+        assert res["metrics"]["device_dispatches_per_op"]["value"] >= 0
     if cell == "rados_write_4m_qd16":
+        assert res["metrics"]["rpc_prepare_ms"]["value"] > 0
         assert res["metrics"]["ops_per_batch"]["value"] >= 1.0
         assert res["metrics"]["pg_fanout_commit_ms"]["value"] > 0
 

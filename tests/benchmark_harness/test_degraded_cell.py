@@ -12,13 +12,14 @@ import pytest
 REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
+import rehearsals  # noqa: E402
 from benchmark import run as bench_run  # noqa: E402
 from benchmark.drivers import rados_degraded  # noqa: E402
 
 CELL = "rados_degraded_read_4m_qd16"
-READ = "rados_seqread_4m_qd16"
 SEED = 2147483777
-# what the deployment adds to the read cell's line, device numbers apart
+# what the deployment adds to the read path's metrics, device numbers
+# apart; this cell lists them, the clean read cell does not
 ADDED = ["get_decode_ms", "pg_subread_ms", "pipeline_dispatch_ms",
          "reconstructed_chunks_per_get", "queue_wait_ms", "pipeline_pack_ms",
          "pipeline_device_wait_ms", "pipeline_fetch_ms", "ops_per_batch"]
@@ -70,12 +71,24 @@ def test_untraced_rehearsal_reports_the_end_to_end_metrics(runs):
                                                 "setup_s"}
 
 
+def check_a_traced_rehearsal_prints_the_cells_own_list(bench, repo):
+    """Exactly the metrics the cell's own entries list, those of the
+    device's plane apart: no expected set is built from the read cell's
+    list, so a metric appended to that cell alone fails nothing."""
+    res = rehearsals.traced(CELL, repo)
+    assert res["correct"] is True, failed_checks(res)
+    assert set(res["metrics"]) == rehearsals.host_side(CELL, repo)
+
+
+# checks of (bench, repo): tests/benchmark_harness/test_manifest.py runs
+# them again on a copy of the benchmark that a later PR's cells grew
+INVARIANTS = [check_a_traced_rehearsal_prints_the_cells_own_list]
+
+
 def test_traced_rehearsal_reports_the_read_cells_metrics_and_its_own(runs):
     got = runs["traced"]["metrics"]
-    host_side = {m["name"] for m in
-                 bench_run.manifest.load_cell(READ)["per_layer"]
-                 if m["source"] != "device_trace"}
-    assert set(got) == host_side | set(ADDED)
+    assert set(got) == rehearsals.host_side(CELL)
+    assert set(ADDED) <= set(got)
     for name in ADDED + ["pipeline_unpack_ms", "device_dispatches_per_op"]:
         assert got[name]["value"] > 0, name
     # a CPU capture has no device plane: no device number is invented

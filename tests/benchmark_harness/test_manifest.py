@@ -4,8 +4,10 @@ loading of a cell's files by name.
 The contract checks are functions of ``(bench, repo)``: they run on this
 tree under the test names they always had, and again on a copy of the
 benchmark that a later PR's cell was added to (the last tests here).
-No check pins how many cells or metrics there are, or where in a list
-one stands: adding one must not fail a test."""
+So do the invariants the harness's other test files hold a cell's own
+entries to (their ``INVARIANTS``).  No check pins how many cells or
+metrics there are, or where in a list one stands: adding one must not
+fail a test."""
 import hashlib
 import json
 import re
@@ -18,7 +20,12 @@ import pytest
 REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
+import rehearsals  # noqa: E402
 from benchmark.lib import manifest  # noqa: E402
+from test_cpu_time_metrics import INVARIANTS as CPU_TIME  # noqa: E402
+from test_degraded_cell import INVARIANTS as DEGRADED  # noqa: E402
+from test_rollforward_metric import INVARIANTS as ROLLFORWARD  # noqa: E402
+from test_small_object_cell import INVARIANTS as SMALL_OBJECT  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -239,6 +246,12 @@ def test_an_unknown_cell_is_refused_by_name():
 READ = "rados_seqread_4m_qd16"
 SIBLING = "rados_sibling_read_4m_qd16"     # what the degraded-pool PR adds
 SIBLING_METRIC = "ec_decode_ms"
+# what the next model_config PR adds: a write cell on a deployment of its
+# own, and a metric after the last entry that both write paths report
+WRITE, SMALL = "rados_write_4m_qd16", "rados_write_64k_qd64"
+WRITE_SIBLING = "rados_sibling_write_64k_qd64"
+WRITE_METRIC = "store_txn_ops_per_put"
+INVARIANTS = CPU_TIME + ROLLFORWARD + SMALL_OBJECT + DEGRADED
 
 
 def _hashes(root):
@@ -265,7 +278,13 @@ def grown(tmp_path_factory):
       ``op_lat_p95`` and every per-layer entry that lists the read cell,
       and one new metric (a file and an entry);
     - an ec cell with a configuration, a traffic mix and a metric of its
-      own.
+      own;
+    - a write-cell sibling: a new configuration (the small-object pool
+      under another name and source), a workload on the EXISTING traffic
+      ``write_64k_qd64``, its name appended to every entry that lists
+      ``rados_write_64k_qd64``, and one new metric appended after the
+      last per-layer entry, listing the 4 MiB write cell and the
+      sibling.
 
     Returns ``(root, hashes of the files before the additions)``."""
     root = tmp_path_factory.mktemp("grown")
@@ -332,6 +351,36 @@ def grown(tmp_path_factory):
     next(m for m in bench["end_to_end"]
          if m["name"] == "codec_bw")["workloads"].append("ec_single_64k")
     bench["per_layer"].append(_entry(metric, ["ec_single_64k"]))
+
+    cfg = json.loads((REPO / "benchmark/configs/rados_bench_ec84_64k.json")
+                     .read_text())
+    cfg["name"] = "rados_bench_ec84_64k_sibling"
+    cfg["source"] = "as a later PR's deployment: " + cfg["source"][:150]
+    (root / "benchmark/configs/rados_bench_ec84_64k_sibling.json"
+     ).write_text(json.dumps(cfg, indent=1))
+    entry = next(c for c in bench["configs"]
+                 if c["name"] == "rados_bench_ec84_64k")
+    bench["configs"].append({**entry, "name": cfg["name"],
+                             "source": cfg["source"],
+                             "file": "benchmark/configs/"
+                                     "rados_bench_ec84_64k_sibling.json"})
+    bench["workloads"].append({"name": WRITE_SIBLING, "config": cfg["name"],
+                               "traffic": "write_64k_qd64", "chips": 1,
+                               "why": "the small-object cell's traffic on a "
+                                      "sibling deployment"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if SMALL in m.get("workloads", []):
+            m["workloads"].append(WRITE_SIBLING)
+    metric = {"name": WRITE_METRIC, "layer": "store", "unit": "1/op",
+              "better": "lower", "source": "program_counter",
+              "moves": "client_bw",
+              "what": "bluestore.* txn_ops over client ops",
+              "reader": "counter_ratio",
+              "params": {"num": ["bluestore.*:txn_ops"],
+                         "den": "client_ops"}}
+    (root / f"benchmark/metrics/{WRITE_METRIC}.json").write_text(
+        json.dumps(metric, indent=1))
+    bench["per_layer"].append(_entry(metric, [WRITE, WRITE_SIBLING]))
     (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
     return root, before
 
@@ -344,11 +393,12 @@ def test_a_new_cell_is_files_and_entries_only(grown):
     root, before = grown
     after = _hashes(root / "benchmark")
     assert {f: after.get(f) for f in before} == before
-    assert sorted(set(after) - set(before)) == [
+    assert sorted(set(after) - set(before)) == sorted([
         "configs/ec_bench_k4m2_64k.json",
         "configs/rados_bench_ec84_sibling.json",
+        "configs/rados_bench_ec84_64k_sibling.json",
         "metrics/codec_encode_ms.json", f"metrics/{SIBLING_METRIC}.json",
-        "traffic/single_64k.json"]
+        f"metrics/{WRITE_METRIC}.json", "traffic/single_64k.json"])
     was = manifest.load_benchmark()
     now = manifest.load_benchmark(root)
     for key, value in was.items():
@@ -371,6 +421,16 @@ def test_a_new_cell_is_files_and_entries_only(grown):
     assert [m["name"] for m in sib["per_layer"]] == \
         [m["name"] for m in read["per_layer"]] + [SIBLING_METRIC]
     assert sib["end_to_end"] == read["end_to_end"]
+    small = manifest.load_cell(SMALL, repo=root)
+    wsib = manifest.load_cell(WRITE_SIBLING, repo=root)
+    assert wsib["config"]["name"] == "rados_bench_ec84_64k_sibling"
+    assert wsib["traffic"] == small["traffic"]
+    assert [m["name"] for m in wsib["per_layer"]] == \
+        [m["name"] for m in small["per_layer"]] + [WRITE_METRIC]
+    assert wsib["end_to_end"] == small["end_to_end"]
+    assert [m["name"] for m in manifest.load_cell(WRITE, root)["per_layer"]
+            ][-1] == WRITE_METRIC
+    assert [m["name"] for m in now["per_layer"]][-1] == WRITE_METRIC
 
 
 @pytest.mark.parametrize("check", CONTRACT, ids=lambda f: f.__name__[6:])
@@ -379,12 +439,35 @@ def test_the_whole_contract_holds_with_the_new_cells(check, grown):
     check(manifest.load_benchmark(root), root)
 
 
+@pytest.mark.parametrize("check", INVARIANTS, ids=lambda f: f.__name__[6:])
+def test_the_cells_own_invariants_hold_with_the_new_cells(check, grown):
+    """What the harness's other test files hold a cell's entries to,
+    on the grown tree: the twelve CPU-time metrics still one run, the
+    roll-forward entry listing the sibling too (its rehearsal prints
+    it), the small-object and degraded cells printing their own lists."""
+    root, _ = grown
+    check(manifest.load_benchmark(root), root)
+
+
 def test_load_cell_finds_every_cell_of_the_grown_tree(grown):
     root, _ = grown
     bench = manifest.load_benchmark(root)
-    assert len(bench["workloads"]) == len(CELLS) + 2
+    assert len(bench["workloads"]) == len(CELLS) + 3
     for w in bench["workloads"]:
         check_load_cell(w["name"], bench, root)
+
+
+@pytest.mark.parametrize("cell", [WRITE_SIBLING, WRITE])
+def test_the_write_cells_print_the_appended_metric_and_their_own_lists(
+        cell, grown):
+    """The sibling and the 4 MiB write cell run through the harness as it
+    stands, traced: each prints exactly its own list, the metric
+    appended after the last entry with it."""
+    root, _ = grown
+    res = rehearsals.traced(cell, root)
+    assert res["correct"] is True, res["checks"]
+    assert set(res["metrics"]) == rehearsals.host_side(cell, root)
+    assert res["metrics"][WRITE_METRIC]["value"] > 0
 
 
 @pytest.mark.parametrize("trace", [True, False], ids=["traced", "untraced"])

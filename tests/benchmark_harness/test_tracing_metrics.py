@@ -98,8 +98,10 @@ def test_device_dispatches_per_op_parts_a_put_from_a_clean_get(traced):
     assert traced[READ]["metrics"][COUNTER]["value"] == 0
     assert traced[WRITE]["metrics"][COUNTER]["value"] == \
         pytest.approx(1.0, abs=0.1)
-    # the counter it stands beside cannot tell them apart
-    assert traced[READ]["metrics"]["dispatches_per_op"]["value"] > 0.9
+    # a clean get still passes through the pipeline, for the host's
+    # relayout alone: what the counter of submissions could not tell
+    # from a put's device trip
+    assert traced[READ]["metrics"]["pipeline_unpack_ms"]["value"] > 0
 
 
 def test_a_cpu_rehearsal_leaves_the_device_metric_out(traced):
