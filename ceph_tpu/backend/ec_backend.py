@@ -43,7 +43,7 @@ from .pg_backend import (Op, OSDShard, PG_META, PGBackend, RecoveryOp,
                          RecoveryState, RepairState, ShardRepairOp,
                          _slice_subchunks)
 from .transaction import PreparedWrite, get_write_plan
-from ..common.tracer import trace_span
+from ..common.tracer import default_tracer, trace_span
 from ..osd.pg_log import OP_DELETE, OP_MODIFY
 
 __all__ = ["ECBackend", "OSDShard", "RecoveryState", "RecoveryOp",
@@ -249,6 +249,11 @@ class ECBackend(PGBackend):
             op.plan = get_write_plan(
                 self.sinfo, op.t, self._hinfo,
                 sub_chunk_count=self.ec_impl.get_sub_chunk_count())
+            if op.plan.to_read:
+                self.perf.inc("rmw_ops")
+                self.perf.inc("overwrite_bytes", sum(
+                    len(data) for oid in op.plan.to_read
+                    for _, data in op.plan.t.ops[oid].buffer_updates))
 
     def _op_blocked(self, op: Op) -> bool:
         """An RMW read overlapping an earlier in-flight write must wait until
@@ -316,6 +321,10 @@ class ECBackend(PGBackend):
                         ext_list.append(entry)
         op._rmw_chunks = {c: self.acting[c] for c in minimum}
         op._rmw_need = need
+        # the span ec.rmw_read runs from the op's first fan-out (a restart
+        # after a failed source stays inside it) to its last reply
+        if getattr(op, "_rmw_read_t0", None) is None:
+            op._rmw_read_t0 = default_tracer().stamp()
         op._rmw_buf: dict[str, dict[int, dict[int, bytes]]] = {}
         # restarts (rotten-source retry, stall recovery) may carry stale
         # pending entries/sentinels: this dispatch defines the set
@@ -569,6 +578,7 @@ class ECBackend(PGBackend):
         op.cache_claims.clear()
         self._rmw_read_tids.pop(getattr(op, "_rmw_read_tid", None), None)
         op._rmw_buf = {}
+        op._rmw_read_t0 = None
 
     # -- failure-handling hooks ----------------------------------------------
 
@@ -812,11 +822,17 @@ class ECBackend(PGBackend):
         op.pending_read_shards.discard(reply.from_shard)
         chunk_of_shard = {s: c for c, s in enumerate(self.acting)}
         chunk = chunk_of_shard[reply.from_shard]
+        got = 0
         for oid, bufs in reply.buffers_read.items():
             store = op._rmw_buf.setdefault(oid, {})
             for c_off, data in bufs:
                 store.setdefault(c_off, {})[chunk] = data
+                got += len(data)
+        self.perf.inc("rmw_read_bytes", got)
         if not op.pending_read_shards:
+            t0, op._rmw_read_t0 = op._rmw_read_t0, None
+            if t0 is not None:
+                default_tracer().observe("ec.rmw_read", t0)
             self._rmw_read_tids.pop(getattr(op, "_rmw_read_tid", None), None)
             self._finish_rmw_reads(op)
             self.check_ops()

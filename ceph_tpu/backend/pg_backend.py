@@ -52,6 +52,7 @@ from .messages import (ECPartialSum, ECPartialSumAbort, ECPartialSumApplied,
                        RollForward, Rollback)
 from .transaction import PGTransaction
 from ..common.tracer import trace_span
+from ..failure.store import unwrap
 from ..osd.pg_log import OP_DELETE, OP_MODIFY, PGLog, dedup_latest
 
 
@@ -154,18 +155,46 @@ class OSDShard:
         t.omap_setkeys(self._meta(), {"vi": pickle.dumps(
             (self.pg_log.head, self.pg_log.tail))})
 
-    def _capture_rollback(self, t: Transaction) -> Transaction:
-        """Inverse transaction: snapshot every touched object's prior state
-        (chunk-sized objects make whole-object capture cheap).  The pgmeta
-        object is never captured — its log/rb keys are unwound explicitly
-        by _rollback, and snapshotting it would embed every prior rb blob
-        in each new one."""
-        touched = {op[1] for op in t.ops}
-        touched |= {op[2] for op in t.ops if op[0] == "clone"}
-        touched = {obj for obj in touched if obj.oid != PG_META}
+    # ops whose inverse an extent capture expresses: bytes written in
+    # place, and the attrs set or removed beside them
+    _EXTENT_UNDO_OPS = frozenset(("write", "setattr", "rmattr"))
+
+    def _capture_rollback(self, t: Transaction) -> tuple[Transaction, int]:
+        """Inverse transaction restoring every touched object's prior
+        state, and the object bytes it holds (``rollback_bytes``).
+
+        An object that exists and that the transaction writes into, with
+        nothing beside the writes but attr updates, keeps what they cover:
+        the old bytes of each written extent that lay inside the object,
+        and its old size, so the inverse writes those bytes back and
+        truncates what the writes grew.  Upstream keeps no more for an
+        EC overwrite: the overwritten extents, as ObjectModDesc rollback
+        extents that ECTransaction clones aside (recalled, not checked
+        against the reference).  Every other object — one the
+        transaction creates, removes, truncates, zeroes, clones or gives
+        omap — is snapshotted whole.  The pgmeta object is never
+        captured — its log/rb keys are unwound explicitly by _rollback,
+        and snapshotting it would embed every prior rb blob in each new
+        one."""
+        by_obj: dict[GObject, list[tuple]] = {}
+        for op in t.ops:
+            by_obj.setdefault(op[1], []).append(op)
+            if op[0] == "clone":
+                by_obj.setdefault(op[2], []).append(op)
         inv = Transaction()
-        for obj in sorted(touched, key=lambda g: (g.oid, g.shard)):
+        captured = 0
+        for obj in sorted(by_obj, key=lambda g: (g.oid, g.shard)):
+            if obj.oid == PG_META:
+                continue
+            ops = by_obj[obj]
             try:
+                if any(op[0] == "write" for op in ops) and \
+                        all(op[0] in self._EXTENT_UNDO_OPS for op in ops) \
+                        and self.store.exists(obj):
+                    undo, n = self._capture_extents(obj, ops)
+                    inv.append(undo)
+                    captured += n
+                    continue
                 o = self.store.objects.get(obj)
             except ChecksumError:
                 # pre-state unreadable (rotten at rest): the best honest
@@ -175,11 +204,44 @@ class OSDShard:
             inv.remove(obj)
             if o is not None:
                 inv.write(obj, 0, bytes(o.data))
+                captured += len(o.data)
                 for name, value in o.xattrs.items():
                     inv.setattr(obj, name, value)
                 if o.omap:
                     inv.omap_setkeys(obj, dict(o.omap))
-        return inv
+        return inv, captured
+
+    def _capture_extents(self, obj: GObject,
+                         ops: list[tuple]) -> tuple[Transaction, int]:
+        """The inverse of writes and attr updates on an existing object:
+        its old bytes under each write, a truncate back to its old size
+        where the writes grew it, and its old value of each attr touched
+        (removed where it had none).  Read beneath the fault plane, as
+        the whole-object capture reads: an injected read error is a
+        client read's, not the journal's."""
+        store = unwrap(self.store)
+        size = store.stat(obj)
+        attrs = store.getattrs(obj)
+        undo = Transaction()
+        captured = 0
+        end = size
+        for op in ops:
+            if op[0] != "write":
+                continue
+            off, n = op[2], len(op[3])
+            end = max(end, off + n)
+            if off < size:
+                old = store.read(obj, off, min(off + n, size) - off)
+                undo.write(obj, off, old)
+                captured += len(old)
+        if end > size:
+            undo.truncate(obj, size)
+        for name in dict.fromkeys(op[2] for op in ops if op[0] != "write"):
+            if name in attrs:
+                undo.setattr(obj, name, attrs[name])
+            else:
+                undo.rmattr(obj, name)
+        return undo, captured
 
     def _roll_forward(self, to: int, txn: Transaction | None = None) -> None:
         """Drop rollback data for entries <= ``to``; the key removals ride
@@ -229,8 +291,14 @@ class OSDShard:
                 # ops are appended to t — so the inverse covers only the
                 # data objects; log keys are unwound explicitly by
                 # _rollback
-                inv = self._capture_rollback(t)
+                inv, captured = self._capture_rollback(t)
                 self.pending_rollbacks[msg.at_version] = inv
+                # the PG's counters are its primary's, the sub-write's
+                # sender (on the primary's own shard, the backend itself)
+                perf = getattr(self.bus.handlers.get(msg.from_shard),
+                               "perf", None)
+                if perf is not None:
+                    perf.inc("rollback_bytes", captured)
                 kvs = {_rb_key(msg.at_version):
                        pickle.dumps(inv.ops,
                                     protocol=pickle.HIGHEST_PROTOCOL)}
@@ -875,6 +943,19 @@ class PGBackend:
                              "PG's next sub-write carries the point")
             .add_u64_counter("write_rollbacks",
                              "in-flight writes rolled back (min_size)")
+            .add_u64_counter("rollback_bytes",
+                             "object bytes the PG's shards captured to "
+                             "undo their sub-writes: the extents an "
+                             "overwrite covers, a whole object otherwise "
+                             "(0 for an object the write creates)")
+            .add_u64_counter("rmw_ops",
+                             "writes planned with a read of the stripes "
+                             "they cover part of (read-modify-write)")
+            .add_u64_counter("rmw_read_bytes",
+                             "chunk bytes those reads brought from the "
+                             "shards")
+            .add_u64_counter("overwrite_bytes",
+                             "client bytes of those writes")
             .add_u64_counter("reads", "client reads completed")
             .add_u64_counter("reads_reconstructed",
                              "client reads that decoded at least one "

@@ -393,6 +393,12 @@ class Tracer:
             ev["args"] = args
         self._emit(ev)
 
+    def stamp(self) -> float:
+        """The tracer's clock now (``time.perf_counter``), for a region
+        that opens in one call and closes in another — a fan-out and its
+        last reply — and is recorded where it closes, by :meth:`observe`."""
+        return time.perf_counter()
+
     def observe(self, name: str, t0: float, t1: float | None = None,
                 cat: str = "", ctx: TraceContext | None = None,
                 track: str | None = None, cpu_s: float | None = None,

@@ -986,6 +986,19 @@ class ClusterServer:
             pid, oid, ObjectOperation().write_full(data, prepared))
         return len(data)
 
+    def _rpc_write(self, ch, pool, oid, offset, data):
+        """A partial write at ``offset``: on an EC pool the backend's
+        read-modify-write reads the stripes the write touches, merges
+        the new bytes in and encodes them under the lock (a write_full's
+        codec work runs ahead of it; this one needs the old bytes).  Not
+        in ``IDEMPOTENT_RPCS``: a resend is answered from the first
+        execution's result, since applying a late resend again after a
+        newer write to the same range would undo that write."""
+        from .osd.osd_ops import ObjectOperation
+        pid = self.cluster.pool_ids[pool]
+        self.cluster.operate(pid, oid, ObjectOperation().write(offset, data))
+        return len(data)
+
     def _rpc_get(self, ch, pool, oid):
         from .osd.osd_ops import ObjectOperation
         pid = self.cluster.pool_ids[pool]
@@ -1441,6 +1454,12 @@ class TcpRados:
 
     def put(self, pool, oid, data):
         return self.call("put", pool=pool, oid=oid, data=bytes(data))
+
+    def write(self, pool, oid, data, offset):
+        """Write ``data`` into ``oid`` at ``offset`` (librados
+        ``write``): the bytes around it stay as they were."""
+        return self.call("write", pool=pool, oid=oid, offset=int(offset),
+                         data=bytes(data))
 
     def get(self, pool, oid) -> bytes:
         return self.call("get", pool=pool, oid=oid)
