@@ -10,10 +10,13 @@ The durable store modeled on the reference's flagship ObjectStore
   blob and remaps logical extents onto it (the reference's copy-on-write
   blob model), so a crash mid-write leaves old metadata pointing at old
   bytes — never torn data;
-- **checksums at rest**: each blob stores the crc32c of its physical
-  bytes, verified on EVERY read (``bluestore_csum_type=crc32c``); a
-  mismatch raises :class:`ChecksumError` (EIO), which deep scrub surfaces
-  without any majority vote;
+- **checksums at rest**: each raw blob stores a crc32c of every
+  ``min_alloc`` block of its physical bytes (``bluestore_csum_type=
+  crc32c`` at the block-size csum order), and a read fetches and
+  verifies the blocks it covers and no others; a compressed blob keeps
+  one crc and is read whole.  A mismatch raises :class:`ChecksumError`
+  (EIO), which deep scrub, reading whole, surfaces without any majority
+  vote;
 - **inline compression** via the CompressorRegistry: blobs compress when
   the configured compressor saves at least one allocation unit, storing
   ``raw_len`` for exact reconstruction (``bluestore_compression_mode``);
@@ -57,7 +60,7 @@ from typing import Any
 
 from ..common.perf_counters import PerfCountersBuilder
 from ..common.tracer import trace_span
-from .ecutil import crc32c
+from .ecutil import crc32c, crc32c_blocks
 from .memstore import GObject, Transaction, _Object
 
 _FRAME = struct.Struct("<II")        # payload length, crc32c(payload)
@@ -188,9 +191,16 @@ class Blob:
     plen: int            # stored (possibly compressed) byte length
     alloc: int           # allocated bytes (plen rounded up to units)
     raw_len: int         # decompressed length
-    csum: int            # crc32c of the STORED bytes
+    # crc32c of the STORED bytes: with csum_chunk, one per csum_chunk
+    # bytes packed as little-endian u32 (the last chunk may be short);
+    # without, one int over them all
+    csum: int | bytes
     comp: str | None     # compressor name, None = raw
     refs: int = 1        # extents (across all onodes) mapping this blob
+    # bytes one crc of ``csum`` covers; 0 is one crc of the whole blob: a
+    # compressed blob's, and (read from the class, the field absent) a
+    # blob journaled before the per-block crcs
+    csum_chunk: int = 0
 
 
 @dataclass
@@ -332,6 +342,12 @@ class BlueStoreLite:
         self.perf = (
             PerfCountersBuilder(f"bluestore.{name or self.path.name}")
             .add_u64_counter("transactions", "transactions committed")
+            .add_u64_counter("read_bytes",
+                             "bytes read() returned to its callers")
+            .add_u64_counter("read_block_bytes",
+                             "bytes read from the block file for them and "
+                             "verified against their crc32c: the csum "
+                             "chunks each read covers, whole")
             .add_u64_counter("commits_queued",
                              "of them, those whose I/O was queued for a "
                              "group commit, acked by their on_commit "
@@ -556,26 +572,45 @@ class BlueStoreLite:
         writes.append((poff, stored))
         bid = self.next_blob
         self.next_blob += 1
+        if comp is None:
+            csum, chunk = crc32c_blocks(stored, self.min_alloc), self.min_alloc
+        else:
+            csum, chunk = crc32c(0xFFFFFFFF, stored), 0
         blob = Blob(poff=poff, plen=len(stored), alloc=alloc,
-                    raw_len=raw_len, csum=crc32c(0xFFFFFFFF, stored),
-                    comp=comp)
+                    raw_len=raw_len, csum=csum, comp=comp, csum_chunk=chunk)
         new_blobs[bid] = blob
         self.blobs[bid] = blob
         return bid
 
-    def _read_blob(self, bid: int) -> bytes:
+    def _read_blob_range(self, bid: int, lo: int, hi: int) -> bytes:
+        """Bytes ``[lo, hi)`` of blob ``bid``'s raw content.  The csum
+        chunks they touch are read whole, in one ``pread``, and checked
+        in one call; a blob of one crc (compressed, or journaled before
+        the per-block crcs) is read and checked whole."""
         b = self.blobs[bid]
         self.wait_committed()           # its bytes may still be queued
-        stored = os.pread(self._block.fileno(), b.plen, b.poff)
-        if crc32c(0xFFFFFFFF, stored) != b.csum:
+        chunk = b.csum_chunk
+        if chunk:
+            start = lo - lo % chunk
+            end = min(hi + -hi % chunk, b.plen)
+            stored = os.pread(self._block.fileno(), end - start,
+                              b.poff + start)
+            ok = crc32c_blocks(stored, chunk) == \
+                b.csum[start // chunk * 4:-(-end // chunk) * 4]
+        else:
+            start = 0
+            stored = os.pread(self._block.fileno(), b.plen, b.poff)
+            ok = crc32c(0xFFFFFFFF, stored) == b.csum
+        self.perf.inc("read_block_bytes", len(stored))
+        if not ok:
             raise ChecksumError(
                 f"blob {bid} at {b.poff}+{b.plen}: stored crc mismatch "
-                f"(bitrot at rest)")
+                f"in [{start}, {start + len(stored)}) (bitrot at rest)")
         if b.comp is not None:
             from ..compressor import CompressorRegistry
             return CompressorRegistry.instance().create(
-                b.comp).decompress(stored)
-        return stored
+                b.comp).decompress(stored)[lo:hi]
+        return stored[lo - start:hi - start]
 
     # -- extent-map surgery --------------------------------------------------
 
@@ -813,9 +848,10 @@ class BlueStoreLite:
                 continue
             s = max(e.loff, offset)
             t_ = min(e_end, end)
-            raw = self._read_blob(e.blob)
-            piece = raw[e.boff + (s - e.loff):e.boff + (t_ - e.loff)]
+            piece = self._read_blob_range(e.blob, e.boff + (s - e.loff),
+                                          e.boff + (t_ - e.loff))
             out[s - offset:s - offset + len(piece)] = piece
+        self.perf.inc("read_bytes", len(out))
         return bytes(out)
 
     def stat(self, obj: GObject) -> int:

@@ -10,6 +10,7 @@ stripes is a pure relayout — bit-identical output, MXU-sized launches.
 """
 from __future__ import annotations
 
+import ctypes
 import functools
 
 import numpy as np
@@ -51,20 +52,24 @@ SMALL_CRC_MAX = 64 * 1024
 
 
 def _load_native_crc():
-    """SSE4.2 CRC32C from the native lib (gf8_simd.cc ec_crc32c) as two
-    calls of one symbol: (lets the interpreter go, keeps it).  The pure
-    Python path below stays as the oracle and no-toolchain fallback."""
+    """SSE4.2 CRC32C from the native lib (gf8_simd.cc) as two calls of
+    each symbol, (lets the interpreter go, keeps it): ``ec_crc32c`` at
+    0 and 1, ``ec_crc32c_blocks`` at 2 and 3.  The pure Python path
+    below stays as the oracle and no-toolchain fallback."""
     global _native_crc
     if _native_crc is not None:
         return _native_crc or None
     try:
-        import ctypes
         from ..native import registry_lib
         lib = registry_lib()
         held = ctypes.PYFUNCTYPE(ctypes.c_uint32, ctypes.c_uint32,
                                  ctypes.c_void_p, ctypes.c_size_t)(
             ("ec_crc32c", lib))
-        _native_crc = (lib.ec_crc32c, held)
+        held_blocks = ctypes.PYFUNCTYPE(
+            None, ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
+            ctypes.c_void_p)(("ec_crc32c_blocks", lib))
+        _native_crc = (lib.ec_crc32c, held, lib.ec_crc32c_blocks,
+                       held_blocks)
     except Exception:
         _native_crc = False
     return _native_crc or None
@@ -108,6 +113,25 @@ def crc32c(seed: int, data: bytes | np.ndarray) -> int:
     for i in range(n16, len(buf)):
         crc = t0[(crc ^ buf[i]) & 0xFF] ^ (crc >> 8)
     return crc
+
+
+def crc32c_blocks(data: bytes, block: int) -> bytes:
+    """``crc32c(0xFFFFFFFF, ·)`` of each ``block`` bytes of ``data`` (the
+    last block may be short), packed as little-endian u32: a BlueStore
+    blob's checksum per csum chunk.  One native call where the library
+    is built, keeping the interpreter up to ``SMALL_CRC_MAX`` bytes as
+    :func:`crc32c` does."""
+    if block <= 0:
+        raise ValueError(f"csum block {block}: want a positive size")
+    n = len(data)
+    fns = _load_native_crc()
+    if fns is None:
+        return b"".join(
+            crc32c(0xFFFFFFFF, data[off:off + block]).to_bytes(4, "little")
+            for off in range(0, n, block))
+    out = ctypes.create_string_buffer(4 * -(-n // block))
+    fns[2 + (n <= SMALL_CRC_MAX)](data, n, block, out)
+    return out.raw
 
 
 # -- crc32c combine algebra (the fused-checksum kernel's host half) ---------

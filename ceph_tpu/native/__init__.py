@@ -52,6 +52,9 @@ def registry_lib() -> C.CDLL:
     lib.ec_simd_level.restype = C.c_int
     lib.ec_crc32c.restype = C.c_uint32
     lib.ec_crc32c.argtypes = [C.c_uint32, C.c_void_p, C.c_size_t]
+    lib.ec_crc32c_blocks.restype = None
+    lib.ec_crc32c_blocks.argtypes = [C.c_void_p, C.c_size_t, C.c_size_t,
+                                     C.c_void_p]
     lib.ec_apply_matrix.restype = C.c_int
     lib.ec_apply_matrix.argtypes = [
         C.c_void_p, C.c_int, C.c_int, C.c_void_p, C.c_void_p, C.c_size_t]

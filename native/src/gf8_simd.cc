@@ -338,3 +338,22 @@ extern "C" uint32_t ec_crc32c(uint32_t seed, const unsigned char *p,
     static const bool hw = __builtin_cpu_supports("sse4.2");
     return hw ? crc32c_hw(seed, p, n) : crc32c_sw(seed, p, n);
 }
+
+/* One crc32c per ``block`` bytes of ``p`` (the last block may be short),
+ * each seeded with 0xFFFFFFFF, stored as little-endian u32 at ``out``:
+ * a BlueStore blob's per-csum-chunk checksums in one call. */
+extern "C" void ec_crc32c_blocks(const unsigned char *p, size_t n,
+                                 size_t block, unsigned char *out) {
+    if (block == 0) return;
+    __builtin_cpu_init();
+    static const bool hw = __builtin_cpu_supports("sse4.2");
+    for (size_t off = 0; off < n; off += block, out += 4) {
+        size_t len = n - off < block ? n - off : block;
+        uint32_t c = hw ? crc32c_hw(0xFFFFFFFFu, p + off, len)
+                        : crc32c_sw(0xFFFFFFFFu, p + off, len);
+        out[0] = (unsigned char)c;
+        out[1] = (unsigned char)(c >> 8);
+        out[2] = (unsigned char)(c >> 16);
+        out[3] = (unsigned char)(c >> 24);
+    }
+}

@@ -653,17 +653,18 @@ def test_a_transaction_that_raises_midway_changes_nothing(tmp_path):
         return set(range(s.alloc.watermark)) - free
 
     before = (_state(s), copy.deepcopy(s.blobs), allocated(),
-              s.committed_seq, s.perf.dump(),
-              (tmp_path / "s" / "kv.log").stat().st_size)
+              s.committed_seq, (tmp_path / "s" / "kv.log").stat().st_size)
     bad = (Transaction().write(g, 100, _data(2000, 2)).zero(g, 0, 50)
            .omap_setkeys(g, {"k1": b"changed", "fresh": b"f"})
            .omap_rmkeys(g, ["k2"]).clone(g, c).omap_clear(g)
            .setattr(g, "x", 2).remove(g))
     bad.ops.append(("no_such_op", g))
+    counted = s.perf.dump()         # after _state's reads, which count
     with pytest.raises(ValueError):
         s.queue_transaction(bad)
+    assert s.perf.dump() == counted
     assert s.onodes[g].omap is live_omap and len(live_omap) == 50
-    assert (_state(s), s.blobs, allocated(), s.committed_seq, s.perf.dump(),
+    assert (_state(s), s.blobs, allocated(), s.committed_seq,
             (tmp_path / "s" / "kv.log").stat().st_size) == before
     assert not s.exists(c)
     s2 = BlueStoreLite(tmp_path / "s", min_alloc=512)
@@ -704,3 +705,200 @@ def test_a_checkpoint_is_counted_and_traced(tmp_path):
     assert s.perf.dump()["checkpoints"] == 2
     assert spans() - before == 2
     s.close()
+
+
+# -- a crc32c per csum block: a read verifies the blocks it covers ---------------
+
+BLOCK = 4096
+SIZE = 5 * BLOCK + 1000                 # the last block is short
+
+
+def _blob_rot(store, bid, at):
+    """Flip the stored byte ``at`` of blob ``bid`` behind the store."""
+    fd = store._block.fileno()
+    poff = store.blobs[bid].poff + at
+    os.pwrite(fd, bytes([os.pread(fd, 1, poff)[0] ^ 0xFF]), poff)
+
+
+@pytest.fixture
+def split(tmp_path):
+    """One 5.25-block object put whole, then a 4 KiB overwrite of its
+    third block: the base blob maps as two extents around the new one."""
+    s = BlueStoreLite(tmp_path / "blk", min_alloc=BLOCK)
+    g = GObject("img", 0)
+    model = bytearray(_data(SIZE, 30))
+    s.queue_transaction(Transaction().write(g, 0, bytes(model)))
+    patch = _data(BLOCK, 31)
+    s.queue_transaction(Transaction().write(g, 2 * BLOCK, patch))
+    model[2 * BLOCK:3 * BLOCK] = patch
+    yield s, g, bytes(model)
+    s.close()
+
+
+RANGES = {
+    "one_block": (BLOCK, BLOCK),
+    "inside_a_block": (BLOCK + 904, 100),
+    "across_blocks": (BLOCK - 96, BLOCK + 200),
+    "across_two_extents_of_one_blob": (BLOCK + 17, 3 * BLOCK),
+    "the_short_last_block": (5 * BLOCK + 300, 700),
+    "to_the_end": (3 * BLOCK + 5, None),
+    "whole": (0, None),
+}
+
+
+@pytest.mark.parametrize("case", list(RANGES))
+def test_a_range_read_is_the_slice_of_the_whole_read(case, split):
+    s, g, model = split
+    off, n = RANGES[case]
+    end = SIZE if n is None else off + n
+    whole = s.read(g)
+    assert whole == model
+    assert s.read(g, off, n) == whole[off:end] == model[off:end]
+
+
+@pytest.mark.parametrize("case", list(RANGES))
+def test_a_range_read_counts_the_blocks_it_covers(case, split):
+    """``read_block_bytes``: each extent's range rounded out to whole
+    blocks of its blob (the short last block as stored), read once."""
+    s, g, _model = split
+    off, n = RANGES[case]
+    end = SIZE if n is None else off + n
+    want = 0
+    for e in s.onodes[g].extents:
+        lo = max(e.loff, off) - e.loff + e.boff
+        hi = min(e.loff + e.length, end) - e.loff + e.boff
+        if hi > lo:
+            want += min(-(-hi // BLOCK) * BLOCK,
+                        s.blobs[e.blob].plen) - lo // BLOCK * BLOCK
+    before = s.perf.dump()
+    s.read(g, off, n)
+    after = s.perf.dump()
+    assert after["read_bytes"] - before["read_bytes"] == end - off
+    assert after["read_block_bytes"] - before["read_block_bytes"] == want
+    assert want < end - off + 2 * 2 * BLOCK
+
+
+def test_a_4k_read_of_a_512k_blob_reads_4k(tmp_path):
+    s = BlueStoreLite(tmp_path / "s", min_alloc=BLOCK)
+    g = GObject("shard", 0)
+    payload = _data(512 * 1024, 32)
+    s.queue_transaction(Transaction().write(g, 0, payload))
+    [blob] = s.blobs.values()
+    assert (blob.csum_chunk, len(blob.csum)) == (BLOCK, 128 * 4)
+    before = s.perf.dump()
+    assert s.read(g, 45 * BLOCK, BLOCK) == \
+        payload[45 * BLOCK:46 * BLOCK]
+    after = s.perf.dump()
+    assert after["read_bytes"] - before["read_bytes"] == BLOCK
+    assert after["read_block_bytes"] - before["read_block_bytes"] == BLOCK
+    s.close()
+
+
+@pytest.mark.parametrize("where", ["covered", "uncovered"])
+def test_rot_fails_the_reads_that_cover_its_block(where, split):
+    """Rot in a block a range read covers fails it and the whole read;
+    rot in a block it does not cover leaves it right, and the whole
+    read (deep scrub's) still finds it."""
+    s, g, model = split
+    base = s.onodes[g].extents[0].blob
+    block = 1 if where == "covered" else 4
+    _blob_rot(s, base, block * BLOCK + 77)
+    off, n = BLOCK + 10, 200
+    if where == "covered":
+        with pytest.raises(ChecksumError, match="stored crc mismatch"):
+            s.read(g, off, n)
+    else:
+        assert s.read(g, off, n) == model[off:off + n]
+    with pytest.raises(ChecksumError, match="stored crc mismatch"):
+        s.read(g)
+
+
+def test_a_compressed_blob_keeps_one_crc_and_reads_whole(tmp_path):
+    s = BlueStoreLite(tmp_path / "z", min_alloc=BLOCK, compression="zlib")
+    g = GObject("z", 0)
+    payload = b"ABCD" * (4 * BLOCK)
+    s.queue_transaction(Transaction().write(g, 0, payload))
+    [blob] = s.blobs.values()
+    assert blob.comp == "zlib" and blob.csum_chunk == 0
+    assert isinstance(blob.csum, int)
+    before = s.perf.dump()["read_block_bytes"]
+    assert s.read(g, BLOCK + 3, 500) == payload[BLOCK + 3:BLOCK + 503]
+    assert s.perf.dump()["read_block_bytes"] - before == blob.plen
+    assert s.read(g) == payload
+    _blob_rot(s, next(iter(s.blobs)), blob.plen - 1)
+    with pytest.raises(ChecksumError):
+        s.read(g, 0, 10)
+    s.close()
+
+
+def _one_crc_blobs(store):
+    """The store's blobs as the code before per-block crcs journaled
+    them: one int crc of the stored bytes and no ``csum_chunk`` field
+    (what unpickling such a record gives)."""
+    from ceph_tpu.backend.bluestore import Blob
+    from ceph_tpu.backend.ecutil import crc32c
+    for bid, b in list(store.blobs.items()):
+        old = Blob.__new__(Blob)
+        old.__dict__.update(
+            poff=b.poff, plen=b.plen, alloc=b.alloc, raw_len=b.raw_len,
+            csum=crc32c(0xFFFFFFFF, os.pread(store._block.fileno(), b.plen,
+                                             b.poff)),
+            comp=b.comp, refs=b.refs)
+        store.blobs[bid] = old
+
+
+@pytest.mark.parametrize("journal", ["kv.snap", "kv.log"])
+def test_a_store_journaled_with_one_crc_a_blob_opens_and_reads(journal,
+                                                               tmp_path):
+    """Blobs of one crc, from a checkpoint or from a journal record,
+    load as one-chunk blobs: every read returns the right bytes and
+    checks the whole blob; new writes beside them take per-block crcs."""
+    from ceph_tpu.backend import bluestore
+    s = BlueStoreLite(tmp_path / "s", min_alloc=BLOCK)
+    g = GObject("old", 0)
+    payload = _data(3 * BLOCK + 10, 33)
+    s.queue_transaction(Transaction().write(g, 0, payload))
+    _one_crc_blobs(s)
+    if journal == "kv.snap":
+        s.close()
+    else:
+        # a record carries the blobs whose refs it changes: journal a
+        # clone of the object after the checkpoint
+        s.checkpoint()
+        s.queue_transaction(Transaction().clone(g, GObject("cl", 0)))
+        record = (tmp_path / "s" / "kv.log").read_bytes()
+        assert b"csum_chunk" not in record
+        s._wal.close()
+        s._block.close()                # killed: no checkpoint
+    with open(tmp_path / "s" / journal, "rb") as f:
+        assert b"csum_chunk" not in f.read()
+    s2 = BlueStoreLite(tmp_path / "s", min_alloc=BLOCK)
+    [bid] = s2.blobs
+    assert "csum_chunk" not in vars(s2.blobs[bid])
+    assert s2.blobs[bid].csum_chunk == 0
+    before = s2.perf.dump()["read_block_bytes"]
+    assert s2.read(g, BLOCK + 1, 20) == payload[BLOCK + 1:BLOCK + 21]
+    assert s2.perf.dump()["read_block_bytes"] - before == len(payload)
+    assert s2.read(g) == payload
+    s2.queue_transaction(Transaction().write(g, BLOCK, _data(BLOCK, 34)))
+    want = payload[:BLOCK] + _data(BLOCK, 34) + payload[2 * BLOCK:]
+    assert s2.read(g) == want
+    assert {b.csum_chunk for b in s2.blobs.values()} == {0, BLOCK}
+    _blob_rot(s2, bid, 2 * BLOCK + 3)
+    with pytest.raises(ChecksumError):
+        s2.read(g, 0, 10)
+    s2.close()
+
+
+@pytest.mark.parametrize("n", [0, 1, 4095, 4096, 4097, 524288])
+def test_the_block_crcs_are_crc32c_of_each_block(n, monkeypatch):
+    """One native call gives per-block crc32c, seeded 0xFFFFFFFF, packed
+    little-endian; the pure-Python fallback gives the same bytes."""
+    from ceph_tpu.backend import ecutil
+    data = _data(n, n)
+    want = b"".join(ecutil.crc32c(0xFFFFFFFF, data[i:i + BLOCK])
+                    .to_bytes(4, "little") for i in range(0, n, BLOCK))
+    native = ecutil.crc32c_blocks(data, BLOCK)
+    monkeypatch.setattr(ecutil, "_native_crc", False)
+    assert native == ecutil.crc32c_blocks(data, BLOCK) == want
+    assert len(want) == 4 * -(-n // BLOCK)

@@ -272,6 +272,43 @@ def test_a_4k_overwrite_of_a_4m_object_captures_4k_a_shard(tmp_path):
         s.close()
 
 
+def _store_read_bytes(s):
+    """Each OSD store's ``read_block_bytes``: bytes it read from its
+    block file and verified."""
+    prefix = f"bluestore.c{s.c.cluster_id}.osd"
+    return {name: v["read_block_bytes"]
+            for name, v in s.c.cct.perf.perf_dump().items()
+            if name.startswith(prefix)}
+
+
+def test_a_4k_overwrite_reads_4k_blocks_of_the_512k_shards(tmp_path):
+    """The RMW read (k data shards) and the undo (every shard) of a 4 KiB
+    overwrite read the 4 KiB block they cover from each shard's 512 KiB
+    blob, with its crc, not the blob: 8 KiB on a data shard, 4 KiB on a
+    parity shard.  The object still reads back exactly."""
+    chunk = 4096
+    s = Served(tmp_path, chunk, pg_num=1)
+    try:
+        model = bytearray(_bytes(4 << 20, 23))
+        s.r.put("p", "img.0", bytes(model))
+        with s.server.lock:
+            assert all(512 * 1024 in {b.raw_len for b in
+                                      s.c.osds[osd].store.blobs.values()}
+                       for osd in range(K + M))
+        before = _store_read_bytes(s)
+        data = _bytes(4096, 24)
+        s.r.write("p", "img.0", data, 345 * 4096 + 8 * 4096 * 3)
+        apply(model, 345 * 4096 + 8 * 4096 * 3, data)
+        read = {name: n - before[name]
+                for name, n in _store_read_bytes(s).items()}
+        assert len(read) == K + M
+        assert all(0 < n <= 2 * 4096 for n in read.values()), read
+        assert sum(read.values()) == (K + K + M) * 4096
+        s.check("img.0", model)
+    finally:
+        s.close()
+
+
 CHUNK = 512
 STRIPE = K * CHUNK
 
@@ -421,3 +458,50 @@ def test_an_overwrite_cut_by_a_crash_rolls_back_in_boot_peering(
         assert all(gg.backend.be_deep_scrub("x").values())
     finally:
         c2.shutdown()
+
+
+def test_a_rolled_back_4k_overwrite_of_a_512k_shard_restores_it(tmp_path):
+    """At the RBD pool's shape (4 KiB chunks, a 4 MiB object, 512 KiB a
+    shard): the overwrite reaches two shards, which read only the block
+    they undo, then rolls back below min_size to the old bytes."""
+    chunk = 4096
+    ec = ErasureCodePluginRegistry.instance().factory(
+        "jax_rs", "", {**PROFILE, "device": "numpy"})
+    stores = {s: BlueStoreLite(tmp_path / f"osd{s}") for s in range(K + M)}
+    bus = MessageBus()
+    backend = ECBackend(ec, StripeInfo(K, chunk), bus,
+                        acting=list(range(K + M)), whoami=0,
+                        min_size=K + 1, store=stores[0])
+    for s in range(1, K + M):
+        OSDShard(s, bus, store=stores[s])
+    try:
+        old = _bytes(4 << 20, 25)
+        backend.submit_transaction(PGTransaction().write("obj", 0, old))
+        bus.deliver_all()
+        was = _stored(bus, "obj")
+        read = {s: st.perf.get("read_block_bytes") for s, st in stores.items()}
+        committed = []
+        backend.submit_transaction(
+            PGTransaction().write("obj", 77 * K * chunk + 5 * chunk,
+                                  _bytes(chunk, 26)),
+            on_commit=committed.append)
+        while not backend.waiting_commit:    # the RMW read, then fan-out
+            assert any(bus.deliver_one(s) for s in range(K + M))
+        while bus.deliver_one(1) or bus.deliver_one(2):
+            pass
+        for s in (1, 2):
+            undo = shard_obj(bus, s).pending_rollbacks[backend.pg_log.head]
+            assert [len(op[3]) for op in undo.ops if op[0] == "write"] \
+                == [chunk]
+        assert all(st.perf.get("read_block_bytes") - read[s] <= 2 * chunk
+                   for s, st in stores.items())
+        for s in range(K, K + M):
+            bus.mark_down(s)
+        bus.deliver_all()
+        assert not committed, "acked below min_size"
+        now = _stored(bus, "obj")
+        for s in range(K):
+            assert now[s] == was[s], f"shard {s} kept the rolled-back write"
+    finally:
+        for st in stores.values():
+            st.close(checkpoint=False)

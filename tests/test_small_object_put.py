@@ -41,7 +41,8 @@ PROFILE = {"plugin": "jax_rs", "k": str(K), "m": str(M),
 SIZES = {"one_stripe": WIDTH, "two_stripes": 2 * WIDTH,
          "three_stripes": 3 * WIDTH, "two_stripes_less_100": 2 * WIDTH - 100}
 PHASES = ("stage_us", "record_us", "block_io_us", "wal_io_us")
-COUNTERS = ("transactions", "commits_queued", "txn_ops", "block_bytes",
+COUNTERS = ("transactions", "read_bytes", "read_block_bytes",
+            "commits_queued", "txn_ops", "block_bytes",
             "wal_bytes", "checkpoints") + PHASES + ("commit_cpu_us",
                                                     "checkpoint_us")
 
