@@ -473,6 +473,13 @@ def _as_u8(v) -> np.ndarray:
 # keeps the verified synchronous path.
 
 def _device_codec(ec_impl, nbytes: int):
+    # A sub-chunked code stays on its plugin's own path even where it has
+    # a device codec (clay's ClayCodec): these variants concatenate many
+    # ops' shard streams along the byte axis and apply one RSCodec matrix
+    # positionwise, and a clay codeword spans its whole shard stream
+    # (its planes are sub-chunks of that height), so joined streams are
+    # not the same code.  The plugin's encode_chunks / decode / repair
+    # still reach the device one call at a time.
     probe = getattr(ec_impl, "device_codec", None)
     if probe is None or ec_impl.get_sub_chunk_count() != 1:
         return None

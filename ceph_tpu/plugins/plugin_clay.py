@@ -22,7 +22,17 @@ Two sub-codecs (ErasureCodeClay.h:35-40):
 
 Parameters: k, m (defaults 4, 2), d in [k, k+m-1] (default k+m-1, the
 repair helper count), scalar_mds in {jerasure, isa, shec, jax_rs},
-technique per sub-plugin.  Profile device=... is forwarded to sub-codecs.
+technique per sub-plugin, device in {jax, numpy, auto}.
+
+Two ways to compute the same bytes.  With scalar_mds=jax_rs the plugin
+builds a device codec (``ops.clay_kernels.ClayCodec``, exposed as
+``self.codec``) from the two sub-codecs' matrices, and ``encode_chunks``,
+``decode_chunks`` and the repair route through it where the device
+routing of ``plugins/base.py`` says device (device=jax always,
+device=auto above the byte cutoff): one device dispatch a call.  Every
+other call runs the host loop below, plane by plane and pair by pair
+over the sub-codecs (device=numpy always; other scalar_mds always), and
+``device`` is forwarded to the sub-codecs.
 
 Python buffers: every chunk is a numpy array viewed as
 [sub_chunk_no, sc_size]; sub-chunk views alias the parent buffer so the
@@ -35,12 +45,13 @@ from typing import Mapping
 import numpy as np
 
 from .. import __version__
-from .base import ErasureCode
+from ..ops.clay_kernels import ClayCodec
+from .base import DeviceRouting, ErasureCode
 from .interface import ErasureCodeProfile
 from .registry import ErasureCodePlugin, ErasureCodePluginRegistry
 
 
-class ErasureCodeClay(ErasureCode):
+class ErasureCodeClay(DeviceRouting, ErasureCode):
     DEFAULT_K = "4"
     DEFAULT_M = "2"
 
@@ -57,6 +68,7 @@ class ErasureCodeClay(ErasureCode):
         self.sub_chunk_no = 0
         self.mds: ErasureCode | None = None
         self.pft: ErasureCode | None = None
+        self.codec: ClayCodec | None = None
 
     # -- init / parse (ErasureCodeClay.cc:62-88,185-282) --------------------
 
@@ -68,6 +80,11 @@ class ErasureCodeClay(ErasureCode):
                                     self.directory, self.mds_profile)
         self.pft = registry.factory(self.pft_profile["plugin"],
                                     self.directory, self.pft_profile)
+        if self.mds_profile["plugin"] == "jax_rs":
+            self.parse_device_routing(profile)
+            self.codec = ClayCodec(self.k, self.m, self.d,
+                                   self.mds.codec.parity_mat,
+                                   self.pft.codec.parity_mat)
         profile["plugin"] = profile.get("plugin", "clay")
         self._profile = profile
 
@@ -173,11 +190,24 @@ class ErasureCodeClay(ErasureCode):
             return 1, 0, 3, 2
         return 0, 1, 2, 3
 
+    def device_codec(self, nbytes: int) -> ClayCodec | None:
+        """The device codec a call of ``nbytes`` routes through, or None
+        where routing says host (the capability hook jax_rs offers)."""
+        if self.codec is not None and self.use_device(int(nbytes)):
+            return self.codec
+        return None
+
     # -- encode / decode (ErasureCodeClay.cc:127-183) -----------------------
 
     def encode_chunks(self, want_to_encode: set,
                       encoded: dict[int, np.ndarray]) -> None:
         k, m, nu = self.k, self.m, self.nu
+        codec = self.device_codec(k * len(encoded[0]))
+        if codec is not None:
+            parity = codec.encode(np.stack([encoded[i] for i in range(k)]))
+            for i in range(m):
+                encoded[k + i][:] = parity[i]
+            return
         chunk_size = len(encoded[0])
         chunks: dict[int, np.ndarray] = {}
         parity_chunks: set[int] = set()
@@ -194,6 +224,14 @@ class ErasureCodeClay(ErasureCode):
     def decode_chunks(self, want_to_read: set, chunks: Mapping[int, np.ndarray],
                       decoded: dict[int, np.ndarray]) -> None:
         k, m, nu = self.k, self.m, self.nu
+        lost = [i for i in range(k + m) if i not in chunks]
+        codec = self.device_codec(sum(decoded[i].nbytes for i in chunks))
+        if codec is not None and 1 <= len(lost) <= m:
+            src = codec.decode_sources(lost)
+            out = codec.decode(np.stack([decoded[i] for i in src]), lost)
+            for i, e in enumerate(lost):
+                decoded[e][:] = out[i]
+            return
         erasures: set[int] = set()
         coded: dict[int, np.ndarray] = {}
         for i in range(k + m):
@@ -408,6 +446,12 @@ class ErasureCodeClay(ErasureCode):
 
         lost = next(iter(want_to_read))
         lost_node = lost if lost < k else lost + nu
+        codec = self.device_codec(repair_blocksize * d)
+        if codec is not None:
+            ids = sorted(chunks)
+            return {lost: codec.repair(
+                np.stack([np.asarray(chunks[i], dtype=np.uint8)
+                          for i in ids]), lost, ids)}
 
         helper: dict[int, np.ndarray] = {}
         aloof: set[int] = set()

@@ -2,7 +2,7 @@
 """chip_smoke.py: does the system still start, serve and place on the chip?
 
 One process, one chip, the entry points a user calls, data from
-``--seed``.  Five phases, each compared against the repo's plain
+``--seed``.  Six phases, each compared against the repo's plain
 references (``gf/ref.py`` in pure numpy, the scalar ``crush_do_rule``
 path, the bytes a client wrote) outside any timed region:
 
@@ -16,6 +16,10 @@ path, the bytes a client wrote) outside any timed region:
               then one more Pallas encode in the same x64 process
   entry       ``__graft_entry__``: entry() jitted, dryrun_multichip()
   ec_bench    the metric of record's command, routed to the device
+  clay        Clay k=8 m=4 d=11 through its plugin's device codec: the
+              encode, the layered decode of chunks 0 and 9, and the
+              repair of every one of the 12 chunks from its 11 helpers'
+              repair runs, against ``benchmark/lib/clay_reference.py``
 
 No phase's failure is caught: the first failed check or exception ends
 the run non-zero.  The bare command refuses anything but a TPU;
@@ -572,6 +576,78 @@ def phase_ec_bench(cfg: dict) -> None:
               f"ec_bench {workload} was not routed to the device")
 
 
+# -- phase 6: Clay through its plugin's device codec --------------------------
+
+def phase_clay(cfg: dict, rng, on_tpu: bool) -> None:
+    import numpy as np
+    from benchmark.lib.clay_reference import Clay
+    from ceph_tpu.ops import clay_kernels
+    from ceph_tpu.plugins.registry import ErasureCodePluginRegistry
+
+    d = K + M - 1
+    ec = ErasureCodePluginRegistry.instance().factory(
+        "clay", "", {"k": str(K), "m": str(M), "d": str(d),
+                     "scalar_mds": "jax_rs", "technique": "cauchy",
+                     "device": "jax"})
+    codec, ref = ec.codec, Clay(K, M, d)
+    check(codec is not None, "the clay plugin built no device codec")
+    stripes, chunk = cfg["stripes"], cfg["chunk"]
+    planes = ec.get_sub_chunk_count()
+    sc = chunk // planes
+    data = rng.integers(0, 256, size=(K, stripes * chunk), dtype=np.uint8)
+    full = ref.codeword(data, chunk)               # pure numpy
+    perf = clay_kernels.perf_counters()
+    calls = perf.dump()["device_dispatches"]
+
+    def same(got, want, what):
+        got = np.asarray(got)
+        bad = int(np.count_nonzero(got != want)) if got.shape == want.shape \
+            else want.size
+        say(f"  clay {what}: {got.shape} {bad} bytes differ")
+        check(bad == 0, f"clay {what} differs from the reference")
+
+    same(codec.encode_device(data, chunk), full[K:], "encode")
+    erasures = ERASURES_TWO
+    src = codec.decode_sources(erasures)
+    same(codec.decode_device(full[src], erasures, chunk), full[erasures],
+         f"decode {erasures}")
+    for lost in range(K + M):
+        runs = ec.minimum_to_decode({lost}, set(range(K + M)) - {lost})
+        ids = list(runs)
+        picked = [z for off, cnt in runs[ids[0]]
+                  for z in range(off, off + cnt)]
+        check(picked == ref.repair_planes(lost),
+              f"repair of {lost} reads other planes than the reference's")
+        helpers = full[ids].reshape(d, stripes, planes, sc)[:, :, picked]
+        same(codec.repair_device(helpers.reshape(d, -1), lost, ids, chunk),
+             full[lost], f"repair of {lost} from {ids}")
+    made = perf.dump()["device_dispatches"] - calls
+    check(made == 2 + K + M, f"{made} dispatches for {2 + K + M} calls")
+    # on the chip every call above ran the Pallas kernel, not XLA's path
+    for sig in (("encode",), ("decode", tuple(erasures)),
+                ("repair", K + M - 1, tuple(ids))):
+        _mat, plan = codec.tables(sig).dev[on_tpu]
+        check((plan is not None) == on_tpu,
+              f"clay {sig[0]} took the {'XLA' if plan is None else 'kernel'}"
+              f" path on {'a TPU' if on_tpu else 'the CPU'}")
+    # the plugin's own entry points, one object of one stripe
+    size = cfg["ec_size"]
+    cp = ec.get_chunk_size(size)
+    obj = rng.integers(0, 256, size=K * cp, dtype=np.uint8)
+    full = ref.codeword(obj.reshape(K, cp), cp)
+    enc = ec.encode(set(range(K + M)), obj.tobytes())
+    same(np.stack([enc[i] for i in range(K + M)]), full, "plugin encode")
+    dec = ec.decode(set(erasures), {i: enc[i] for i in range(K + M)
+                                    if i not in erasures})
+    same(np.stack([dec[e] for e in erasures]), full[erasures],
+         "plugin decode")
+    runs = ec.minimum_to_decode({0}, set(range(1, K + M)))
+    helpers = {h: enc[h].reshape(planes, -1)[
+        [z for off, cnt in r for z in range(off, off + cnt)]].reshape(-1)
+        for h, r in runs.items()}
+    same(ec.decode({0}, helpers, cp)[0], full[0], "plugin repair")
+
+
 # -- main ---------------------------------------------------------------------
 
 def main(argv=None) -> int:
@@ -616,6 +692,8 @@ def main(argv=None) -> int:
             phase_entry()
         with phase("ec_bench", meter):
             phase_ec_bench(cfg)
+        with phase("clay", meter):
+            phase_clay(cfg, rng, on_tpu)
     finally:
         shutil.rmtree(out_dir, ignore_errors=True)
     setup = meter.setup_seconds(since=t0)

@@ -243,3 +243,28 @@ def test_crush_bulk_kernel_compiles_for_tpu(v5e):
     with jax.enable_x64(True):
         _compile(lambda xs: bulk.map_rule(ruleno, xs, result_max=6),
                  _sds(v5e[0], (32768,), jnp.uint32))
+
+
+@pytest.mark.parametrize("kind", ["encode", "decode", "repair"])
+def test_clay_kernels_lower_at_the_benchmarks_shapes(v5e, kind):
+    """Clay k=8 m=4 d=11 over 256 stripes x 1 MiB (2 KiB sub-chunks):
+    each signature's blocks of the matrix stay resident in VMEM, the
+    gathers of four-plane words lower, and the Mosaic call carries the
+    call's name, which a profile reader finds it by."""
+    from ceph_tpu.ops import clay_kernels
+    codec = clay_kernels.ClayCodec(8, 4, 11, cauchy1(8, 4), cauchy1(2, 2))
+    sig = {"encode": ("encode",), "decode": ("decode", (0, 9)),
+           "repair": ("repair", 0, tuple(range(1, 12)))}[kind]
+    entry = codec.tables(sig)
+    rows, z_in = (11, 16) if kind == "repair" else (8, 64)
+    n = 256 * 131072 // (4 if kind == "repair" else 1)
+    assert clay_kernels._pallas_fits(z_in, 64, 2048)
+    plan = clay_kernels._plan(entry.G, rows, z_in, len(entry.out), 64)
+    bits = clay_kernels._plan_bits(entry.G, plan, z_in, len(entry.out), 64)
+    assert 2 * bits.nbytes <= clay_kernels._MATRIX_VMEM_BYTES
+    with jax.enable_x64(False):
+        lowered = clay_kernels._APPLY[kind].lower(
+            _sds(v5e[0], bits.shape, jnp.int8), _sds(v5e[0], (rows, n)),
+            z_in=z_in, sc=2048, r_out=len(entry.out), z_out=64, plan=plan)
+        text = lowered.compile().as_text()
+    assert f"%clay_{kind}" in text and "tpu_custom_call" in text
