@@ -18,20 +18,31 @@ signature by running the layered decode (or the repair) of the paper
 over coefficient vectors instead of bytes, then cached with its
 device-resident bit-matrix, as ``RSCodec`` caches decode tables.
 
-A call is one device dispatch, whatever the stripe count: the rows
-``[rows, S*C]`` are viewed as ``[rows, S*Z, C/Z]`` (stripe s, plane z
-at row ``s*Z + z``), and a Pallas kernel takes every plane of every
-input row for a tile of sub-chunk bytes and applies the matrix as
-bit-sliced int8 matmuls on the MXU.  The matrix is sparse and blocked:
-the kernel groups the output planes four to a word, and each group
-gathers only the input words it depends on (14 of 128 for the encode at
-k=8 m=4 d=11), so the MXU does a small part of the whole matrix's work;
-the grouping is chosen per signature from the matrix itself.  Off the
-TPU (and at shapes the kernel does not tile) the same matrix goes
-through ``rs_kernels.gf_apply`` on a transposed layout.  The kernel's name says what the call was (``clay_encode``,
+A call is one device dispatch, whatever the stripe count, and a Pallas
+kernel reads and writes the caller's arrays as they lie in HBM, a stripe
+a grid step: eight input rows through ``[S*C/128, 8, 128]`` (one
+128-byte window of every row a slab, which is how XLA:TPU tiles a
+``[8, S*C]`` u8 array, so the view is a bitcast), other row counts
+(the repair's 11 helpers) as the 2-D ``[rows, S*C]`` itself.  In VMEM
+the stripe is regathered: an int32 of the caller's layout holds four
+chunk rows at one plane, and a byte transpose of four such words makes
+four words of four planes of one row, the kernel's unit.  The kernel
+then applies the matrix as bit-sliced int8 matmuls on the MXU.  The
+matrix is sparse and blocked: the kernel groups the output planes four
+to a word, and each group gathers only the input words it depends on
+(14 of 128 for the encode at k=8 m=4 d=11), so the MXU does a small part
+of the whole matrix's work; the grouping is chosen per signature from
+the matrix itself.  Each group's planes go straight into the caller's
+layout of the output: ``[4, S*C]`` (four rows to a word; two or three
+rows padded to four) or, for one row, ``[S*C/128, 128]``, the tiling of
+the 1-D ``[S*C]`` a repair returns.  So no relayout of the data runs
+beside the kernel.  Off the TPU (and at shapes the kernel does not tile)
+the same matrix goes through ``rs_kernels.gf_apply`` on a transposed
+layout.  The kernel's name says what the call was (``clay_encode``,
 ``clay_decode``, ``clay_repair``): the TPU compiler names the Mosaic
 custom call after the jit around it, so a profile reader finds each
-call's kernel by name, its output shape ``u8[rows, S*Z, C/Z]`` beside.
+call's kernel by name, its output shape ``u8[4, S*C]`` or
+``u8[S*C/128, 128]`` beside.
 """
 from __future__ import annotations
 
@@ -61,6 +72,9 @@ _VMEM_LIMIT_BYTES = 96 << 20
 # the groups' bit-matrices are resident in VMEM, double-buffered by the
 # pipeline; a signature whose blocks would not fit takes the XLA path
 _MATRIX_VMEM_BYTES = 16 << 20
+# a stripe's input and output blocks, double-buffered, and its words;
+# a chunk whose stripe would not fit takes the XLA path
+_BLOCK_VMEM_BYTES = 48 << 20
 
 _PERF = None
 _PERF_LOCK = threading.Lock()
@@ -78,6 +92,9 @@ def perf_counters():
                                       "encode, decode and repair")
                      .add_u64_counter("device_dispatches",
                                       "jitted dispatches those calls made")
+                     .add_u64_counter("kernel_calls",
+                                      "calls that ran the Mosaic kernel "
+                                      "on the caller's layout")
                      .add_u64_counter("repair_read_bytes",
                                       "helper bytes a repair call read")
                      .add_u64_counter("repair_bytes",
@@ -162,87 +179,207 @@ def _plan_bits(G: np.ndarray, plan: _Plan, z_in: int, r_out: int,
     return np.stack(out)
 
 
-def _clay_kernel(b_ref, x_ref, o_ref, *, plan: _Plan):
-    """One tile of sub-chunk bytes: every plane of every input row in,
-    every plane of every output row out.  For each group of output
-    words, the input words it reads are gathered, unpacked to
-    bit-planes four planes to an int32 word (plane-major: bit, word,
-    plane), one int8 matmul applies the group's block of the matrix,
-    and the group's planes are packed back to bytes."""
+def _transpose4(w0, w1, w2, w3):
+    """The 4x4 byte transpose of four int32 words: byte e of the r-th
+    word out is byte r of word e in.  Four words of four chunk rows at
+    one plane each (the caller's packing) become four words of four
+    planes of one row each (the kernel's), and back."""
+    srl = jax.lax.shift_right_logical
+    lo, hi = np.int32(0x0000FFFF), np.int32(-0x00010000)        # 0xFFFF0000
+    even, odd = np.int32(0x00FF00FF), np.int32(-0x00FF0100)     # 0xFF00FF00
+    half, byte = np.int32(16), np.int32(8)
+    t0, t2 = (w0 & lo) | (w2 << half), srl(w0, half) | (w2 & hi)
+    t1, t3 = (w1 & lo) | (w3 << half), srl(w1, half) | (w3 & hi)
+    return ((t0 & even) | ((t1 & even) << byte),
+            (srl(t0, byte) & even) | (t1 & odd),
+            (t2 & even) | ((t3 & even) << byte),
+            (srl(t2, byte) & even) | (t3 & odd))
+
+
+def _gather_slabs(x_ref, s_ref, z_in: int, sc: int):
+    """Eight rows of one stripe, ``[C/128, 8, 128]`` (slab t is the
+    128-byte window t of every row, four rows to an int32 word), into
+    the kernel's words ``s_ref`` [8, Z/4, sc]: for each window j of a
+    sub-chunk and quad of rows, a strided load takes window j of the
+    planes 4a+e of every a, and the byte transpose makes the words of
+    planes 4a..4a+3 of each of the quad's rows."""
+    from jax.experimental import pallas as pl
+
+    spp = sc // 128
+    x32 = x_ref.bitcast(jnp.int32)                  # [C/128, 2, 128]
+    for w in range(2):
+        for j in range(spp):
+            quad = [x32[pl.ds(e * spp + j, z_in // 4, stride=4 * spp), w, :]
+                    for e in range(4)]
+            for r, word in enumerate(_transpose4(*quad)):
+                s_ref[4 * w + r, :, j * 128:(j + 1) * 128] = word
+
+
+def _gather_rows(x_ref, s_ref, z_in: int, sc: int):
+    """Rows of one stripe, ``[R, C]`` (R the rows padded to whole int32
+    words), into the kernel's words ``s_ref`` [rows, Z/4, sc]: the byte
+    transpose of planes 4a..4a+3 of every quad of rows at once."""
+    r_in = s_ref.shape[0]
+    x32 = x_ref.bitcast(jnp.int32)                  # [R/4, C]
+    for a in range(z_in // 4):
+        quad = [x32[:, (4 * a + e) * sc:(4 * a + e + 1) * sc]
+                for e in range(4)]
+        for r, word in enumerate(_transpose4(*quad)):
+            for w in range(-(-(r_in - r) // 4)):    # the rows 4w+r < r_in
+                s_ref[4 * w + r, a:a + 1, :] = word[w:w + 1]
+
+
+def _put_words(o_ref, out, planes, r_out: int, sc: int, c0: int):
+    """Output planes ``out`` [r_out*n, T] (row o*n + p: row o, plane
+    planes[p]) into ``[4, C]``: four rows to an int32 word, the rows
+    past r_out zero."""
+    from jax.experimental.pallas import tpu as pltpu
+    n, tile = len(planes), out.shape[1]
+    word = out[0:n]
+    for o in range(1, r_out):
+        word = word | (out[o * n:(o + 1) * n] << np.int32(8 * o))
+    for p, z in enumerate(planes):
+        o_ref[:, z * sc + c0:z * sc + c0 + tile] = pltpu.bitcast(
+            word[p:p + 1], jnp.uint8)
+
+
+def _put_flat(o_ref, out, planes, sc: int, c0: int):
+    """One output row's planes ``out`` [n, T] into ``[C/128, 128]``,
+    the row's bytes 128 a line, which is the tiling of a 1-D array:
+    the lines 4k..4k+3 of a plane to an int32 word."""
+    from jax.experimental.pallas import tpu as pltpu
+    tile = out.shape[1]
+    quads = []
+    for k in range(0, tile, 512):
+        word = out[:, k:k + 128]
+        for i in range(1, 4):
+            word = word | (out[:, k + 128 * i:k + 128 * (i + 1)]
+                           << np.int32(8 * i))
+        quads.append(word)                          # [n, 128]
+    for p, z in enumerate(planes):
+        line = (z * sc + c0) // 128
+        o_ref[line:line + tile // 128, :] = pltpu.bitcast(
+            jnp.concatenate([q[p:p + 1] for q in quads], axis=0), jnp.uint8)
+
+
+def _clay_kernel(b_ref, x_ref, o_ref, s_ref, *, plan: _Plan, z_in: int,
+                 sc: int, r_out: int, tile: int):
+    """One stripe: its input rows in the caller's layout regathered into
+    words of four planes of a row (``s_ref``), then for each tile of
+    sub-chunk bytes and group of output words, the input words it reads
+    are gathered, unpacked to bit-planes four planes to an int32 word
+    (plane-major: bit, word, plane), one int8 matmul applies the group's
+    block of the matrix, and the group's planes are packed into the
+    caller's layout of the output."""
     from jax.experimental.pallas import tpu as pltpu
 
-    r_out, tile = o_ref.shape[0], x_ref.shape[2]
+    if len(x_ref.shape) == 3:
+        _gather_slabs(x_ref, s_ref, z_in, sc)
+    else:
+        _gather_rows(x_ref, s_ref, z_in, sc)
     rows = 4 * plan.gs * r_out
-    planes = [[] for _ in range(r_out)]
-    for gi, (_g0, runs) in enumerate(plan.groups):
-        pieces = [x_ref[i, 4 * a:4 * b] for i, a, b in runs]
-        short = plan.n_in - sum(b - a for _i, a, b in runs)
-        if short:
-            pieces.append(jnp.zeros((4 * short, tile), jnp.uint8))
-        words = pltpu.bitcast(jnp.concatenate(pieces, axis=0), jnp.int32)
-        bits = jnp.concatenate(
-            [pltpu.bitcast((words >> np.int32(b)) & _PLANE_ONES, jnp.int8)
-             for b in range(8)], axis=0)                  # [8*4*n_in, T]
-        acc = jax.lax.dot_general(
-            b_ref[gi], bits, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)             # [8*rows, T]
-        out = acc[0:rows] & np.int32(1)
-        for b in range(1, 8):
-            out = out | (acc[b * rows:(b + 1) * rows] & np.int32(1 << b))
-        for o in range(r_out):
-            planes[o].append(out[o * 4 * plan.gs:(o + 1) * 4 * plan.gs])
-    for o in range(r_out):
-        o_ref[o] = jnp.concatenate(planes[o], axis=0).astype(jnp.uint8)
+    for c0 in range(0, sc, tile):
+        for gi, (g0, runs) in enumerate(plan.groups):
+            pieces = [s_ref[i, a:b, c0:c0 + tile] for i, a, b in runs]
+            short = plan.n_in - sum(b - a for _i, a, b in runs)
+            if short:
+                pieces.append(jnp.zeros((short, tile), jnp.int32))
+            words = jnp.concatenate(pieces, axis=0)     # [n_in, T]
+            bits = jnp.concatenate(
+                [pltpu.bitcast((words >> np.int32(b)) & _PLANE_ONES, jnp.int8)
+                 for b in range(8)], axis=0)              # [8*4*n_in, T]
+            acc = jax.lax.dot_general(
+                b_ref[gi], bits, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32)         # [8*rows, T]
+            out = acc[0:rows] & np.int32(1)
+            for b in range(1, 8):
+                out = out | (acc[b * rows:(b + 1) * rows] & np.int32(1 << b))
+            planes = range(4 * g0, 4 * (g0 + plan.gs))
+            if r_out == 1:
+                _put_flat(o_ref, out, planes, sc, c0)
+            else:
+                _put_words(o_ref, out, planes, r_out, sc, c0)
 
 
-def _tile(sc: int, per_col: int) -> int:
-    """Columns of a grid step: the widest power of two dividing the
-    sub-chunk whose working set (``per_col`` bytes a column) fits the
-    budget."""
-    tile = 128
+def _tile(sc: int, per_col: int, least: int) -> int:
+    """Columns of a pass: the widest power of two from ``least`` up
+    dividing the sub-chunk whose working set (``per_col`` bytes a
+    column) fits the budget."""
+    tile = least
     while (sc % (2 * tile) == 0
            and 2 * tile * per_col <= _TILE_VMEM_BYTES):
         tile *= 2
     return tile
 
 
-def _pallas_fits(z_in: int, z_out: int, sc: int) -> bool:
+def _rows_padded(r_in: int) -> int:
+    """The rows of an input block: whole int32 words of rows, and whole
+    8-row tiles where the rows are not a multiple of four (11 helpers
+    read as 16: the tiling pads the array to that in HBM)."""
+    return r_in if r_in % 4 == 0 else -(-r_in // 8) * 8
+
+
+def _pallas_fits(r_in: int, z_in: int, r_out: int, z_out: int,
+                 sc: int) -> bool:
     """Shapes the kernel tiles: whole words of planes, whole lanes of
-    bytes."""
-    return z_in % 4 == 0 and z_out % 4 == 0 and sc % 128 == 0
+    bytes, at most a word of output rows (one row written 1 KiB of a
+    plane at a time), and a stripe's blocks within the VMEM budget."""
+    if z_in % 4 or z_out % 4 or sc % 128 or not 1 <= r_out <= 4:
+        return False
+    if r_out == 1 and sc % 1024:
+        return False
+    c_in, c_out = z_in * sc, z_out * sc
+    blocks = (2 * (_rows_padded(r_in) * c_in + (1 if r_out == 1 else 4)
+                   * c_out) + r_in * c_in)
+    return blocks <= _BLOCK_VMEM_BYTES
 
 
-def _pallas_apply(B, x3, z_in: int, r_out: int, z_out: int, plan: _Plan,
-                  interpret: bool = False):
-    """``x3`` [r_in, S*z_in, sc] -> [r_out, S*z_out, sc] through the
-    kernel; traced inside the caller's named jit."""
+def _pallas_apply(B, data, z_in: int, sc: int, r_out: int, z_out: int,
+                  plan: _Plan, interpret: bool = False):
+    """``data`` [r_in, S*z_in*sc] -> [r_out, S*z_out*sc] through the
+    kernel, a stripe a grid step, in the caller's layouts: eight rows
+    are read as ``[S*C/128, 8, 128]`` and others as they are; four
+    output rows (and two or three, padded) are written as ``[4, S*C]``
+    and one as ``[S*C/128, 128]``, each a bitcast of the array it
+    stands for.  Traced inside the caller's named jit."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    r_in, rows, sc = x3.shape
-    stripes = rows // z_in
-    # bytes a column: a group's int32 accumulators, its int8 bit-planes
-    # and the words they come from, the output planes held as int32, and
-    # both blocks double-buffered
-    per_col = (4 * B.shape[1] + 3 * B.shape[2] // 2 + 4 * r_out * z_out
-               + 2 * (r_in * z_in + r_out * z_out))
-    tile = _tile(sc, per_col)
-    return pl.pallas_call(
-        functools.partial(_clay_kernel, plan=plan),
-        out_shape=jax.ShapeDtypeStruct((r_out, stripes * z_out, sc),
-                                       jnp.uint8),
-        grid=(stripes, sc // tile),
-        in_specs=[
-            pl.BlockSpec(B.shape, lambda s, j: (_I0, _I0, _I0)),
-            pl.BlockSpec((r_in, z_in, tile), lambda s, j: (_I0, s, j)),
-        ],
-        out_specs=pl.BlockSpec((r_out, z_out, tile),
-                               lambda s, j: (_I0, s, j)),
+    r_in, n = data.shape
+    c_in, c_out = z_in * sc, z_out * sc
+    stripes = n // c_in
+    if r_in == 8:
+        x = data.reshape(8, n // 128, 128).transpose(1, 0, 2)
+        x_spec = pl.BlockSpec((c_in // 128, 8, 128),
+                              lambda s: (s, _I0, _I0))
+    else:
+        x = data
+        x_spec = pl.BlockSpec((_rows_padded(r_in), c_in),
+                              lambda s: (_I0, s))
+    if r_out == 1:
+        out_shape = (stripes * c_out // 128, 128)
+        o_spec = pl.BlockSpec((c_out // 128, 128), lambda s: (s, _I0))
+    else:
+        out_shape = (4, stripes * c_out)
+        o_spec = pl.BlockSpec((4, c_out), lambda s: (_I0, s))
+    # bytes a column of a pass: a group's int32 accumulators and its
+    # int8 bit-planes with the words they come from
+    tile = _tile(sc, 4 * B.shape[1] + 3 * B.shape[2] // 2,
+                 1024 if r_out == 1 else 128)
+    y = pl.pallas_call(
+        functools.partial(_clay_kernel, plan=plan, z_in=z_in, sc=sc,
+                          r_out=r_out, tile=tile),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.uint8),
+        grid=(stripes,),
+        in_specs=[pl.BlockSpec(B.shape, lambda s: (_I0, _I0, _I0)), x_spec],
+        out_specs=o_spec,
+        scratch_shapes=[pltpu.VMEM((r_in, z_in // 4, sc), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(B, x3)
+    )(B, x)
+    return y.reshape(1, -1) if r_out == 1 else y[:r_out]
 
 
 def _xla_apply(G, x3, z_in: int, r_out: int, z_out: int):
@@ -253,28 +390,30 @@ def _xla_apply(G, x3, z_in: int, r_out: int, z_out: int):
     x = x3.reshape(r_in, stripes, z_in, sc).transpose(0, 2, 1, 3)
     y = rs_kernels.gf_apply(G, x.reshape(r_in * z_in, stripes * sc))
     y = y.reshape(r_out, z_out, stripes, sc).transpose(0, 2, 1, 3)
-    return y.reshape(r_out, stripes * z_out, sc)
+    return y.reshape(r_out, stripes * z_out * sc)
 
 
-def _make_apply(name: str):
+def _make_apply(name: str, flat: bool = False):
     """A jitted ``(mat, data [r_in, S*C_in]) -> [r_out, S*C_out]`` named
-    ``name``: the kernel's blocks of the matrix under ``plan``, or,
-    with no plan, the GF matrix through ``_xla_apply``."""
+    ``name`` (``[S*C_out]`` if ``flat``, one output row): the kernel's
+    blocks of the matrix under ``plan``, or, with no plan, the GF matrix
+    through ``_xla_apply``."""
     def apply(mat, data, z_in: int, sc: int, r_out: int, z_out: int,
               plan: _Plan | None, interpret: bool = False):
         r_in, n = data.shape
-        x3 = data.reshape(r_in, n // sc, sc)
         if plan is None:
-            y = _xla_apply(mat, x3, z_in, r_out, z_out)
+            y = _xla_apply(mat, data.reshape(r_in, n // sc, sc), z_in,
+                           r_out, z_out)
         else:
-            y = _pallas_apply(mat, x3, z_in, r_out, z_out, plan, interpret)
-        return y.reshape(r_out, -1)
+            y = _pallas_apply(mat, data, z_in, sc, r_out, z_out, plan,
+                              interpret)
+        return y.reshape(-1) if flat else y
     apply.__name__ = apply.__qualname__ = name
     return jax.jit(apply, static_argnames=("z_in", "sc", "r_out", "z_out",
                                            "plan", "interpret"))
 
 
-_APPLY = {kind: _make_apply(f"clay_{kind}")
+_APPLY = {kind: _make_apply(f"clay_{kind}", flat=kind == "repair")
           for kind in ("encode", "decode", "repair")}
 
 
@@ -533,7 +672,7 @@ class ClayCodec:
                              f"segments of {z_in} sub-chunks of {sc}")
         r_out, z_out = len(entry.out), self.planes
         pallas = (rs_kernels._runs_on_tpu(data)
-                  and _pallas_fits(z_in, z_out, sc))
+                  and _pallas_fits(r_in, z_in, r_out, z_out, sc))
         found = entry.dev.get(pallas)
         if found is None:
             plan, host = None, entry.G
@@ -548,6 +687,8 @@ class ClayCodec:
                            z_out=z_out, plan=plan)
         self.perf.inc("calls")
         self.perf.inc("device_dispatches")
+        if plan is not None:
+            self.perf.inc("kernel_calls")
         return out
 
     def _sub_chunk(self, row_bytes: int, z_in: int,
@@ -601,7 +742,7 @@ class ClayCodec:
                 z_in, self._sub_chunk(n, z_in, chunk_size))
             self.perf.inc("repair_read_bytes", len(ids) * n)
             self.perf.inc("repair_bytes", n * self.q)
-            return out[0]
+            return out
 
     # -- host wrappers ------------------------------------------------------------
 

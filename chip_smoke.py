@@ -598,6 +598,7 @@ def phase_clay(cfg: dict, rng, on_tpu: bool) -> None:
     full = ref.codeword(data, chunk)               # pure numpy
     perf = clay_kernels.perf_counters()
     calls = perf.dump()["device_dispatches"]
+    kernel_calls = perf.dump()["kernel_calls"]
 
     def same(got, want, what):
         got = np.asarray(got)
@@ -623,6 +624,9 @@ def phase_clay(cfg: dict, rng, on_tpu: bool) -> None:
              full[lost], f"repair of {lost} from {ids}")
     made = perf.dump()["device_dispatches"] - calls
     check(made == 2 + K + M, f"{made} dispatches for {2 + K + M} calls")
+    ran = perf.dump()["kernel_calls"] - kernel_calls
+    check(ran == (made if on_tpu else 0),
+          f"{ran} of {made} calls counted as kernel calls")
     # on the chip every call above ran the Pallas kernel, not XLA's path
     for sig in (("encode",), ("decode", tuple(erasures)),
                 ("repair", K + M - 1, tuple(ids))):

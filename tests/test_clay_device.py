@@ -247,34 +247,76 @@ def test_cluster_recovery_of_one_osd_reads_d_quarter_chunks(pool):
     assert read == D * full.shape[1] // (D - K + 1)
 
 
-@pytest.mark.parametrize("kind", ["encode", "decode", "repair"])
-def test_the_pallas_kernel_in_interpret_mode_equals_the_reference(kind):
-    """The TPU path's blocking (groups of output words, each reading only
-    the input words it depends on) in the Pallas interpreter, at
-    (8, 4, 11) with 128-byte sub-chunks: the same bytes as the reference."""
-    import jax
+def _kernel_case(kind, stripes):
+    """(codec, signature, input rows, z_in, sub-chunk bytes, wanted
+    output) of a kernel call at (8, 4, 11): the encode and a two-erasure
+    decode at 512-byte sub-chunks, the repair of chunk 0 from its 11
+    helpers' repair planes at 1 KiB ones (the least the kernel writes
+    one output row at)."""
     ec, ref = _plugin(8, 4, 11, "jax"), Clay(8, 4, 11)
-    codec, sc, stripes = ec.codec, 128, 2
+    codec = ec.codec
+    sc = 1024 if kind == "repair" else 512
     chunk = ref.planes * sc
-    data = np.random.default_rng(7).integers(
+    data = np.random.default_rng(7 + stripes).integers(
         0, 256, (8, stripes * chunk), dtype=np.uint8)
     full = ref.codeword(data, chunk)
     if kind == "encode":
-        sig, x, z_in, want = ("encode",), data, 64, full[8:]
-    elif kind == "decode":
-        sig = ("decode", (0, 9))
+        return codec, ("encode",), data, 64, sc, full[8:]
+    if kind == "decode":
         src = codec.decode_sources([0, 9])
-        x, z_in, want = full[src], 64, full[[0, 9]]
-    else:
-        ids = tuple(range(1, 12))
-        sig, z_in, want = ("repair", 0, ids), 16, full[:1]
-        x = full[list(ids)].reshape(11, stripes, 64, sc)[
-            :, :, ref.repair_planes(0)].reshape(11, -1)
+        return codec, ("decode", (0, 9)), full[src], 64, sc, full[[0, 9]]
+    ids = tuple(range(1, 12))
+    x = full[list(ids)].reshape(11, stripes, 64, sc)[
+        :, :, ref.repair_planes(0)].reshape(11, -1)
+    return codec, ("repair", 0, ids), x, 16, sc, full[0]
+
+
+@pytest.mark.parametrize("stripes", [1, 2, 3])
+@pytest.mark.parametrize("kind", ["encode", "decode", "repair"])
+def test_the_pallas_kernel_in_interpret_mode_equals_the_reference(kind,
+                                                                  stripes):
+    """The TPU path in the Pallas interpreter, bit for bit against the
+    reference at 1, 2 and 3 stripes: the caller's rows read as they lie
+    (eight rows as 128-byte slabs, the 11 helpers' rows as a 2-D block),
+    regathered into words of four planes, the blocking (groups of output
+    words, each reading only the input words it depends on), and the
+    output written in the caller's layout (four rows to a word, or the
+    repair's one row as the 1-D chunk)."""
+    import jax
+    codec, sig, x, z_in, sc, want = _kernel_case(kind, stripes)
     entry = codec.tables(sig)
-    plan = clay_kernels._plan(entry.G, x.shape[0], z_in, len(entry.out), 64)
+    r_out = len(entry.out)
+    assert clay_kernels._pallas_fits(x.shape[0], z_in, r_out, 64, sc)
+    plan = clay_kernels._plan(entry.G, x.shape[0], z_in, r_out, 64)
     assert len(plan.groups) > 1                 # blocked, not the whole matrix
-    bits = clay_kernels._plan_bits(entry.G, plan, z_in, len(entry.out), 64)
+    bits = clay_kernels._plan_bits(entry.G, plan, z_in, r_out, 64)
     got = clay_kernels._APPLY[kind](
         jax.numpy.asarray(bits), jax.numpy.asarray(x), z_in=z_in, sc=sc,
-        r_out=len(entry.out), z_out=64, plan=plan, interpret=True)
+        r_out=r_out, z_out=64, plan=plan, interpret=True)
+    assert got.shape == want.shape
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_kernel_calls_counts_the_kernels_calls_and_not_xlas(monkeypatch):
+    """``clay`` ``kernel_calls`` rises by one for a call that ran the
+    kernel (a TPU host's path, here in the interpreter) and not for one
+    that took ``_xla_apply``; ``calls`` counts both."""
+    import functools
+    from ceph_tpu.ops import rs_kernels
+    codec, _sig, x, _z_in, sc, want = _kernel_case("repair", 1)
+    ids = tuple(range(1, 12))
+    perf = clay_kernels.perf_counters()
+
+    def counted():
+        before = dict(perf.dump())
+        got = codec.repair(x, 0, ids, 64 * sc)
+        np.testing.assert_array_equal(got, want)
+        after = perf.dump()
+        return (after["calls"] - before["calls"],
+                after["kernel_calls"] - before["kernel_calls"])
+
+    assert counted() == (1, 0)
+    monkeypatch.setattr(rs_kernels, "_runs_on_tpu", lambda data: True)
+    monkeypatch.setitem(clay_kernels._APPLY, "repair", functools.partial(
+        clay_kernels._APPLY["repair"], interpret=True))
+    assert counted() == (1, 1)

@@ -245,26 +245,59 @@ def test_crush_bulk_kernel_compiles_for_tpu(v5e):
                  _sds(v5e[0], (32768,), jnp.uint32))
 
 
-@pytest.mark.parametrize("kind", ["encode", "decode", "repair"])
+# what a relayout of a call's rows lowers to on the TPU: a copy of the
+# data into the kernel's view, or out of it, or a row cut out of a block
+_RELAYOUT_OPS = ("copy", "transpose", "slice", "reduce", "fusion")
+
+
+def _relayouts(text: str, least: int) -> list[str]:
+    """The ops of the compiled entry computation that relayout, whose
+    result has at least ``least`` elements."""
+    import re
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    found = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\w[\w-]*)\(",
+                     line)
+        if m and m.group(2) in _RELAYOUT_OPS:
+            size = int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+            if size >= least:
+                found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("kind", ["encode", "decode", "decode_three",
+                                  "repair"])
 def test_clay_kernels_lower_at_the_benchmarks_shapes(v5e, kind):
     """Clay k=8 m=4 d=11 over 256 stripes x 1 MiB (2 KiB sub-chunks):
     each signature's blocks of the matrix stay resident in VMEM, the
-    gathers of four-plane words lower, and the Mosaic call carries the
-    call's name, which a profile reader finds it by."""
+    gathers of four-plane words lower, the Mosaic call carries the
+    call's name, which a profile reader finds it by, and the kernel
+    reads and writes the caller's layouts: no copy, transpose, slice,
+    reduce or fusion as large as the call's data or output is left
+    around it (the int8 matrix's small copy is), and the repair's jit
+    returns the rebuilt chunk as the 1-D ``[S*C]`` ``repair_device``
+    hands back."""
     from ceph_tpu.ops import clay_kernels
     codec = clay_kernels.ClayCodec(8, 4, 11, cauchy1(8, 4), cauchy1(2, 2))
     sig = {"encode": ("encode",), "decode": ("decode", (0, 9)),
+           "decode_three": ("decode", (1, 8, 11)),
            "repair": ("repair", 0, tuple(range(1, 12)))}[kind]
     entry = codec.tables(sig)
     rows, z_in = (11, 16) if kind == "repair" else (8, 64)
     n = 256 * 131072 // (4 if kind == "repair" else 1)
-    assert clay_kernels._pallas_fits(z_in, 64, 2048)
-    plan = clay_kernels._plan(entry.G, rows, z_in, len(entry.out), 64)
-    bits = clay_kernels._plan_bits(entry.G, plan, z_in, len(entry.out), 64)
+    r_out = len(entry.out)
+    assert clay_kernels._pallas_fits(rows, z_in, r_out, 64, 2048)
+    plan = clay_kernels._plan(entry.G, rows, z_in, r_out, 64)
+    bits = clay_kernels._plan_bits(entry.G, plan, z_in, r_out, 64)
     assert 2 * bits.nbytes <= clay_kernels._MATRIX_VMEM_BYTES
     with jax.enable_x64(False):
-        lowered = clay_kernels._APPLY[kind].lower(
+        lowered = clay_kernels._APPLY[sig[0]].lower(
             _sds(v5e[0], bits.shape, jnp.int8), _sds(v5e[0], (rows, n)),
-            z_in=z_in, sc=2048, r_out=len(entry.out), z_out=64, plan=plan)
+            z_in=z_in, sc=2048, r_out=r_out, z_out=64, plan=plan)
         text = lowered.compile().as_text()
-    assert f"%clay_{kind}" in text and "tpu_custom_call" in text
+    assert f"%clay_{sig[0]}" in text and "tpu_custom_call" in text
+    out_shape = (256 * 131072,) if kind == "repair" else (r_out, 256 * 131072)
+    assert lowered.out_info.shape == out_shape
+    assert _relayouts(text, min(rows * n, r_out * 256 * 131072)) == []
